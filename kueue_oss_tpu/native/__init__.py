@@ -3,13 +3,16 @@
 The compute path is JAX/XLA; these are the *host-side* hot loops around
 it — currently the sequential quota-oracle verify used when committing
 solver plans (oracle.cpp). The library is compiled on first use with the
-system toolchain and cached next to the source; every entry point has a
-pure-Python fallback so the framework works without a compiler.
+system toolchain and cached next to the source under a name keyed on the
+source's CONTENT (file times do not survive a copy of the tree), so what
+runs is always built from the oracle.cpp beside it; every entry point
+has a pure-Python fallback so the framework works without a compiler.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,39 +25,52 @@ from kueue_oss_tpu.core.quota import QuotaNode
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "oracle.cpp")
-_LIB = os.path.join(_DIR, "_oracle.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
-def _compile() -> bool:
+def lib_path() -> str:
+    """Where the library built from the CURRENT oracle.cpp lives."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_oracle-{digest}.so")
+
+
+def _compile(lib: str) -> bool:
+    # build beside the target and rename: a concurrent loader (xdist
+    # workers, a sidecar next to its manager) never maps a half-written
+    # library
+    tmp = f"{lib}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-             "-o", _LIB, _SRC],
+             "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120)
+        os.replace(tmp, lib)
         return True
     except (OSError, subprocess.SubprocessError):
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """The compiled library, building it if stale; None if unavailable."""
+    """The compiled library, building it if absent; None if unavailable."""
     global _lib, _load_failed
     with _lock:
         if _lib is not None:
             return _lib
         if _load_failed:
             return None
-        stale = (not os.path.exists(_LIB)
-                 or os.path.getmtime(_LIB) < os.path.getmtime(_SRC))
-        if stale and not _compile():
+        path = lib_path()
+        if not os.path.exists(path) and not _compile(path):
             _load_failed = True
             return None
         try:
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(path)
         except OSError:
             _load_failed = True
             return None

@@ -78,6 +78,12 @@ class SolverEngine:
         #: separate sidecar process (SURVEY §2.4); export, verify, and
         #: commit stay in this process
         self.remote = remote
+        if remote is None:
+            # this process compiles the solver programs: minutes for
+            # the flagship drain, paid once per machine
+            from kueue_oss_tpu.util import xla_cache
+
+            xla_cache.enable()
         #: circuit breaker over the remote backend: a tripped breaker
         #: short-circuits drains into SolverUnavailable (host-cycle
         #: fallback) instead of re-probing a dead sidecar every pass
@@ -115,11 +121,12 @@ class SolverEngine:
         #: cut round counts ~10x on park-heavy shapes (see _size_caps).
         self.h_max_cap = 1024
         #: per-round search-work budget in lane-option-group units
-        #: (each lane runs K x g victim searches): on an accelerator
-        #: the lanes vectorize so the budget is generous; on the CPU
-        #: fallback they serialize, so multi-flavor/multi-group shapes
-        #: trade lanes for rounds at roughly constant work. None =
-        #: choose by backend at first drain.
+        #: (each lane runs K x g victim searches). None = the budget
+        #: of the backend that SOLVES (full_kernels.lane_work_budget):
+        #: asked of this process at the first local drain, and left to
+        #: the sidecar when the solve is remote — the control plane
+        #: never initializes a backend to size lanes for a device it
+        #: does not own.
         self.h_work_budget = None
         #: debugger.Tracer for drain spans; when unset, the scheduler's
         #: attached tracer (attach_to_scheduler) is used, so host cycle
@@ -606,7 +613,12 @@ class SolverEngine:
             # gauges/counters flow even with the ledger disabled (the
             # bench twin's off arm disables the ledger, not devtel)
             dtl.note_transfers(arm, tenant, device)
-            device.update(dtl.sample_residency(self._resident_bytes()))
+            if self.remote is None:
+                # HBM is the device owner's to report: a control plane
+                # in front of a sidecar holds nothing resident and has
+                # no backend to ask (asking would initialize one)
+                device.update(
+                    dtl.sample_residency(self._resident_bytes()))
             events = dtl.compiles.drain_events()
             if events:
                 device["compiles"] = len(events)
@@ -721,13 +733,19 @@ class SolverEngine:
                 return None
             self.refresh_mesh(self._mesh_max_devices)
         if not self._mesh_resolved:
-            from kueue_oss_tpu.solver import meshutil
+            # a remote engine has no mesh of its own: the sidecar owns
+            # the devices and advertises its width in session
+            # responses (remote_mesh_devices). A control plane must
+            # not initialize a backend — on a chip host that would
+            # take the sidecar's chip — to look for one
+            if self.remote is None:
+                from kueue_oss_tpu.solver import meshutil
 
-            try:
-                self._mesh_obj = meshutil.detect_mesh(
-                    self.mesh_mode, self._mesh_max_devices)
-            except Exception:
-                self._mesh_obj = None  # backend init failure != crash
+                try:
+                    self._mesh_obj = meshutil.detect_mesh(
+                        self.mesh_mode, self._mesh_max_devices)
+                except Exception:
+                    self._mesh_obj = None  # backend init failure != crash
             self._mesh_resolved = True
         return self._mesh_obj
 
@@ -1601,8 +1619,11 @@ class SolverEngine:
         resolution to h_max classes per round (the round-5 churn
         profile: 49 park-only rounds at h=64 vs 5 at h=1024 on the
         50k x 1k shape). Production drains therefore size lanes to the
-        CQ count up to `h_max_cap`; the stepped serve-loop path can run
-        a narrow-lane variant for per-round latency. p_max
+        CQ count up to `h_max_cap`, clamped to the solving backend's
+        per-round work budget (full_kernels.budgeted_lanes; a remote
+        sidecar applies its own budget to the h_max shipped here); the
+        stepped serve-loop path can run a narrow-lane variant for
+        per-round latency. p_max
         bounds candidates per search and MUST cover the largest possible
         candidate set. Candidates are always CONCURRENTLY-ADMITTED
         workloads with nonzero usage in the preemptor's cohort tree
@@ -1618,22 +1639,19 @@ class SolverEngine:
         uses >= the smallest positive request on some FR. Rounded up to
         powers of two to reuse compiled kernels.
         """
-        C = problem.n_cqs
-        if self.h_work_budget is None:
-            import jax
+        from kueue_oss_tpu.solver.full_kernels import (
+            budgeted_lanes,
+            lane_work_budget,
+        )
 
-            self.h_work_budget = (8192 if jax.default_backend() != "cpu"
-                                  else 512)
-        K = problem.wl_req.shape[1] if problem.wl_req.ndim == 3 else 1
-        g = max(1, int(problem.cq_ngroups.max()) if C else 1)
-        # round the budgeted lane count DOWN to a power of two so the
-        # budget is actually enforced; the 64-lane floor overrides it
-        # for very wide K x g shapes (fewer lanes than that defers too
-        # many heads per round to ever converge quickly)
-        lane_cap = pow2(max(
-            1, self.h_work_budget // max(K * g, 1)) + 1) // 2
-        lane_cap = max(64, lane_cap)
-        h_max = max(1, pow2(min(C, self.h_max_cap, lane_cap)))
+        C = problem.n_cqs
+        h_max = max(1, pow2(min(C, self.h_max_cap)))
+        if self.h_work_budget is None and self.remote is None:
+            self.h_work_budget = lane_work_budget()
+        if self.h_work_budget is not None:
+            K = problem.wl_req.shape[1] if problem.wl_req.ndim == 3 else 1
+            g = max(1, int(problem.cq_ngroups.max()) if C else 1)
+            h_max = budgeted_lanes(h_max, self.h_work_budget, K, g)
         root_of_cq = problem.cq_root
         wl_root = root_of_cq[np.minimum(problem.wl_cqid[:-1], C - 1)]
         counts = np.bincount(wl_root, minlength=problem.n_nodes + 1)

@@ -56,6 +56,7 @@ from kueue_oss_tpu.solver.tensors import (
     POLICY_NEVER,
     NO_THRESHOLD,
     SolverProblem,
+    pow2,
 )
 
 # candidate variants (classical/candidate_generator.go)
@@ -1073,8 +1074,6 @@ def _run_searches(t, usage, wl_usage, admitted, evicted, ts,
 
     from jax.sharding import PartitionSpec as P
 
-    from kueue_oss_tpu.solver.meshutil import pvary, shard_map
-
     W_null = t.wl_cqid.shape[0] - 1
     n_dev = mesh.shape[axis]
     L = flat_w.shape[0]
@@ -1096,10 +1095,11 @@ def _run_searches(t, usage, wl_usage, admitted, evicted, ts,
     def shard_body(hw, rq, av, cd, *rep):
         # mark the replicated state varying-over-mesh so while_loop
         # carries inside the search have consistent manual-axes types
-        rep = jax.tree_util.tree_map(lambda x: pvary(x, axis), rep)
+        rep = jax.tree_util.tree_map(
+            lambda x: jax.lax.pcast(x, (axis,), to="varying"), rep)
         return vsearch(hw, rq, av, cd, *rep)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis),
@@ -1421,6 +1421,29 @@ def _solve_full_impl(t: FullTensors, g_max: int, h_max: int, p_max: int,
     return (admitted, final["opt"], final["admit_round"], parked,
             final["rounds"], final["usage"], final["wl_usage"],
             final["victim_reason"])
+
+
+def lane_work_budget() -> int:
+    """Per-round victim-search work budget, in lane-option-group units
+    (each lane runs K x g searches), of the backend THIS process
+    solves on: on an accelerator the lanes vectorize so the budget is
+    generous; on the CPU they serialize, so multi-flavor/multi-group
+    shapes trade lanes for rounds at roughly constant work.
+
+    Only the process that owns the device may ask: a CPU-pinned manager
+    in front of a sidecar would get the CPU's answer for the sidecar's
+    chip (see :func:`budgeted_lanes`, applied sidecar-side)."""
+    return 8192 if jax.default_backend() != "cpu" else 512
+
+
+def budgeted_lanes(h_max: int, work_budget: int, K: int, g: int) -> int:
+    """Clamp ``h_max`` victim-search lanes to ``work_budget``: the
+    budgeted lane count rounds DOWN to a power of two so the budget is
+    actually enforced; the 64-lane floor overrides it for very wide
+    K x g shapes (fewer lanes than that defers too many heads per
+    round to ever converge quickly)."""
+    lane_cap = pow2(max(1, work_budget // max(K * g, 1)) + 1) // 2
+    return min(h_max, max(64, lane_cap))
 
 
 def make_full_solver(g_max: int, h_max: int, p_max: int,

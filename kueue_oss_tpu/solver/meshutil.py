@@ -1,14 +1,11 @@
 """Mesh plumbing shared by the engine, the sidecar, and the bench.
 
 The multi-chip kernels (solver/sharded.py, full_kernels mesh lanes)
-need three things every production call site repeats: a portable
-``shard_map`` (the API moved between jax releases; the image's jax
-still ships it under ``jax.experimental``), mesh *detection* (config /
-env / device-count auto), and a cache of jitted mesh drains so every
-drain of the same (mesh, shape) reuses one compiled SPMD program.
-Centralizing them here keeps `engine.py` and `service.py` free of
-version probing and makes the sidecar's placement decisions identical
-to the in-process engine's.
+need two things every production call site repeats: mesh *detection*
+(config / env / device-count auto), and a cache of jitted mesh drains
+so every drain of the same (mesh, shape) reuses one compiled SPMD
+program. Centralizing them here makes the sidecar's placement
+decisions identical to the in-process engine's.
 
 Mesh mode grammar (``SolverBackendConfig.mesh`` / ``KUEUE_SOLVER_MESH``):
 
@@ -88,20 +85,17 @@ def bootstrap_distributed(coordinator_address: Optional[str] = None,
         return 1
     import jax
 
-    if "cpu" in str(getattr(jax.config, "jax_platforms", None) or "cpu"):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:
-            pass  # non-CPU build or option renamed: backend default
-        try:
-            # belt and suspenders for the gloo frame-interleaving
-            # hazard above: synchronous dispatch keeps two PROGRAMS
-            # from being in flight at once (the one-device-per-process
-            # deployment shape handles the within-program case)
-            jax.config.update("jax_cpu_enable_async_dispatch", False)
-        except Exception:
-            pass
+    # the platform list this process was started with, not an
+    # initialized backend: jax.distributed.initialize must run before
+    # any backend exists. Unset means "whatever JAX finds" — on a chip
+    # host that is the chip, which needs no CPU collectives.
+    if "cpu" in (jax.config.jax_platforms or "").split(","):
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
+        # belt and suspenders for the gloo frame-interleaving hazard
+        # above: synchronous dispatch keeps two PROGRAMS from being in
+        # flight at once (the one-device-per-process deployment shape
+        # handles the within-program case)
+        jax.config.update("jax_cpu_enable_async_dispatch", False)
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=int(num_processes), process_id=int(process_id))
@@ -148,39 +142,6 @@ def host_replicated(arrays) -> tuple:
         else:
             out.append(np.asarray(mhu.process_allgather(a, tiled=True)))
     return tuple(out)
-
-
-def shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable shard_map.
-
-    On a jax new enough to expose ``jax.shard_map`` the default
-    varying-axes checking runs (the kernels mark their per-shard
-    carries with :func:`pvary`); on the older ``jax.experimental``
-    spelling the replication checker is disabled instead — it predates
-    varying-type annotations, and the drain carries deliberately mix
-    replicated tree state with shard-varying workload rows."""
-    import jax
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as esm
-
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def pvary(x, axis: str):
-    """Mark a replicated value varying over ``axis`` where the running
-    jax tracks varying-axes types (``jax.lax.pcast``, paired with the
-    ``jax.shard_map`` spelling above); identity on older jax, where the
-    value is already just a per-device array inside shard_map."""
-    import jax
-
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, (axis,), to="varying")
-    return x
 
 
 def parse_mesh_mode(mode: Optional[str]) -> Optional[int]:

@@ -191,6 +191,21 @@ def deserialize_problem(meta: dict, blob: bytes) -> SolverProblem:
     return SolverProblem(**kwargs)
 
 
+def _budgeted_h_max(header: dict, K: int) -> int:
+    """The request's victim-search lane count under THIS process's
+    work budget. The client ships the lanes its problem could use (CQ
+    count up to its cap); the budget depends on the backend that runs
+    the search, which only the device owner can name — a CPU-pinned
+    control plane must not guess it for the sidecar's chip."""
+    from kueue_oss_tpu.solver.full_kernels import (
+        budgeted_lanes,
+        lane_work_budget,
+    )
+
+    return budgeted_lanes(int(header["h_max"]), lane_work_budget(), K,
+                          int(header["g_max"]))
+
+
 def _solve_kernel(tensors, header: dict, mesh=None):
     """Run the jitted kernel matching the request params; returns
     (out tuple, legacy array names). With a ``mesh`` BOTH kernels
@@ -202,8 +217,9 @@ def _solve_kernel(tensors, header: dict, mesh=None):
         from kueue_oss_tpu.solver.full_kernels import solve_backlog_full
 
         out = solve_backlog_full(
-            tensors, header["g_max"], header["h_max"], header["p_max"],
-            fs_enabled=header["fs_enabled"], mesh=mesh)
+            tensors, header["g_max"],
+            _budgeted_h_max(header, tensors.wl_req.shape[1]),
+            header["p_max"], fs_enabled=header["fs_enabled"], mesh=mesh)
         names = ["admitted", "opt", "admit_round", "parked",
                  "rounds", "usage", "wl_usage", "victim_reason"]
     else:
@@ -435,7 +451,8 @@ def _multihost_solve(header: dict, blob: bytes, mesh):
         from kueue_oss_tpu.solver.sharded import solve_backlog_full_sharded
 
         out = solve_backlog_full_sharded(
-            problem, mesh, header["g_max"], header["h_max"],
+            problem, mesh, header["g_max"],
+            _budgeted_h_max(header, problem.wl_req.shape[1]),
             header["p_max"], fs_enabled=header["fs_enabled"])
         names = ["admitted", "opt", "admit_round", "parked",
                  "rounds", "usage", "wl_usage", "victim_reason"]
@@ -576,15 +593,20 @@ def _session_request(header: dict, blob: bytes,
             epoch = sess.epoch
     buf = io.BytesIO()
     np.savez(buf, **arrays)
+    import jax
+
     from kueue_oss_tpu.solver.meshutil import mesh_devices
 
     # advertise the sidecar's mesh width so a mesh-less client can
     # re-pad its next drains to a shardable axis (engine._pad_target);
     # without this, a CPU-only control plane would ship pow2+1 rows
-    # forever and the accelerator sidecar could never shard them
+    # forever and the accelerator sidecar could never shard them. The
+    # platform that just solved rides beside it: the control plane has
+    # no backend of its own to ask what its plans were computed on.
     return {"ok": True, "compact": True, "epoch": epoch,
             "mesh_devices": mesh_devices(getattr(server, "mesh", None)
                                          if server is not None else None),
+            "platform": jax.default_backend(),
             "spans": _spans(header, t0)}, buf.getvalue()
 
 
@@ -735,6 +757,9 @@ class SolverServer(socketserver.ThreadingUnixStreamServer):
         #: optional federation/farm.py FarmScheduler; when set, every
         #: decoded request is admitted through its per-tenant DRR queue
         self.farm = None
+        from kueue_oss_tpu.util import xla_cache
+
+        xla_cache.enable()
         #: sidecar mesh detection (solver/meshutil.py): sessions place
         #: their resident lean tensors over the mesh and solve via the
         #: sharded SPMD drain; full solves lane-shard. KUEUE_SOLVER_MESH
@@ -892,6 +917,9 @@ class SolverClient:
         #: the engine aligns its pad target to it so the sidecar can
         #: shard the resident problem (0 = unknown / no sidecar mesh)
         self.remote_mesh_devices = 0
+        #: the platform the sidecar's last session solve ran on
+        #: ("tpu" / "cpu" / ...; "" = unknown)
+        self.remote_platform = ""
         if sessions is None:
             sessions = os.environ.get("KUEUE_SOLVER_SESSIONS") != "0"
         self.use_sessions = bool(sessions)
@@ -1089,6 +1117,7 @@ class SolverClient:
             self.remote_mesh_devices = int(resp.get("mesh_devices", 0))
         except (TypeError, ValueError):
             self.remote_mesh_devices = 0
+        self.remote_platform = str(resp.get("platform", "") or "")
         try:
             data = np.load(io.BytesIO(body))
             if resp.get("compact"):
@@ -1105,3 +1134,41 @@ class SolverClient:
         except Exception as e:  # zipfile/np decode errors on corruption
             raise SolverProtocolError(
                 f"undecodable plan payload: {e!r}") from e
+
+
+def main(argv: Optional[list[str]] = None) -> int:
+    """``python -m kueue_oss_tpu.solver.service <socket>``: the sidecar
+    container's command (deploy/manifests/base/manager.yaml). Serves
+    until SIGTERM/SIGINT; with KUEUE_SOLVER_COORDINATOR set, the
+    non-coordinator ranks join the pod's collective solves instead."""
+    import argparse
+    import signal
+
+    ap = argparse.ArgumentParser(
+        prog="python -m kueue_oss_tpu.solver.service",
+        description="kueue_oss_tpu solver sidecar")
+    ap.add_argument("socket_path",
+                    help="unix-domain socket to serve on")
+    args = ap.parse_args(argv)
+    server = serve_multihost(args.socket_path)
+    if not isinstance(server, SolverServer):
+        return 0  # follower rank: the coordinator shut the pod down
+
+    def stop(*_):
+        # shutdown() blocks until serve_forever returns, so it cannot
+        # run on the thread that serve_forever is interrupted on
+        threading.Thread(target=server.shutdown, daemon=True).start()
+
+    signal.signal(signal.SIGTERM, stop)
+    signal.signal(signal.SIGINT, stop)
+    try:
+        server.serve_forever()
+    finally:
+        server.server_close()
+        if os.path.exists(args.socket_path):
+            os.unlink(args.socket_path)
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -44,7 +44,6 @@ from kueue_oss_tpu.solver.kernels import (
     potential_available_all,
     refresh_cohort_usage,
 )
-from kueue_oss_tpu.solver.meshutil import pvary, shard_map
 from kueue_oss_tpu.solver.tensors import BIG, SolverProblem
 
 #: NamedSharding specs for the lean ProblemTensors: workload axis
@@ -171,7 +170,7 @@ def make_sharded_drain(mesh: Mesh, axis: str = "wl"):
         )
 
         @partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(node_specs,),
             out_specs=(P(axis), P(axis), P(axis), P(axis), P(), P()),
         )
@@ -287,15 +286,18 @@ def make_sharded_drain(mesh: Mesh, axis: str = "wl"):
                 return (usage, admitted, parked, opt, admit_round,
                         cursor_c, head_w, progress, rounds + 1)
 
+            def varying(x):
+                return jax.lax.pcast(x, (axis,), to="varying")
+
             init = (
                 tl.usage0,
                 # admitted/parked/opt/admit_round are per-shard plan
                 # state: mark them varying over the mesh axis so the
                 # carry types line up.
-                pvary(jnp.zeros((shard,), dtype=bool), axis),
-                pvary(jnp.zeros((shard,), dtype=bool), axis),
-                pvary(jnp.zeros((shard,), dtype=jnp.int32), axis),
-                pvary(jnp.full((shard,), -1, dtype=jnp.int32), axis),
+                varying(jnp.zeros((shard,), dtype=bool)),
+                varying(jnp.zeros((shard,), dtype=bool)),
+                varying(jnp.zeros((shard,), dtype=jnp.int32)),
+                varying(jnp.full((shard,), -1, dtype=jnp.int32)),
                 jnp.zeros((C + 1,), dtype=jnp.int32),
                 jnp.full((C,), BIG, dtype=jnp.int32),
                 jnp.ones((), dtype=bool),
@@ -330,7 +332,7 @@ def make_sharded_relax_lp(mesh: Mesh, iters: int, axis: str = "wl"):
         cq_node=P(), path_cq=P(), parent=P(), depth=P(),
         slack=P(), scale=P())
 
-    @partial(shard_map, mesh=mesh, in_specs=(specs,),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(specs,),
              out_specs=P(axis))
     def run(lp):
         return lp_loop(lp, iters, psum_axis=axis)

@@ -1,12 +1,16 @@
-"""Persistent XLA compilation cache (production default: on).
+"""Persistent XLA compilation cache: one function decides where it is.
 
-The solver's programs are compiled per (padded-shape, caps) key; a
-production deployment — and the bench's subprocess-per-scenario
-protocol — must not pay that compile more than once per machine.
-JAX only honors the JAX_COMPILATION_CACHE_DIR environment variable on
-some versions; setting the config keys explicitly works on all, so
-every entry point (bench scenarios, the solver sidecar, serve()) calls
-:func:`enable` before the first compile.
+The solver's programs are compiled per (padded-shape, caps) key, and
+the flagship preemption drain takes minutes to compile; no process on
+a machine should pay that twice. Everything that compiles a solver
+program — ``SolverEngine``, ``SolverServer``, ``chip_smoke.py``, the
+bench scenarios — calls :func:`enable` before its first compile.
+
+Where ``JAX_COMPILATION_CACHE_DIR`` is set (the deployment manifests
+set it), JAX reads the variable itself and this module sets nothing.
+Where it is not, the cache goes to ONE fixed directory inside the
+checkout: the directory is part of the cache key, so a temporary,
+per-process or per-run name would never hit.
 
 Reference analog: the reference amortizes scheduling-logic cost by
 being a long-lived controller process (cmd/kueue main.go); our
@@ -18,29 +22,19 @@ from __future__ import annotations
 
 import os
 
-_DEFAULT_DIR = "/tmp/kueue_oss_tpu_xla_cache"
+#: the checkout root (the directory holding the ``kueue_oss_tpu``
+#: package); the default cache lives directly under it, git-ignored
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+DEFAULT_DIR = os.path.join(_CHECKOUT, ".xla_cache")
 
-_enabled = False
 
+def enable() -> str:
+    """Make sure JAX's persistent compilation cache has a directory;
+    returns the directory in use. Idempotent, and never overrides a
+    directory chosen from outside (environment or ``jax.config``)."""
+    import jax
 
-def enable(path: str | None = None) -> str | None:
-    """Idempotently point JAX's persistent compilation cache at *path*.
-
-    Returns the cache dir, or None if disabled via
-    KUEUE_TPU_XLA_CACHE=off or an unavailable jax.
-    """
-    global _enabled
-    if os.environ.get("KUEUE_TPU_XLA_CACHE", "").lower() in ("off", "0"):
-        return None
-    path = path or os.environ.get("JAX_COMPILATION_CACHE_DIR", _DEFAULT_DIR)
-    if _enabled:
-        return path
-    try:
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        return None
-    _enabled = True
-    return path
+    if jax.config.jax_compilation_cache_dir is None:
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    return jax.config.jax_compilation_cache_dir

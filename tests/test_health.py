@@ -276,7 +276,7 @@ def test_solver_drain_ledger_row_records_arm_and_frame():
     assert row.solver_arm in ("single", "mesh")
     assert row.frame_kind == "sync" and row.frame_bytes > 0
     assert row.frame_reason == "first_sync"
-    assert set(row.phases) == {"solve", "apply"}
+    assert {"solve", "apply"} <= set(row.phases)
     # second drain with churn ships a delta frame
     sched = Scheduler(store, queues)
     admitted = [k for k, w in store.workloads.items()

@@ -655,7 +655,6 @@ def test_sidecar_mesh_fault_serves_single_chip_and_trips(
         calls["n"] += 1
         raise RuntimeError("injected sidecar mesh loss")
 
-    monkeypatch.setattr(meshutil, "lean_mesh_solver", boom)
     store = _store(preemption=False)
     for i in range(48):
         store.add_workload(_wl(i))
@@ -664,14 +663,22 @@ def test_sidecar_mesh_fault_serves_single_chip_and_trips(
     engine = SolverEngine(store, queues, scheduler=sched,
                           remote=SolverClient(path))
     engine.pad_to = 64
-    result = engine.drain(now=0.0)  # served despite the mesh fault
-    assert calls["n"] == 1
+    # a control plane looks for no mesh of its own: the first response
+    # teaches it the sidecar's width, the next drain ships a shardable
+    # axis, and THAT solve meets the mesh fault
+    result = engine.drain(now=0.0)
     assert result.admitted == 32
+    assert engine.remote.remote_mesh_devices == 8
+    monkeypatch.setattr(meshutil, "lean_mesh_solver", boom)
+    _churn_run(engine, store, sched, cycles=1)  # served despite the fault
+    assert calls["n"] == 1
+    # both seats the churn freed were refilled by the faulted solve
+    assert sum(w.is_quota_reserved and not w.is_finished
+               for w in store.workloads.values()) == 32
     assert srv.mesh is None, "sidecar mesh must trip off, not flap"
     sess = next(iter(srv.sessions.values()))
     assert not sess.device.mesh_placed
     # subsequent drains stay single-chip and never touch the mesh again
-    monkeypatch.undo()
     _churn_run(engine, store, sched, cycles=1)
     assert calls["n"] == 1
 

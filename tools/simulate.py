@@ -24,10 +24,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# the what-if batch is a planning tool: default to the CPU backend
-# unless the caller explicitly picked a platform
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 from kueue_oss_tpu.config.configuration import SimulatorConfig  # noqa: E402
 from kueue_oss_tpu.perf.generator import GeneratorConfig, generate  # noqa: E402
 from kueue_oss_tpu.sim import (  # noqa: E402
@@ -158,6 +154,10 @@ def main(argv=None, out=None) -> int:
             kind_counts_per_cycle(events)
             == kind_counts_per_cycle(replayed.events()))
 
+    import jax
+
+    # the report is platform-independent; where it was computed is not
+    print(f"solved on {jax.default_backend()}", file=sys.stderr)
     text = (json.dumps(result, sort_keys=True,
                        separators=(",", ":"))
             if args.compact else
