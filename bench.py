@@ -2727,7 +2727,8 @@ def run_scenario(scenario: str) -> dict:
 
         def set_devtel(on: bool) -> None:
             col.enabled = on
-            engine.tracer = tracer if on else None
+            # the tracer is a sink of the program's spans (obs/spans.py)
+            (obs.spans.add_sink if on else obs.spans.remove_sink)(tracer)
 
         set_devtel(True)  # warm-up runs the full collector path
         path = os.path.join(tempfile.mkdtemp(), "solver.sock")

@@ -1,0 +1,183 @@
+"""``progspans.py``: the two reader kinds on planted facts, the totals of
+a window, and the trace reduction on the second recorded sample
+(``data/sample_spans.xplane.pb``, v5e, ``record_sample_spans.py``)."""
+
+import os
+
+import pytest
+
+from benchmark import deployment, progspans, readers, run
+
+DATA = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "data")
+SAMPLE = os.path.join(DATA, "sample_spans.xplane.pb")
+
+FACTS = {
+    "passes": 4.0, "window_s": 8.0, "trace": None,
+    "program": {
+        "spans": {
+            "quiet": {"s": 6.0, "n": 4, "self_s": 0.2},
+            "route": {"s": 2.5, "n": 4, "self_s": 0.1},
+            "solver_drain": {"s": 2.4, "n": 1, "self_s": 0.3},
+            "solve": {"s": 1.6, "n": 1, "self_s": 0.0},
+            "dispatch": {"s": 0.4, "n": 1, "self_s": 0.4},
+            "apply.commit": {"s": 0.3, "n": 1, "self_s": 0.3},
+            "schedule": {"s": 3.2, "n": 10, "self_s": 0.2},
+            "entries": {"s": 2.0, "n": 8, "self_s": 2.0},
+            "store.finish": {"s": 0.002, "n": 1000, "self_s": 0.002}},
+        "counts": {"drain_admitted": 600, "retrace_s": 0.08}}}
+
+
+def test_program_reader_sums_subtracts_counts_and_divides():
+    rd = progspans.program
+    assert rd(FACTS, ["entries"], per="window_s", scale=100.0) == 25.0
+    assert rd(FACTS, ["route"], ["solver_drain"], per="window_s",
+              scale=100.0) == pytest.approx(1.25)
+    assert rd(FACTS, count="schedule", per="passes") == 2.5
+    assert rd(FACTS, count="retrace_s", per="window_s",
+              scale=100.0) == pytest.approx(1.0)
+    assert rd(FACTS, ["apply.commit"], per="drain_admitted",
+              scale=1e6) == pytest.approx(500.0)
+    assert rd(FACTS, ["store.finish"], per="store.finish",
+              scale=1e6) == pytest.approx(2.0)
+    # a span the program does not have: nothing, and never a made-up 0
+    assert rd(FACTS, ["no_such_span"], per="window_s") is None
+    assert rd(FACTS, count="no_such_count") is None
+    assert rd(FACTS, ["entries"], per="no_such_count") is None
+    # the parent commit has no spans at all
+    assert rd({"passes": 1.0, "window_s": 1.0}, ["entries"]) is None
+    assert rd({**FACTS, "program": None}, ["entries"]) is None
+
+
+def test_trace_scope_reader_is_a_share_of_busy_time():
+    rd = progspans.trace_scope
+    assert rd(FACTS, ["classical_search"]) is None
+    facts = {**FACTS, "trace": {"busy_s": 4.0, "window_s": 8.0}}
+    assert rd(facts, ["classical_search"]) is None    # no scopes read
+    facts["trace"]["scope_s"] = {"classical_search": 1.0,
+                                 "walk_assign": 0.5, "": 0.2}
+    assert rd(facts, ["classical_search"]) == 25.0
+    assert rd(facts, ["nominate_full", "walk_assign"]) == 12.5
+
+
+@pytest.mark.parametrize("metric", progspans.proposed_metrics(),
+                         ids=lambda m: m["name"])
+def test_proposed_metric_has_its_layer_file_and_reads(metric):
+    layer = deployment.load_json("layers", f"{metric['name']}.json")
+    for key in ("name", "layer", "unit", "better", "source", "moves"):
+        assert layer[key] == metric[key], key
+    assert layer["reader"] in progspans.READERS
+    assert layer["reader"] not in readers.READERS or (
+        readers.READERS[layer["reader"]]
+        is progspans.READERS[layer["reader"]])
+    # the accepted benchmark does not list it, and no name collides
+    bench = run.load_benchmark()
+    assert metric["name"] not in {m["name"] for m in bench["per_layer"]}
+    assert metric["layer"] in {m["layer"] for m in bench["per_layer"]}
+    # on a program without spans the reader gives nothing and does not
+    # raise
+    empty = {"passes": 1.0, "window_s": 1.0, "trace": None}
+    assert progspans.READERS[layer["reader"]](
+        empty, **layer.get("args", {})) is None
+
+
+def test_totals_log_weights_the_last_pass_by_its_part():
+    class FakeSpans:
+        def __init__(self):
+            self.t = {}
+            self.c = {}
+
+        def totals(self):
+            return {k: dict(v) for k, v in self.t.items()}
+
+        def counters(self):
+            return {**self.c, "jax_by_span": {}}
+
+    log = progspans.TotalsLog()
+    fake = log.spans = FakeSpans()
+    fake.t = {"quiet": {"s": 100.0, "n": 7, "self_s": 1.0}}  # set-up's
+    log.start()
+    for i in range(3):
+        fake.t["quiet"]["s"] += 2.0
+        fake.t["quiet"]["n"] += 1
+        fake.c["drain_admitted"] = 10 * (i + 1)
+        log.on_pass()
+    prog = log.window(last_part=0.25)
+    assert prog["spans"]["quiet"]["s"] == pytest.approx(4.5)
+    assert prog["spans"]["quiet"]["n"] == pytest.approx(2.25)
+    assert prog["counts"]["drain_admitted"] == pytest.approx(22.5)
+    assert progspans.last_part(
+        [{"t_start": 0.0, "t_end": 4.0}], {"t_end": 1.0}) == 0.25
+    assert progspans.last_part(
+        [{"t_start": 0.0, "t_end": 4.0}], {"t_end": 5.0}) == 1.0
+
+
+def test_self_pieces_names_every_stretch_by_the_innermost_span():
+    got = progspans.self_pieces([
+        ("quiet", 0, 100), ("route", 10, 40), ("solve", 15, 35),
+        ("schedule", 50, 90), ("entries", 60, 80)])
+    assert sorted(got, key=lambda p: p[1]) == [
+        ("quiet", 0, 10), ("route", 10, 15), ("solve", 15, 35),
+        ("route", 35, 40), ("quiet", 40, 50), ("schedule", 50, 60),
+        ("entries", 60, 80), ("schedule", 80, 90), ("quiet", 90, 100)]
+    assert sum(e - s for _n, s, e in got) == 100
+
+
+def test_scope_path_keeps_the_programs_names_only():
+    sp = progspans.scope_path
+    assert sp("jit(solve)/while/body/round_body/vmap(classical_search)"
+              "/while/body/add:") == ("round_body", "classical_search")
+    assert sp("jit(solve)/while/body/round_body/vmap(stage_search)/"
+              "jit(remainder)/jit(_where)/select_n:") == (
+        "round_body", "stage_search")
+    assert sp("jit(solve)/while:") == ()
+    assert sp("jit(delta_scatter)/delta_scatter/scatter:") == (
+        "delta_scatter",)
+    assert progspans.op_kind(
+        "%add_select_fusion.4 = s32[8]{0} fusion(...)") == (
+        "add_select_fusion")
+    assert progspans.op_kind("%while") == "while"
+
+
+def test_reduction_of_the_recorded_sample_with_spans_and_scopes():
+    # three rounds of a 400-step while_loop (7.8 ms each) whose body
+    # runs stage_search under vmap and stage_scan, a 30 ms sleep inside
+    # the program's span ``entries`` and a 20 ms sleep of the
+    # benchmark's between them, in a 184 ms window
+    names = progspans.op_names(SAMPLE)
+    assert len(names) >= 8
+    assert any(v.endswith("vmap(stage_search)/jit(remainder)/select_n:")
+               for v in names.values())
+    r = progspans.reduce_trace(SAMPLE)
+    assert r["devices"] == 1 and abs(r["window_s"] - 0.1841) < 0.001
+    assert 0.0215 < r["busy_s"] < 0.0235
+    assert r["program_spans"] == 51
+    # the gaps carry the name of the program's span the host was in
+    assert [g[0] for g in r["idle_gaps"][:3]] == ["entries"] * 3
+    assert all(0.052 < g[1] < 0.056 for g in r["idle_gaps"][:3])
+    # the operations stand under their scope's name, summed over the
+    # numbered copies a recompile renumbers
+    top = dict(r["device_ops"])
+    assert r["device_ops"][0][0] == (
+        "round_body/stage_search:add_select_fusion")
+    assert 0.0070 < top["round_body/stage_search:add_select_fusion"] < 0.0077
+    assert "round_body/stage_scan:add_remainder_fusion" in top
+    # what XLA's own passes made of the cumsum keeps no scope
+    assert "%fusion.5" in top
+    assert 0.80 < r["scoped_share_of_listed"] < 0.85
+    assert set(r["scope_s"]) == {"stage_search", "stage_scan", ""}
+    assert sum(r["scope_s"].values()) == pytest.approx(r["busy_s"],
+                                                       rel=0.01)
+    facts = {"trace": r}
+    assert 31.0 < progspans.trace_scope(facts, ["stage_search"]) < 34.5
+    assert 48.0 < progspans.trace_scope(facts, ["stage_scan"]) < 51.5
+
+
+def test_first_sample_reads_as_before_under_the_new_reduction():
+    from benchmark import tracered
+
+    old = os.path.join(DATA, "sample.xplane.pb")
+    a = tracered.reduce_trace(old)
+    b = progspans.reduce_trace(old)
+    assert b.pop("program_spans") == 0
+    assert a == b   # no spans, no scopes: nothing is renamed

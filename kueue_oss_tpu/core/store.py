@@ -24,6 +24,7 @@ from kueue_oss_tpu.api.types import (
     Workload,
     WorkloadPriorityClass,
 )
+from kueue_oss_tpu.obs import spans
 
 Event = tuple[str, str, object]  # (verb, kind, obj)
 
@@ -179,6 +180,7 @@ class Store:
             self._emit("delete", "Node", node)
 
     def add_workload(self, wl: Workload) -> None:
+        t0 = spans.start()   # per event: totals only (obs/spans.py)
         with self._lock:
             if wl.priority_class and wl.priority == 0:
                 pc = self.priority_classes.get(wl.priority_class)
@@ -188,7 +190,9 @@ class Store:
             self.workloads[wl.key] = wl
             self._index_workload(wl)
             self._track_finished(wl)
+        # the watchers run here, the queue manager's handler among them
         self._emit("add", "Workload", wl)
+        spans.add_since("store.add", t0)
 
     def update_workload(self, wl: Workload) -> None:
         with self._lock:

@@ -8,8 +8,8 @@ pprofBindAddress (apis/config PprofBindAddress; pkg/config/config_test.go
   /debug/pprof/profile equivalent for the host scheduling path;
 - `Tracer`: lightweight span recording with Chrome-trace JSON export
   (chrome://tracing / Perfetto-loadable, the same workflow used for
-  JAX/XLA device traces), wired into the scheduler's cycle phases via
-  `attach_to_scheduler`.
+  JAX/XLA device traces); `attach_to_scheduler` registers it as a sink
+  of the program's one span primitive (`obs/spans.py`).
 """
 
 from __future__ import annotations
@@ -404,23 +404,17 @@ class DebugServer:
 
 
 def attach_to_scheduler(scheduler, tracer: Tracer) -> None:
-    """Wrap the scheduler's cycle phases in tracer spans: one
-    'schedule' span per cycle with nested 'snapshot' / 'nominate'
-    phases (the reference logs per-phase durations at V(2)). The tracer
-    is also published on the scheduler so the solver engine's drain and
-    imported sidecar spans land in the SAME ring — one merged timeline
-    keyed by cycle id."""
+    """Make ``tracer`` a sink of the program's spans (obs/spans.py) and
+    publish it on the scheduler. Nothing is patched: the scheduler, the
+    engine and the store open their own spans (``quiet`` > ``route`` >
+    ``solver_drain`` > ``export`` / ``solve`` / ``apply`` ...,
+    ``schedule`` > ``snapshot`` / ``nominate`` / ``entries`` /
+    ``flush``), and while a sink is registered each one lands in the
+    tracer's ring with its cycle id, beside the sidecar's and the farm's
+    spans: one merged timeline. The sink is held weakly: dropping the
+    tracer (and the scheduler that publishes it) switches the record
+    off again."""
+    from kueue_oss_tpu.obs import spans
+
     scheduler.tracer = tracer
-    orig_schedule = scheduler.schedule
-    orig_nominate = scheduler._nominate
-
-    def schedule(now=None):
-        with tracer.span("schedule", cycle=scheduler.cycle_count + 1):
-            return orig_schedule(now)
-
-    def nominate(heads, snapshot, now):
-        with tracer.span("nominate", heads=len(heads)):
-            return orig_nominate(heads, snapshot, now)
-
-    scheduler.schedule = schedule
-    scheduler._nominate = nominate
+    spans.add_sink(tracer)

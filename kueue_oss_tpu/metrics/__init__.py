@@ -516,9 +516,40 @@ build_info.set("kueue-oss-tpu-r3", value=1)
 
 # -- solver-specific (new; no reference analog) ------------------------------
 
-solver_cycle_duration_seconds = registry.register(Histogram(
-    "kueue_tpu_solver_cycle_duration_seconds",
-    "Wall time of one batched TPU solve", ("phase",)))
+
+
+class _SpanTotals(Counter):
+    """The span primitive's process-wide totals (obs/spans.py), read at
+    scrape time: ``rate()`` over them is the seconds (or the count) a
+    second that each phase of the served path takes. They replace
+    ``kueue_tpu_solver_cycle_duration_seconds``, which timed ``solve``
+    and ``apply`` a second time beside the ledger."""
+
+    def __init__(self, name: str, help_: str, field: str) -> None:
+        super().__init__(name, help_, ("span",))
+        self._field = field
+
+    def collect(self) -> dict[LabelValues, float]:
+        from kueue_oss_tpu.obs import spans
+
+        return {(k,): float(v[self._field])
+                for k, v in spans.totals().items()}
+
+    def value(self, *label_values: str) -> float:
+        return self.collect().get(self._key(label_values), 0.0)
+
+    def total(self) -> float:
+        return sum(self.collect().values())
+
+
+span_seconds_total = registry.register(_SpanTotals(
+    "kueue_span_seconds_total",
+    "Seconds spent in each span of the served path (obs/spans.py)", "s"))
+span_self_seconds_total = registry.register(_SpanTotals(
+    "kueue_span_self_seconds_total",
+    "Seconds spent in each span outside its child spans", "self_s"))
+span_total = registry.register(_SpanTotals(
+    "kueue_span_total", "Times each span of the served path ran", "n"))
 solver_plan_fallbacks_total = registry.register(Counter(
     "kueue_tpu_solver_plan_fallbacks_total",
     "Solver plans rejected by the host oracle re-check", ()))
@@ -1045,3 +1076,7 @@ def reset_all() -> None:
         s._values = {}  # type: ignore[attr-defined]
         if isinstance(s, Histogram):
             s._exemplars = {}
+    # the span totals are read from their owner at scrape time
+    from kueue_oss_tpu.obs import spans
+
+    spans.reset()

@@ -365,6 +365,7 @@ from kueue_oss_tpu.obs.ledger import (  # noqa: E402
 )
 from kueue_oss_tpu.obs.ledger import ledger as cycle_ledger  # noqa: E402
 from kueue_oss_tpu.obs import devtel  # noqa: E402
+from kueue_oss_tpu.obs import spans  # noqa: E402,F401
 from kueue_oss_tpu.obs.devtel import (  # noqa: E402
     CompileDetector,
     DeepCapture,

@@ -41,6 +41,7 @@ import time
 from typing import Optional
 
 from kueue_oss_tpu import metrics
+from kueue_oss_tpu.obs import spans
 from kueue_oss_tpu.resilience import CooldownPolicy
 
 #: device-delta counter name -> transfer direction (the unification of
@@ -237,6 +238,10 @@ class DeepCapture:
 
                 jax.profiler.start_trace(rec["path"])
                 rec["profiler"] = True
+                # the host's spans lie in the same trace, as
+                # ``kueue:<name>`` annotations beside the device's
+                # operations (obs/spans.py)
+                spans.trace_on("devtel.capture")
             except Exception:
                 rec["profiler"] = False
 
@@ -275,6 +280,7 @@ class DeepCapture:
 
     def _finish(self, rec: dict, t: float) -> None:
         if rec.get("profiler"):
+            spans.trace_off("devtel.capture")
             try:
                 import jax
 

@@ -8,14 +8,16 @@ the decision chain for a cycle join on that id (Gavel,
 arXiv:2008.09213, treats per-round placement latencies as the primary
 health artifact; this is our per-round record).
 
-A host row carries the cycle's phase durations (the same phase names
-the Tracer spans use — ``snapshot`` / ``nominate`` / ``entries`` /
-``flush``), admitted/preempted/skipped counts with per-slug skip
+A row's ``phases`` are the durations of the spans (obs/spans.py) that
+ran under the row's own span, by name: a host row carries ``requeue`` /
+``snapshot`` / ``nominate`` / ``entries`` / ``flush``, admitted/preempted/skipped counts with per-slug skip
 breakdowns, and the solver breaker state at cycle end. A solver row
 carries the chosen arm (host routing's third arm lives in the
 scheduler), the session frame kind (sync/delta/legacy) with its
 payload bytes and session churn stats, donated-buffer accounting
-deltas from the resident device state, and the solve/apply walls.
+deltas from the resident device state, and the drain's spans
+(``export`` / ``encode`` / ``solve`` with ``device_put``, ``dispatch``,
+``wait`` and ``fetch`` inside it / ``apply`` with its parts).
 
 Bounded ring (newest ``max_cycles`` rows), thread-safe, dumpable with
 the same atomic + dir-fsynced discipline as the decision journal, and
@@ -52,13 +54,18 @@ class CycleRecord:
     ``to_dict`` where empty."""
 
     seq: int
+    #: wall clock (``time.time``), for the journal
     ts: float
     cycle: int
+    #: the moment the row was written on the spans' clock
+    #: (``time.perf_counter_ns``, obs/spans.py): host rows, solver rows
+    #: and every span come off this one clock, so a row is placed among
+    #: the spans without converting clocks after the fact
+    mono_ns: int = 0
     kind: str = HOST_CYCLE
     breaker: str = "closed"
     duration_s: float = 0.0
-    #: phase name -> seconds (host rows: snapshot/nominate/entries/
-    #: flush; solver rows: solve/apply)
+    #: span name -> seconds, summed over the row's span (obs/spans.py)
     phases: dict = field(default_factory=dict)
     # -- host cycle outcome counts --------------------------------------
     heads: int = 0
@@ -95,6 +102,8 @@ class CycleRecord:
         d = {"seq": self.seq, "ts": self.ts, "cycle": self.cycle,
              "kind": self.kind, "breaker": self.breaker,
              "durationS": self.duration_s}
+        if self.mono_ns:
+            d["monoNs"] = self.mono_ns
         if self.phases:
             d["phases"] = self.phases
         if self.kind == HOST_CYCLE:
@@ -126,6 +135,7 @@ class CycleRecord:
         return cls(
             seq=int(d.get("seq", 0)), ts=float(d.get("ts", 0.0)),
             cycle=int(d.get("cycle", 0)),
+            mono_ns=int(d.get("monoNs", 0)),
             kind=str(d.get("kind", HOST_CYCLE)),
             breaker=str(d.get("breaker", "closed")),
             duration_s=float(d.get("durationS", 0.0)),
@@ -173,7 +183,8 @@ class CycleLedger:
         if not self.enabled:
             return None
         row = CycleRecord(seq=next(self._seq), ts=self.clock(),
-                          cycle=cycle, kind=kind, **fields)
+                          cycle=cycle, kind=kind,
+                          mono_ns=time.perf_counter_ns(), **fields)
         with self._lock:
             self._ring.append(row)
         metrics.ledger_records_total.inc(kind)

@@ -29,6 +29,7 @@ from kueue_oss_tpu.core.snapshot import (
 )
 from kueue_oss_tpu.core.store import Store
 from kueue_oss_tpu import metrics, obs
+from kueue_oss_tpu.obs import spans
 from kueue_oss_tpu.core.workload_info import (
     WorkloadInfo,
     effective_per_pod_requests,
@@ -198,6 +199,13 @@ class Scheduler:
     # ------------------------------------------------------------------
 
     def schedule(self, now: Optional[float] = None) -> CycleStats:
+        # the cycle's span collects its children's durations: they are
+        # the ledger row's ``phases`` (obs/spans.py, one clock)
+        with spans.span("schedule", cycle=self.cycle_count + 1,
+                        collect=True) as sp:
+            return self._schedule(now, sp)
+
+    def _schedule(self, now: Optional[float], sp) -> CycleStats:
         start = self.clock()
         wall0 = time.monotonic()
         now = now if now is not None else start
@@ -206,10 +214,10 @@ class Scheduler:
         self.queues.current_time = now  # AFS decay reference point
         obs.slo_engine.advance(now)  # windows roll on idle cycles too
         self._cycle_skip_slugs = {}
-        self.requeue_due(now)
-        self._run_second_pass(now)
-
-        heads = self.queues.heads()
+        with spans.span("requeue"):
+            self.requeue_due(now)
+            self._run_second_pass(now)
+            heads = self.queues.heads()
         stats.heads = len(heads)
         if not heads:
             # Still flush gauges for CQs touched by out-of-cycle evictions
@@ -218,24 +226,21 @@ class Scheduler:
             # gauges actually have CQs to report. Empty cycles record no
             # ledger row either — the ledger is a record of work done,
             # and a serve loop's idle polls would churn the ring.
-            for cq_name, counts in (
-                    self.queues.drain_dirty_pending_counts().items()):
-                metrics.report_pending_workloads(cq_name, *counts)
-            if self._cycle_touched_cqs:
-                self._flush_metrics(build_snapshot(self.store), entries=[])
-            self._persist_flush()
+            with spans.span("flush"):
+                for cq_name, counts in (
+                        self.queues.drain_dirty_pending_counts().items()):
+                    metrics.report_pending_workloads(cq_name, *counts)
+                if self._cycle_touched_cqs:
+                    self._flush_metrics(build_snapshot(self.store),
+                                        entries=[])
+                self._persist_flush()
             return stats
 
-        # per-phase walls for the cycle ledger row — the same phase
-        # vocabulary the Tracer spans use, measured on perf_counter so
-        # a ledger row and a Chrome-trace span of the same cycle agree
-        p0 = time.perf_counter()
-        snapshot = build_snapshot(self.store)
-        t_snapshot = time.perf_counter() - p0
+        with spans.span("snapshot"):
+            snapshot = build_snapshot(self.store)
 
-        p1 = time.perf_counter()
-        entries, inadmissible = self._nominate(heads, snapshot, now)
-        t_nominate = time.perf_counter() - p1
+        with spans.span("nominate", heads=len(heads)):
+            entries, inadmissible = self._nominate(heads, snapshot, now)
         stats.inadmissible = len(inadmissible)
         for e in inadmissible:
             # flight recorder: the nomination-stage rejection reason
@@ -248,22 +253,22 @@ class Scheduler:
                 cluster_queue=e.info.cluster_queue,
                 reason=e.inadmissible_msg, reason_slug="inadmissible")
 
-        p2 = time.perf_counter()
-        iterator = self._make_iterator(entries, snapshot)
-        preempted_workloads: dict[str, WorkloadInfo] = {}
-        while iterator.has_next():
-            self._process_entry(iterator.pop(), snapshot,
-                                preempted_workloads, stats, now)
+        with spans.span("entries"):
+            iterator = self._make_iterator(entries, snapshot)
+            preempted_workloads: dict[str, WorkloadInfo] = {}
+            while iterator.has_next():
+                self._process_entry(iterator.pop(), snapshot,
+                                    preempted_workloads, stats, now)
 
-        for e in entries:
-            if e.status not in (ASSUMED, EVICTED):
+            for e in entries:
+                if e.status not in (ASSUMED, EVICTED):
+                    self._requeue_and_update(e)
+            for e in inadmissible:
                 self._requeue_and_update(e)
-        for e in inadmissible:
-            self._requeue_and_update(e)
-        t_entries = time.perf_counter() - p2
 
         stats.duration_s = self.clock() - start
         if stats.admitted:
+            # the router's own cost estimate (behaviour, not a trace):
             # the adaptive solver gate compares against the drain's
             # time.monotonic wall — measure in the same time domain
             # (self.clock may be injected/simulated)
@@ -280,19 +285,17 @@ class Scheduler:
         result = (metrics.CycleResult.SUCCESS if stats.admitted or stats.preempted
                   else metrics.CycleResult.INADMISSIBLE)
         metrics.observe_admission_attempt(result, stats.duration_s)
-        p3 = time.perf_counter()
-        self._flush_metrics(snapshot, entries)
-        self._persist_flush()
+        with spans.span("flush"):
+            self._flush_metrics(snapshot, entries)
+            self._persist_flush()
         ledger = obs.cycle_ledger
         if ledger.enabled:
             ledger.record(
                 self.cycle_count, obs.HOST_CYCLE,
                 breaker=obs.breaker_state_name(),
                 duration_s=stats.duration_s,
-                phases={"snapshot": round(t_snapshot, 6),
-                        "nominate": round(t_nominate, 6),
-                        "entries": round(t_entries, 6),
-                        "flush": round(time.perf_counter() - p3, 6)},
+                phases={k: round(v, 6)
+                        for k, v in (sp.phases or {}).items()},
                 heads=stats.heads, admitted=stats.admitted,
                 preempted=stats.preempted, skipped=stats.skipped,
                 inadmissible=stats.inadmissible,
@@ -553,6 +556,15 @@ class Scheduler:
         groups, admission-scope CQs, weighted fair sharing, oversized
         quantities) fall through to the host cycle loop.
         """
+        if self.solver is None:
+            return False
+        # the router's own time: the gate, the backlog counts, the
+        # lazy-flush materialisation, the cost estimate and the walk
+        # over the plan's keys (``route`` less ``solver_drain``)
+        with spans.span("route", cycle=self.cycle_count + 1):
+            return self._route(now)
+
+    def _route(self, now: Optional[float]) -> bool:
         engine = self._solver_engine()
         if engine is None or not self.queues.has_pending():
             return False
@@ -635,6 +647,7 @@ class Scheduler:
                 self.queues.set_lazy_flush(True)
         try:
             backlog_now = max(1, self.queues.solver_backlog_count())
+            # the router's own cost estimate (behaviour, not a trace)
             t0 = time.monotonic()
             result = engine.drain(now=now if now is not None else 0.0,
                                   verify=True)
@@ -699,6 +712,11 @@ class Scheduler:
         (a frozen clock collapses eviction/admission timestamps into
         ties, which real deployments never see).
         """
+        with spans.span("quiet", cycle=self.cycle_count + 1):
+            return self._run_until_quiet(max_cycles, now, tick)
+
+    def _run_until_quiet(self, max_cycles: int, now: Optional[float],
+                         tick: float) -> int:
         cycles = 0
         prev_probe = None
         while cycles < max_cycles:
@@ -733,7 +751,8 @@ class Scheduler:
         return cycles
 
     def _queue_fingerprint(self):
-        return self.queues.membership_fingerprint()
+        with spans.span("quiet.fingerprint"):
+            return self.queues.membership_fingerprint()
 
     def serve(self, stop, poll: float = 0.05,
               clock=None, backoff=None) -> int:
@@ -846,10 +865,10 @@ class Scheduler:
                     # invocation; the host cycle below mops up the
                     # trickle and anything the solver could not model
                     # or verify.
-                    drained = (self._solver_drain(clock())
-                               if self.solver else False)
-                    pre = self._queue_fingerprint()
-                    stats = self.schedule(now=clock())
+                    with spans.span("quiet", cycle=self.cycle_count + 1):
+                        drained = self._solver_drain(clock())
+                        pre = self._queue_fingerprint()
+                        stats = self.schedule(now=clock())
                     self._last_full_cycle_wall = clock()
                     cycles += 1
             if skip_heavy:
@@ -1649,6 +1668,13 @@ class Scheduler:
 
     def finish_workload(self, key: str, now: float = 0.0) -> None:
         """Mark Finished and release quota (jobframework Finished path)."""
+        t0 = spans.start()   # per event: totals only, no span object
+        try:
+            self._finish_workload(key, now)
+        finally:
+            spans.add_since("store.finish", t0)
+
+    def _finish_workload(self, key: str, now: float) -> None:
         wl = self.store.workloads.get(key)
         if wl is None:
             return

@@ -1006,6 +1006,25 @@ def _tree_nbytes(t) -> int:
     return sum(int(getattr(a, "nbytes", 0)) for a in t)
 
 
+def delta_scatter(buf, idx, vals):
+    """The donated row scatter of a delta epoch, under its own name in
+    a device trace (program ``jit_delta_scatter``, scope
+    ``delta_scatter``)."""
+    import jax
+
+    with jax.named_scope("delta_scatter"):
+        return buf.at[idx].set(vals)
+
+
+def delta_overwrite(buf, vals):
+    """The donated whole-array rewrite of a full sync over resident
+    buffers (program ``jit_delta_overwrite``)."""
+    import jax
+
+    with jax.named_scope("delta_overwrite"):
+        return buf.at[...].set(vals)
+
+
 class DeviceResidentProblem:
     """Padded problem tensors pinned on device across drains.
 
@@ -1174,8 +1193,7 @@ class DeviceResidentProblem:
                 kw = {}
                 if self.mesh_placed and sharding is not None:
                     kw["out_shardings"] = sharding
-                fn = jax.jit(lambda b, v: b.at[...].set(v),
-                             donate_argnums=0, **kw)
+                fn = jax.jit(delta_overwrite, donate_argnums=0, **kw)
                 self._scatter_cache[key] = fn
             out.append(fn(old, new))
         return type(prev)(*out)
@@ -1217,8 +1235,7 @@ class DeviceResidentProblem:
             kw = {}
             if self.mesh_placed and sharding is not None:
                 kw["out_shardings"] = sharding
-            fn = jax.jit(lambda b, i, v: b.at[i].set(v),
-                         donate_argnums=0, **kw)
+            fn = jax.jit(delta_scatter, donate_argnums=0, **kw)
             self._scatter_cache[key] = fn
         return fn(buf, idx, vals)
 

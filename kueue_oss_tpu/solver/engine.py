@@ -9,8 +9,6 @@ verifying solver plans before assuming, SURVEY.md §7 step 4).
 
 from __future__ import annotations
 
-import contextlib
-import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -27,7 +25,7 @@ from kueue_oss_tpu.core.queue_manager import QueueManager
 from kueue_oss_tpu.core.store import Store
 from kueue_oss_tpu.core.workload_info import WorkloadInfo
 from kueue_oss_tpu import metrics, obs, resilience
-from kueue_oss_tpu.obs import devtel
+from kueue_oss_tpu.obs import devtel, spans
 from kueue_oss_tpu.solver.delta import (
     DeviceResidentProblem,
     HostDeltaSession,
@@ -106,6 +104,10 @@ class SolverEngine:
         #: between two compiled programs — recompiles are monotone
         #: crossings only
         self._pad_hwm = 0
+        #: the last export's columnar walk/scatter split and dirty-row
+        #: stats, for the drain's ledger row (_note_export_stats)
+        self._export_split: dict = {}
+        self._export_stats: dict = {}
         #: production device-TAS path: TAS CQs whose backlog shapes the
         #: extended placer supports drain through the quota kernel and
         #: place on device (solver/tas_engine.py); set False to force
@@ -128,10 +130,6 @@ class SolverEngine:
         #: never initializes a backend to size lanes for a device it
         #: does not own.
         self.h_work_budget = None
-        #: debugger.Tracer for drain spans; when unset, the scheduler's
-        #: attached tracer (attach_to_scheduler) is used, so host cycle
-        #: spans and solver/sidecar spans land in ONE Chrome trace
-        self.tracer = None
         #: total drains started; the obs cycle id for engines used
         #: standalone (no scheduler whose cycle_count anchors the drain)
         self.drain_count = 0
@@ -242,11 +240,6 @@ class SolverEngine:
         #: every completed full drain re-arms its fences — a full
         #: solve is the oracle-parity baseline boundary
         self.streaming = None
-
-    def _tracer(self):
-        if self.tracer is not None:
-            return self.tracer
-        return getattr(self.scheduler, "tracer", None)
 
     def supported(self) -> bool:
         """Whether the drain can run on-device.
@@ -479,9 +472,10 @@ class SolverEngine:
                 reason_slug="breaker_open")
             raise SolverUnavailable(
                 "solver backend breaker is open (cooling down)")
-        tracer = self._tracer()
-        with (tracer.span("solver_drain", cycle=self._drain_cycle)
-              if tracer is not None else contextlib.nullcontext()):
+        # the drain's span collects its children's durations: they are
+        # the solver ledger row's ``phases`` (obs/spans.py)
+        with spans.span("solver_drain", cycle=self._drain_cycle,
+                        collect=True):
             completed = False
             if self.streaming is not None:
                 # mark which fences this solve's export can cover:
@@ -501,7 +495,8 @@ class SolverEngine:
                 # group-committed before the scheduler builds on them
                 persistence = getattr(self.store, "persistence", None)
                 if persistence is not None:
-                    persistence.flush()
+                    with spans.span("record"):
+                        persistence.flush()
                 if self.streaming is not None:
                     # full-solve boundary: the streaming fences reset
                     # against the post-solve store (a failed drain
@@ -514,14 +509,15 @@ class SolverEngine:
                         self.streaming.note_solve_abort()
 
     def _drain(self, now: float, verify: bool) -> DrainResult:
-        pending = self.pending_backlog()
-        if self.needs_full_kernel(pending):
+        with spans.span("backlog"):
+            pending = self.pending_backlog()
+            full = self.needs_full_kernel(pending)
+        if full:
             return self._drain_full(now, verify=verify, pending=pending)
         result = DrainResult()
-        self._drain_phases = {}
-        te = time.monotonic()
-        problem, pending = self.export(pending)
-        self._note_export_phase(time.monotonic() - te)
+        with spans.span("export"):
+            problem, pending = self.export(pending)
+            self._note_export_stats()
         if problem.n_workloads == 0:
             return result
         # pad_workloads rebuilds the dataclass, so the columnar hint
@@ -535,41 +531,40 @@ class SolverEngine:
         problem, frame = self._session_encode("lean", problem, hint=hint)
         dev0 = self._device_totals()
 
-        t0 = time.monotonic()
-        if self.remote is not None:
-            (admitted, opt, admit_round, parked, rounds,
-             _usage) = self._dispatch_remote(
-                problem, 6, frame, "lean", verify, full=False)
-        else:
-            (admitted, opt, admit_round, parked, rounds,
-             _usage) = self._local_solve(problem, frame, full=False,
-                                         n_live=n_live)
-        admitted = np.asarray(admitted)
-        opt = np.asarray(opt)
-        admit_round = np.asarray(admit_round)
-        parked = np.asarray(parked)
-        if self.remote is not None:
-            # guard IMPORTED plans only: the in-process kernel is
-            # trusted (a local bug should fail tests loudly, not
-            # silently degrade), and the local hot path stays free of
-            # the O(W) validation passes
-            self._check_plan(problem, admitted, opt, admit_round,
-                             parked, rounds=rounds, full=False)
-        result.rounds = int(rounds)
-        result.solver_time_s = time.monotonic() - t0
-        metrics.solver_cycle_duration_seconds.observe(
-            "solve", value=result.solver_time_s)
+        # forced: DrainResult returns these two durations
+        with spans.span("solve", force=True) as sp:
+            if self.remote is not None:
+                (admitted, opt, admit_round, parked, rounds,
+                 _usage) = self._dispatch_remote(
+                    problem, 6, frame, "lean", verify, full=False)
+            else:
+                (admitted, opt, admit_round, parked, rounds,
+                 _usage) = self._local_solve(problem, frame, full=False,
+                                             n_live=n_live)
+            admitted = np.asarray(admitted)
+            opt = np.asarray(opt)
+            admit_round = np.asarray(admit_round)
+            parked = np.asarray(parked)
+            if self.remote is not None:
+                # guard IMPORTED plans only: the in-process kernel is
+                # trusted (a local bug should fail tests loudly, not
+                # silently degrade), and the local hot path stays free
+                # of the O(W) validation passes
+                self._check_plan(problem, admitted, opt, admit_round,
+                                 parked, rounds=rounds, full=False)
+            result.rounds = int(rounds)
+        result.solver_time_s = sp.seconds
 
-        t1 = time.monotonic()
-        self._apply_plan(problem, admitted, opt, admit_round, parked, now,
-                         result, verify=verify)
-        result.apply_time_s = time.monotonic() - t1
-        metrics.solver_cycle_duration_seconds.observe(
-            "apply", value=result.apply_time_s)
-        self._ledger_record(
-            result, frame, "lean", dev0,
-            parked_n=int(np.asarray(
-                parked[:problem.n_workloads]).astype(bool).sum()))
+        with spans.span("apply", force=True) as sp:
+            self._apply_plan(problem, admitted, opt, admit_round, parked,
+                             now, result, verify=verify)
+        result.apply_time_s = sp.seconds
+        spans.count("drain_admitted", result.admitted)
+        with spans.span("record"):
+            self._ledger_record(
+                result, frame, "lean", dev0,
+                parked_n=int(np.asarray(
+                    parked[:problem.n_workloads]).astype(bool).sum()))
         return result
 
     # -- cycle ledger (obs/ledger.py) --------------------------------------
@@ -638,14 +633,14 @@ class SolverEngine:
                 sess_obj = self._delta_sessions.get(kind)
                 if sess_obj is not None:
                     frame_bytes = sess_obj.last_sync_wire_bytes()
-        phases = {"solve": round(result.solver_time_s, 6),
-                  "apply": round(result.apply_time_s, 6)}
-        # export/encode/device_put walls + the columnar walk/scatter
-        # split, accumulated by _note_export_phase/_session_encode/
-        # _local_tensors over this drain
-        for k, v in (getattr(self, "_drain_phases", None) or {}).items():
+        # the durations of the drain's spans so far, by name (backlog,
+        # export, encode, solve with device_put / dispatch / wait /
+        # fetch inside it, apply and its parts), and the columnar
+        # export's own walk/scatter split
+        phases = {k: round(v, 6) for k, v in spans.collected().items()}
+        for k, v in self._export_split.items():
             phases[k] = round(v, 6)
-        session.update(getattr(self, "_export_stats", None) or {})
+        session.update(self._export_stats)
         # farm tenancy attribution (docs/FEDERATION.md): ledger rows
         # from a control plane sharing a multi-tenant solver farm carry
         # the tenant id its frames were billed under
@@ -953,14 +948,16 @@ class SolverEngine:
         try:
             if self.solve_fault_hook is not None:
                 self.solve_fault_hook("relax")
-            t0 = _time.monotonic()
+            t0 = _time.monotonic()   # for _note_arm_wall
             # solve_relaxed itself falls back to the single-chip LP
             # when the padded axis does not shard evenly
             mesh = self._mesh()
-            out, stats = relax.solve_relaxed(
-                problem, iters=self.relax_iters,
-                threshold=self.relax_support_threshold, mesh=mesh,
-                pad_to=self._relax_pad_hwm)
+            # the relaxed arm uploads, runs and fetches inside one call
+            with spans.span("dispatch"):
+                out, stats = relax.solve_relaxed(
+                    problem, iters=self.relax_iters,
+                    threshold=self.relax_support_threshold, mesh=mesh,
+                    pad_to=self._relax_pad_hwm)
             wall = _time.monotonic() - t0
         except Exception as e:
             self._note_relax_failure(e, "relax_error")
@@ -1040,18 +1037,22 @@ class SolverEngine:
                 # hiccup into a discarded plan + tripped mesh
                 if self.solve_fault_hook is not None:
                     self.solve_fault_hook("mesh")
+                # the router's own cost estimate (behaviour, not a
+                # trace): _note_arm_wall
                 t0 = _time.monotonic()
                 tensors = self._local_tensors(problem, frame, full=full,
                                               mesh=mesh)
-                if full:
-                    from kueue_oss_tpu.solver.full_kernels import (
-                        solve_backlog_full,
-                    )
+                with spans.span("dispatch"):
+                    if full:
+                        from kueue_oss_tpu.solver.full_kernels import (
+                            solve_backlog_full,
+                        )
 
-                    out = solve_backlog_full(tensors, mesh=mesh, **caps)
-                else:
-                    out = meshutil.lean_mesh_solver(mesh)(tensors)
-                out = tuple(np.asarray(a) for a in out)
+                        out = solve_backlog_full(tensors, mesh=mesh,
+                                                 **caps)
+                    else:
+                        out = meshutil.lean_mesh_solver(mesh)(tensors)
+                out = self._fetch(out)
                 wall = _time.monotonic() - t0
             except Exception as e:
                 self._note_mesh_failure(e, kind)
@@ -1071,17 +1072,18 @@ class SolverEngine:
         try:
             if self.solve_fault_hook is not None:
                 self.solve_fault_hook("single")
-            t0 = _time.monotonic()
+            t0 = _time.monotonic()   # for _note_arm_wall, as above
             tensors = self._local_tensors(problem, frame, full=full)
-            if full:
-                from kueue_oss_tpu.solver.full_kernels import (
-                    solve_backlog_full,
-                )
+            with spans.span("dispatch"):
+                if full:
+                    from kueue_oss_tpu.solver.full_kernels import (
+                        solve_backlog_full,
+                    )
 
-                out = solve_backlog_full(tensors, **caps)
-            else:
-                out = solve_backlog(tensors)
-            out = tuple(np.asarray(a) for a in out)
+                    out = solve_backlog_full(tensors, **caps)
+                else:
+                    out = solve_backlog(tensors)
+            out = self._fetch(out)
         except Exception as e:
             # the single-chip arm died too (whole accelerator gone):
             # degrade the round to host cycles, counted, never silent
@@ -1106,6 +1108,19 @@ class SolverEngine:
         metrics.solver_mesh_devices.set(value=0)
         self._clear_device_error()
         return out
+
+    @staticmethod
+    def _fetch(out) -> tuple:
+        """The device's work, then the copy to the host, as two spans.
+        Waiting first adds no synchronisation: ``np.asarray`` blocked
+        on the same buffers already; it only parts the device's time
+        (``wait``) from the transfer's (``fetch``)."""
+        import jax
+
+        with spans.span("wait"):
+            jax.block_until_ready(out)
+        with spans.span("fetch"):
+            return tuple(np.asarray(a) for a in out)
 
     # -- delta-sync sessions + pipelined dispatch --------------------------
 
@@ -1141,27 +1156,22 @@ class SolverEngine:
         self._delta_sessions.clear()
         self._device_states.clear()
 
-    def _note_export_phase(self, wall_s: float) -> None:
-        """Fold one export's wall + the columnar view's walk/scatter
-        split and dirty-row counts into this drain's phase breakdown
-        (ledger satellite: export cost must be attributable)."""
-        phases = getattr(self, "_drain_phases", None)
-        if phases is None:
-            phases = self._drain_phases = {}
-        phases["export"] = phases.get("export", 0.0) + wall_s
+    def _note_export_stats(self) -> None:
+        """After an export: the columnar view's own walk/scatter split
+        and dirty-row counts for this drain's ledger row (the export's
+        wall is the ``export`` span's)."""
         col = getattr(self.export_cache, "columnar", None)
         stats = getattr(col, "last_stats", None) or {}
         if stats:
-            phases["export_walk"] = (phases.get("export_walk", 0.0)
-                                     + stats.get("walk_s", 0.0))
-            phases["export_scatter"] = (
-                phases.get("export_scatter", 0.0)
-                + stats.get("scatter_s", 0.0))
+            self._export_split = {
+                "export_walk": stats.get("walk_s", 0.0),
+                "export_scatter": stats.get("scatter_s", 0.0)}
             self._export_stats = {
                 "export_mode": stats.get("mode", ""),
                 "export_dirty_rows": int(stats.get("dirty_rows", 0)),
                 "export_rows": int(stats.get("rows", 0))}
         else:
+            self._export_split = {}
             self._export_stats = {}
 
     def _session_encode(self, kind: str, problem: SolverProblem,
@@ -1203,12 +1213,8 @@ class SolverEngine:
         # local path, so fast-path frames may carry the cheap chained
         # checksum instead of an O(W) crc per drain
         sess.cheap_checksum = self.remote is None
-        t0 = time.monotonic()
-        slotted, frame = sess.advance(problem, hint=hint)
-        phases = getattr(self, "_drain_phases", None)
-        if phases is not None:
-            phases["encode"] = (phases.get("encode", 0.0)
-                                + time.monotonic() - t0)
+        with spans.span("encode"):
+            slotted, frame = sess.advance(problem, hint=hint)
         if frame is not None and frame.full_reason == "interleave_migration":
             metrics.solver_resync_total.inc("interleave_migration")
         return slotted, frame
@@ -1222,15 +1228,9 @@ class SolverEngine:
         resident state lives sharded over the ``wl`` axis; mesh and
         single-chip arms keep separate resident copies so arm flips
         cannot corrupt each other's donated buffers."""
-        t0 = time.monotonic()
-        try:
+        with spans.span("device_put"):
             return self._local_tensors_inner(problem, frame, full=full,
                                              mesh=mesh)
-        finally:
-            phases = getattr(self, "_drain_phases", None)
-            if phases is not None:
-                phases["device_put"] = (phases.get("device_put", 0.0)
-                                        + time.monotonic() - t0)
 
     def _local_tensors_inner(self, problem: SolverProblem, frame, *,
                              full: bool, mesh=None):
@@ -1365,17 +1365,16 @@ class SolverEngine:
 
     def _import_sidecar_spans(self) -> None:
         """Merge the sidecar's solve spans (returned in the response
-        header) into the host tracer. The two processes have unrelated
-        perf_counter origins, so spans are END-ALIGNED at the moment the
+        header) into the span sinks (obs/spans.py: an attached Tracer).
+        The two processes have unrelated perf_counter origins, so spans are END-ALIGNED at the moment the
         response arrived — the duration and the shared cycle id are the
         signal; the sub-millisecond start skew is not."""
-        tracer = self._tracer()
-        spans = getattr(self.remote, "last_spans", None)
-        if tracer is None or not spans:
+        echoed = getattr(self.remote, "last_spans", None)
+        if not echoed or not spans.tracing():
             return
-        now_us = int(tracer.clock() * 1e6)
+        now_us = spans.now() // 1000
         tenant = str(getattr(self.remote, "tenant", "") or "")
-        for sp in spans:
+        for sp in echoed:
             # span import is best-effort diagnostics: a version-skewed
             # or garbled spans entry must not abort the drain (the plan
             # itself is separately sanity-guarded)
@@ -1395,11 +1394,11 @@ class SolverEngine:
                 # shared tid=0 pile-up
                 src = str(dict(sp.get("args") or {}).get("source", "")
                           or f"sidecar:{tenant or 'solver'}")
-                tracer.add_span(str(sp.get("name", "sidecar_solve")),
-                                now_us - skew_us - dur_us, dur_us,
-                                source=src, **args)
                 if tenant:
-                    tracer.track(src, tenant=tenant)
+                    args.setdefault("tenant", tenant)
+                spans.external(str(sp.get("name", "sidecar_solve")),
+                               now_us - skew_us - dur_us, dur_us,
+                               source=src, **args)
             except Exception:
                 continue
 
@@ -1526,77 +1525,89 @@ class SolverEngine:
         # call (SURVEY.md §7 step 4 verify-then-assume pattern).
         pre = self._take_prework()
         wl_of = pre.get("wl_of")
-        adm_ws = np.nonzero(admitted[:-1])[0]
-        order = adm_ws[np.argsort(admit_round[adm_ws], kind="stable")]
-        candidates = []
-        declared_of: dict[str, set] = {}
-        for w in order:
-            key = problem.wl_keys[w]
-            wl = (wl_of.get(key) if wl_of is not None
-                  else self.store.workloads.get(key))
-            if wl is None or wl.is_quota_reserved or not wl.active:
-                continue
-            cq_name = problem.cq_names[problem.wl_cqid[w]]
-            flavor = problem.cq_option_flavors[cq_name][opt[w]]
-            info = WorkloadInfo(wl, cluster_queue=cq_name)
-            declared = declared_of.get(cq_name)
-            if declared is None:
-                declared = {
-                    r for rg in
-                    self.store.cluster_queues[cq_name].resource_groups
-                    for r in rg.covered_resources}
-                declared_of[cq_name] = declared
-            plan_usage: dict[tuple[str, str], int] = {}
-            for psr in info.total_requests:
-                for r, q in psr.requests.items():
-                    if r not in declared:
-                        continue  # QuotaCheckStrategy=IgnoreUndeclared
-                    fr = (flavor, r)
-                    plan_usage[fr] = plan_usage.get(fr, 0) + q
-            candidates.append((wl, cq_name, flavor, info, plan_usage))
+        with spans.span("apply.decode"):
+            adm_ws = np.nonzero(admitted[:-1])[0]
+            order = adm_ws[np.argsort(admit_round[adm_ws], kind="stable")]
+            candidates = []
+            declared_of: dict[str, set] = {}
+            for w in order:
+                key = problem.wl_keys[w]
+                wl = (wl_of.get(key) if wl_of is not None
+                      else self.store.workloads.get(key))
+                if wl is None or wl.is_quota_reserved or not wl.active:
+                    continue
+                cq_name = problem.cq_names[problem.wl_cqid[w]]
+                flavor = problem.cq_option_flavors[cq_name][opt[w]]
+                info = WorkloadInfo(wl, cluster_queue=cq_name)
+                declared = declared_of.get(cq_name)
+                if declared is None:
+                    declared = {
+                        r for rg in
+                        self.store.cluster_queues[cq_name].resource_groups
+                        for r in rg.covered_resources}
+                    declared_of[cq_name] = declared
+                plan_usage: dict[tuple[str, str], int] = {}
+                for psr in info.total_requests:
+                    for r, q in psr.requests.items():
+                        if r not in declared:
+                            continue  # QuotaCheckStrategy=IgnoreUndeclared
+                        fr = (flavor, r)
+                        plan_usage[fr] = plan_usage.get(fr, 0) + q
+                candidates.append((wl, cq_name, flavor, info, plan_usage))
 
-        candidates, topo_of = self._compute_tas_assignments(
-            candidates, snapshot=pre.get("snapshot"))
+            candidates, topo_of = self._compute_tas_assignments(
+                candidates, snapshot=pre.get("snapshot"))
 
-        if verify and candidates:
-            # Verify-then-fallback (scheduler.go:427 fits re-check): plan
-            # entries the oracle rejects are not committed — those
-            # workloads stay queued for the host scheduler path. The
-            # sequential fits/add_usage walk runs in native code when the
-            # toolchain is available (kueue_oss_tpu/native/oracle.cpp).
-            # The snapshot comes from the pipelined-dispatch prework
-            # when it overlapped the solve (no mutations since export).
-            from kueue_oss_tpu.core.snapshot import build_snapshot
-            from kueue_oss_tpu.native import BatchOracle
+        ok = self._verify_candidates(candidates, verify,
+                                     snapshot=pre.get("snapshot"))
 
-            snapshot = pre.get("snapshot") or build_snapshot(self.store)
-            oracle = BatchOracle(snapshot.forest.cqs)
-            ok = oracle.verify_and_apply(
-                [(cq_name, usage)
-                 for _, cq_name, _, _, usage in candidates])
-        else:
-            ok = np.ones(len(candidates), dtype=np.uint8)
-
-        for passed, (wl, cq_name, flavor, info, _) in zip(ok, candidates):
-            if not passed:
-                metrics.solver_plan_fallbacks_total.inc()
-                obs.recorder.record(
-                    obs.SOLVER_FALLBACK, wl.key, cycle=self._drain_cycle,
-                    cluster_queue=cq_name, path=obs.SOLVER,
-                    reason="host oracle re-check rejected the plan entry;"
-                           " workload stays queued for the host cycle",
-                    reason_slug="oracle_rejected")
-                continue
-            flavor_of = {r: flavor for psr in info.total_requests
-                         for r in psr.requests}
-            self._commit_admission(wl, cq_name, flavor_of, info, now,
-                                   result, topology=topo_of.get(wl.key))
+        with spans.span("apply.commit"):
+            for passed, (wl, cq_name, flavor, info, _) in zip(
+                    ok, candidates):
+                if not passed:
+                    self._record_rejected(wl, cq_name)
+                    continue
+                flavor_of = {r: flavor for psr in info.total_requests
+                             for r in psr.requests}
+                self._commit_admission(wl, cq_name, flavor_of, info, now,
+                                       result,
+                                       topology=topo_of.get(wl.key))
         # Mirror the solver's inadmissible-parking decisions host-side;
         # StrictFIFO blocked heads (not parked) stay in their heaps.
-        for w in np.nonzero(parked[:problem.n_workloads])[0]:
-            cq_name = problem.cq_names[problem.wl_cqid[w]]
-            self.queues.queues[cq_name].park(problem.wl_keys[w])
-            self._record_parked(problem.wl_keys[w], cq_name)
+        with spans.span("apply.park"):
+            for w in np.nonzero(parked[:problem.n_workloads])[0]:
+                cq_name = problem.cq_names[problem.wl_cqid[w]]
+                self.queues.queues[cq_name].park(problem.wl_keys[w])
+                self._record_parked(problem.wl_keys[w], cq_name)
+
+    def _verify_candidates(self, candidates, verify: bool, snapshot=None):
+        """Verify-then-fallback (scheduler.go:427 fits re-check): plan
+        entries the oracle rejects are not committed — those workloads
+        stay queued for the host scheduler path. The sequential
+        fits/add_usage walk runs in native code when the toolchain is
+        available (kueue_oss_tpu/native/oracle.cpp). ``snapshot`` comes
+        from the pipelined-dispatch prework when it overlapped the
+        solve (no mutations since export)."""
+        if not (verify and candidates):
+            return np.ones(len(candidates), dtype=np.uint8)
+        from kueue_oss_tpu.core.snapshot import build_snapshot
+        from kueue_oss_tpu.native import BatchOracle
+
+        with spans.span("apply.verify"):
+            snapshot = snapshot or build_snapshot(self.store)
+            oracle = BatchOracle(snapshot.forest.cqs)
+            return oracle.verify_and_apply(
+                [(cq_name, usage)
+                 for _, cq_name, _, _, usage in candidates])
+
+    def _record_rejected(self, wl, cq_name: str) -> None:
+        metrics.solver_plan_fallbacks_total.inc()
+        obs.recorder.record(
+            obs.SOLVER_FALLBACK, wl.key, cycle=self._drain_cycle,
+            cluster_queue=cq_name, path=obs.SOLVER,
+            reason="host oracle re-check rejected the plan entry;"
+                   " workload stays queued for the host cycle",
+            reason_slug="oracle_rejected")
 
     def _record_parked(self, key: str, cq_name: str) -> None:
         obs.recorder.record(
@@ -1712,8 +1723,73 @@ class SolverEngine:
         admissions in (round, entry-order), then parking decisions.
         """
         result = DrainResult()
-        if pending is None:
-            pending = self.pending_backlog()
+        with spans.span("backlog"):
+            if pending is None:
+                pending = self.pending_backlog()
+            parked_map = self._parked_map()
+        with spans.span("export"):
+            problem = export_problem(self.store, pending,
+                                     include_admitted=True,
+                                     parked=parked_map,
+                                     afs=self.queues.afs, now=now,
+                                     cache=self.export_cache)
+            self._note_export_stats()
+        if problem.n_workloads == 0:
+            return result
+        hint = getattr(problem, "_columnar_hint", None)
+        g_max = int(problem.cq_ngroups.max())
+        h_max, p_max = self._size_caps(problem)
+        n_live = problem.n_workloads
+        self._pad_hwm = max(self._pad_hwm,
+                            pow2(max(problem.n_workloads, self.pad_to)))
+        problem = pad_workloads(problem, self._pad_target())
+        problem, frame = self._session_encode("full", problem, hint=hint)
+        dev0 = self._device_totals()
+
+        # forced: DrainResult returns these two durations
+        with spans.span("solve", force=True) as sp:
+            if self.remote is not None:
+                (admitted, opt, admit_round, parked, rounds, _usage,
+                 _wl_usage, victim_reason) = self._dispatch_remote(
+                    problem, 8, frame, "full", verify, full=True,
+                    g_max=g_max, h_max=h_max, p_max=p_max,
+                    fs_enabled=self.enable_fair_sharing)
+            else:
+                (admitted, opt, admit_round, parked, rounds, _usage,
+                 _wl_usage, victim_reason) = self._local_solve(
+                    problem, frame, full=True, n_live=n_live,
+                    g_max=g_max, h_max=h_max, p_max=p_max,
+                    fs_enabled=self.enable_fair_sharing)
+            admitted = np.asarray(admitted)
+            opt = np.asarray(opt)
+            admit_round = np.asarray(admit_round)
+            parked = np.asarray(parked)
+            victim_reason = np.asarray(victim_reason)
+            if self.remote is not None:
+                # imported plans only (see the lean drain's note)
+                self._check_plan(problem, admitted, opt, admit_round,
+                                 parked, victim_reason=victim_reason,
+                                 rounds=rounds, full=True)
+            result.rounds = int(rounds)
+        result.solver_time_s = sp.seconds
+
+        with spans.span("apply", force=True) as sp:
+            self._apply_full_plan(problem, admitted, opt, admit_round,
+                                  parked, victim_reason, now, result,
+                                  verify=verify)
+        result.apply_time_s = sp.seconds
+        spans.count("drain_admitted", result.admitted)
+        W = problem.n_workloads
+        with spans.span("record"):
+            self._ledger_record(
+                result, frame, "full", dev0,
+                parked_n=int((np.asarray(parked[:W]).astype(bool)
+                              & ~np.asarray(admitted[:W]).astype(bool)
+                              ).sum()))
+        return result
+
+    def _parked_map(self) -> dict[str, list[WorkloadInfo]]:
+        """Still-parked entries the full export carries as parked0."""
         parked_map: dict[str, list[WorkloadInfo]] = {}
         for name, q in self.queues.queues.items():
             if not q.inadmissible or (
@@ -1728,65 +1804,7 @@ class SolverEngine:
                              for ps in i.obj.podsets)]
             if infos:
                 parked_map[name] = infos
-        self._drain_phases = {}
-        te = time.monotonic()
-        problem = export_problem(self.store, pending,
-                                 include_admitted=True, parked=parked_map,
-                                 afs=self.queues.afs, now=now,
-                                 cache=self.export_cache)
-        self._note_export_phase(time.monotonic() - te)
-        if problem.n_workloads == 0:
-            return result
-        hint = getattr(problem, "_columnar_hint", None)
-        g_max = int(problem.cq_ngroups.max())
-        h_max, p_max = self._size_caps(problem)
-        n_live = problem.n_workloads
-        self._pad_hwm = max(self._pad_hwm,
-                            pow2(max(problem.n_workloads, self.pad_to)))
-        problem = pad_workloads(problem, self._pad_target())
-        problem, frame = self._session_encode("full", problem, hint=hint)
-        dev0 = self._device_totals()
-
-        t0 = time.monotonic()
-        if self.remote is not None:
-            (admitted, opt, admit_round, parked, rounds, _usage,
-             _wl_usage, victim_reason) = self._dispatch_remote(
-                problem, 8, frame, "full", verify, full=True,
-                g_max=g_max, h_max=h_max, p_max=p_max,
-                fs_enabled=self.enable_fair_sharing)
-        else:
-            (admitted, opt, admit_round, parked, rounds, _usage,
-             _wl_usage, victim_reason) = self._local_solve(
-                problem, frame, full=True, n_live=n_live, g_max=g_max,
-                h_max=h_max, p_max=p_max,
-                fs_enabled=self.enable_fair_sharing)
-        admitted = np.asarray(admitted)
-        opt = np.asarray(opt)
-        admit_round = np.asarray(admit_round)
-        parked = np.asarray(parked)
-        victim_reason = np.asarray(victim_reason)
-        if self.remote is not None:
-            # imported plans only (see the lean drain's note)
-            self._check_plan(problem, admitted, opt, admit_round,
-                             parked, victim_reason=victim_reason,
-                             rounds=rounds, full=True)
-        result.rounds = int(rounds)
-        result.solver_time_s = time.monotonic() - t0
-        metrics.solver_cycle_duration_seconds.observe(
-            "solve", value=result.solver_time_s)
-
-        t1 = time.monotonic()
-        self._apply_full_plan(problem, admitted, opt, admit_round, parked,
-                              victim_reason, now, result, verify=verify)
-        result.apply_time_s = time.monotonic() - t1
-        metrics.solver_cycle_duration_seconds.observe(
-            "apply", value=result.apply_time_s)
-        W = problem.n_workloads
-        self._ledger_record(
-            result, frame, "full", dev0,
-            parked_n=int((np.asarray(parked[:W]).astype(bool)
-                          & ~np.asarray(admitted[:W]).astype(bool)).sum()))
-        return result
+        return parked_map
 
     def _evictor(self):
         """Host scheduler used purely for its eviction state machine."""
@@ -1824,85 +1842,78 @@ class SolverEngine:
         # 1) evictions: initially-admitted workloads that lost their
         #    admission, or were evicted mid-drain and re-admitted with a
         #    (possibly different) flavor (admit_round >= 0).
-        evictor = self._evictor()
-        evict_ws = np.nonzero(
-            wl_admitted0[:W]
-            & ~(admitted[:W] & (admit_round[:W] < 0)))[0]
-        for w in evict_ws:
-            key = problem.wl_keys[w]
-            wl = lookup(key)
-            if wl is None or not wl.is_quota_reserved:
-                continue
-            reason = reason_of.get(int(victim_reason[w]),
-                                   IN_CLUSTER_QUEUE)
-            evictor.evict_workload(
-                key, reason="Preempted",
-                message="Preempted by the solver drain plan",
-                now=now, preemption_reason=reason,
-                decision_path=obs.SOLVER,
-                decision_cycle=self._drain_cycle)
-            if not admitted[w]:
-                result.evicted += 1
-                result.evicted_keys.append(key)
+        with spans.span("apply.evict"):
+            evictor = self._evictor()
+            evict_ws = np.nonzero(
+                wl_admitted0[:W]
+                & ~(admitted[:W] & (admit_round[:W] < 0)))[0]
+            for w in evict_ws:
+                key = problem.wl_keys[w]
+                wl = lookup(key)
+                if wl is None or not wl.is_quota_reserved:
+                    continue
+                reason = reason_of.get(int(victim_reason[w]),
+                                       IN_CLUSTER_QUEUE)
+                evictor.evict_workload(
+                    key, reason="Preempted",
+                    message="Preempted by the solver drain plan",
+                    now=now, preemption_reason=reason,
+                    decision_path=obs.SOLVER,
+                    decision_cycle=self._drain_cycle)
+                if not admitted[w]:
+                    result.evicted += 1
+                    result.evicted_keys.append(key)
 
         # 2) admissions in (round, entry-order); per-group flavor decode.
-        adm_ws = np.nonzero(admitted[:W] & (admit_round[:W] >= 0))[0]
-        order = adm_ws[np.argsort(admit_round[adm_ws], kind="stable")]
-        candidates = []
-        for w in order:
-            key = problem.wl_keys[w]
-            wl = lookup(key)
-            if wl is None or wl.is_quota_reserved or not wl.active:
-                continue
-            cq_name = problem.cq_names[problem.wl_cqid[w]]
-            rg_of = problem.cq_resource_group[cq_name]
-            opts = problem.cq_option_flavors[cq_name]
-            info = WorkloadInfo(wl, cluster_queue=cq_name)
-            flavor_of = {
-                r: opts[opt[w, g]] for r, g in rg_of.items()}
-            plan_usage: dict[tuple[str, str], int] = {}
-            for psr in info.total_requests:
-                for r, q in psr.requests.items():
-                    if r not in flavor_of:
-                        continue  # QuotaCheckStrategy=IgnoreUndeclared
-                    fr = (flavor_of[r], r)
-                    plan_usage[fr] = plan_usage.get(fr, 0) + q
-            candidates.append((wl, cq_name, flavor_of, info, plan_usage))
+        with spans.span("apply.decode"):
+            adm_ws = np.nonzero(admitted[:W] & (admit_round[:W] >= 0))[0]
+            order = adm_ws[np.argsort(admit_round[adm_ws], kind="stable")]
+            candidates = []
+            for w in order:
+                key = problem.wl_keys[w]
+                wl = lookup(key)
+                if wl is None or wl.is_quota_reserved or not wl.active:
+                    continue
+                cq_name = problem.cq_names[problem.wl_cqid[w]]
+                rg_of = problem.cq_resource_group[cq_name]
+                opts = problem.cq_option_flavors[cq_name]
+                info = WorkloadInfo(wl, cluster_queue=cq_name)
+                flavor_of = {
+                    r: opts[opt[w, g]] for r, g in rg_of.items()}
+                plan_usage: dict[tuple[str, str], int] = {}
+                for psr in info.total_requests:
+                    for r, q in psr.requests.items():
+                        if r not in flavor_of:
+                            continue  # QuotaCheckStrategy=IgnoreUndeclared
+                        fr = (flavor_of[r], r)
+                        plan_usage[fr] = plan_usage.get(fr, 0) + q
+                candidates.append(
+                    (wl, cq_name, flavor_of, info, plan_usage))
 
-        # device-TAS placement in admission order; failed placements
-        # drop out of the plan (host mop-up) BEFORE the oracle verify so
-        # the sequential usage walk matches what actually commits
-        candidates, topo_of = self._compute_tas_assignments(candidates)
+            # device-TAS placement in admission order; failed placements
+            # drop out of the plan (host mop-up) BEFORE the oracle verify
+            # so the sequential usage walk matches what actually commits
+            candidates, topo_of = self._compute_tas_assignments(candidates)
 
-        if verify and candidates:
-            from kueue_oss_tpu.core.snapshot import build_snapshot
-            from kueue_oss_tpu.native import BatchOracle
+        # the evictions above changed usage: the snapshot is built now
+        ok = self._verify_candidates(candidates, verify)
 
-            oracle = BatchOracle(build_snapshot(self.store).forest.cqs)
-            ok = oracle.verify_and_apply(
-                [(cq_name, usage)
-                 for _, cq_name, _, _, usage in candidates])
-        else:
-            ok = np.ones(len(candidates), dtype=np.uint8)
-
-        for passed, (wl, cq_name, flavor_of, info, _) in zip(ok, candidates):
-            if not passed:
-                metrics.solver_plan_fallbacks_total.inc()
-                obs.recorder.record(
-                    obs.SOLVER_FALLBACK, wl.key, cycle=self._drain_cycle,
-                    cluster_queue=cq_name, path=obs.SOLVER,
-                    reason="host oracle re-check rejected the plan entry;"
-                           " workload stays queued for the host cycle",
-                    reason_slug="oracle_rejected")
-                continue
-            self._commit_admission(wl, cq_name, flavor_of, info, now,
-                                   result, topology=topo_of.get(wl.key))
+        with spans.span("apply.commit"):
+            for passed, (wl, cq_name, flavor_of, info, _) in zip(
+                    ok, candidates):
+                if not passed:
+                    self._record_rejected(wl, cq_name)
+                    continue
+                self._commit_admission(wl, cq_name, flavor_of, info, now,
+                                       result,
+                                       topology=topo_of.get(wl.key))
 
         # 3) parking decisions (inadmissible backoff parity).
-        for w in np.nonzero(parked[:W] & ~admitted[:W])[0]:
-            cq_name = problem.cq_names[problem.wl_cqid[w]]
-            self.queues.queues[cq_name].park(problem.wl_keys[w])
-            self._record_parked(problem.wl_keys[w], cq_name)
+        with spans.span("apply.park"):
+            for w in np.nonzero(parked[:W] & ~admitted[:W])[0]:
+                cq_name = problem.cq_names[problem.wl_cqid[w]]
+                self.queues.queues[cq_name].park(problem.wl_keys[w])
+                self._record_parked(problem.wl_keys[w], cq_name)
 
     def _commit_admission(self, wl, cq_name: str,
                           flavor_of: dict[str, str], info: WorkloadInfo,

@@ -283,6 +283,7 @@ def _height_along_path(t, usage, cq_node, req):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("select_heads_full")
 def select_heads_full(t: FullTensors, admitted, parked, ts,
                       lq_penalty=None):
     C = t.cq_node.shape[0]
@@ -326,6 +327,7 @@ def select_heads_full(t: FullTensors, admitted, parked, ts,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("nominate_full")
 def nominate_full(t: FullTensors, usage, avail, pot, cand_w, cursor,
                   g_max: int, fs_enabled: bool = False):
     """Classify each CQ's head across (group, flavor) options.
@@ -424,6 +426,7 @@ def nominate_full(t: FullTensors, usage, avail, pot, cand_w, cursor,
             opt_fit, opt_preempt, opt_level, group_active, valid)
 
 
+@jax.named_scope("walk_assign")
 def walk_assign(t: FullTensors, head_w, pmode_k, borrow_k, valid_k,
                 group_active_row, g_max: int):
     """The assigner's flavor walk over granular modes, for ONE head (vmap).
@@ -523,6 +526,7 @@ def _workload_fits(t, usage, cq_node, req, allow_borrow):
     return fits_avail & (allow_borrow | no_borrow_ok)
 
 
+@jax.named_scope("build_candidate_table")
 def build_candidate_table(t: FullTensors, admitted, admit_rank, wl_usage,
                           a_max: int):
     """Per-cohort-root admitted-candidate table, [N+1, A] int32.
@@ -559,6 +563,7 @@ def build_candidate_table(t: FullTensors, admitted, admit_rank, wl_usage,
     return table.at[row, col].set(sorted_w, mode="drop")
 
 
+@jax.named_scope("classical_search")
 def classical_search(t: FullTensors, usage0_round, wl_usage, admitted,
                      evicted_f, ts, head_w, req, avail_cq,
                      cands, p_max: int):
@@ -859,6 +864,7 @@ def _quota_to_reserve(t, usage, cq_node, req, borrow):
         0, jnp.where(borrow > 0, reserve_borrowing, reserve_nominal))
 
 
+@jax.named_scope("full_round_scan")
 def full_round_scan(t: FullTensors, state, cand_w, mode, k_chosen, req_c,
                     borrow, lane_of_entry, lane_success, lane_cand_w,
                     lane_victims, lane_reason, p_max: int,
@@ -1113,6 +1119,7 @@ def _run_searches(t, usage, wl_usage, admitted, evicted, ts,
     return out
 
 
+@jax.named_scope("round_body")
 def round_body(t: FullTensors, state, pot, g_max: int, h_max: int,
                p_max: int, fs_enabled: bool = False, lendable_r=None,
                mesh=None, axis: str = "wl"):
@@ -1255,8 +1262,9 @@ def round_body(t: FullTensors, state, pot, g_max: int, h_max: int,
         order = jnp.argsort(key)
         return vw_row[order], vm_row[order], re_row[order]
 
-    lane_cand_w, lane_victims, lane_reason = jax.vmap(_compact)(
-        lane_cand_w, lane_victims, lane_reason)
+    with jax.named_scope("compact_victims"):
+        lane_cand_w, lane_victims, lane_reason = jax.vmap(_compact)(
+            lane_cand_w, lane_victims, lane_reason)
 
     # park NoFit heads of BestEffortFIFO queues (post-walk modes)
     park_now = is_head & (mode == M_NOFIT) & ~t.cq_strict

@@ -226,6 +226,7 @@ def borrow_levels(t: ProblemTensors, usage: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("nominate")
 def nominate(t: ProblemTensors, usage: jnp.ndarray, avail: jnp.ndarray,
              pot: jnp.ndarray, cand_w: jnp.ndarray, cursor: jnp.ndarray):
     """Classify each CQ's head: (mode, chosen option, borrow level,
@@ -347,6 +348,7 @@ def _add_usage_along_path(t: ProblemTensors, usage: jnp.ndarray,
     return usage
 
 
+@jax.named_scope("_round_scan")
 def _round_scan(t: ProblemTensors, usage, cq_usage, admitted, parked,
                 cand_w, mode, k_chosen, borrow):
     # strict queues never park (their head keeps blocking the queue)
@@ -419,6 +421,7 @@ def _round_scan(t: ProblemTensors, usage, cq_usage, admitted, parked,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("_select_heads")
 def _select_heads(t: ProblemTensors, admitted, parked):
     """Per-CQ lowest-rank pending workload (two-pass int32 segment min)."""
     C = t.cq_node.shape[0]
@@ -453,6 +456,7 @@ def _solve_backlog_impl(t: ProblemTensors):
         _, _, _, _, _, _, progress, rounds = state
         return progress & (rounds < W1 + C + 2)
 
+    @jax.named_scope("round")
     def body(state):
         usage, admitted, parked, cursor, opt, admit_round, _, rounds = state
         parked_before = parked

@@ -230,8 +230,10 @@ def test_host_cycle_ledger_row_matches_stats_and_joins_recorder():
     # the slug breakdown mirrors the recorder's per-reason counters
     slug = next(iter(row2.skip_slugs))
     assert metrics.decision_skips_total.value(slug) == 1
-    assert set(row.phases) == {"snapshot", "nominate", "entries",
-                               "flush"}
+    # the row's phases are its spans' durations by name (obs/spans.py)
+    assert set(row.phases) == {"requeue", "snapshot", "nominate",
+                               "entries", "flush"}
+    assert row.mono_ns > 0
     assert row.duration_s >= 0.0
     assert row.breaker == "closed"
     # the recorder's decision events carry the SAME cycle id
