@@ -65,7 +65,15 @@ class Counter(_Series):
         with self._lock:
             for key in [k for k in self._values
                         if all(k[i] == v for i, v in idx.items())]:
-                del self._values[key]
+                self._drop(key)
+
+    def _drop(self, key: LabelValues) -> None:
+        del self._values[key]
+
+    def reset(self) -> None:
+        """Drop every sample (``reset_all``)."""
+        with self._lock:
+            self._values = {}
 
     def collect(self) -> dict[LabelValues, float]:
         # a concurrent inc()/set() during a scrape would otherwise raise
@@ -77,10 +85,48 @@ class Counter(_Series):
 class Gauge(Counter):
     kind = "gauge"
 
+    def __init__(self, name: str, help_: str,
+                 labels: tuple[str, ...] = ()) -> None:
+        super().__init__(name, help_, labels)
+        #: prefix length -> prefix -> the suffixes ``_values`` holds
+        #: under it; a length appears with the first ``replace_prefix``
+        #: that asks for it, and every writer below keeps it true under
+        #: the series' lock. ``_values`` stays the one dict of truth.
+        self._by_prefix: dict[int, dict[LabelValues, set[LabelValues]]] = {}
+
+    def _store(self, key: LabelValues, value: float) -> None:
+        """``_values[key] = value`` with the lock held; only a key new
+        to the gauge costs the index anything."""
+        values = self._values
+        before = len(values)
+        values[key] = value
+        if self._by_prefix and len(values) != before:
+            for n, index in self._by_prefix.items():
+                index.setdefault(key[:n], set()).add(key[n:])
+
+    def _drop(self, key: LabelValues) -> None:
+        del self._values[key]
+        for n, index in self._by_prefix.items():
+            suffixes = index[key[:n]]
+            suffixes.discard(key[n:])
+            if not suffixes:
+                # churned label sets must not pile up here either
+                del index[key[:n]]
+
     def set(self, *label_values: str, value: float) -> None:
         key = self._key(label_values)
         with self._lock:
-            self._values[key] = float(value)
+            self._store(key, float(value))
+
+    def inc(self, *label_values: str, by: float = 1.0) -> None:
+        key = self._key(label_values)
+        with self._lock:
+            self._store(key, self._values.get(key, 0.0) + by)
+
+    def reset(self) -> None:
+        with self._lock:
+            self._values = {}
+            self._by_prefix = {}
 
     def replace_prefix(self, prefix: tuple[str, ...],
                        updates: dict[tuple, float]) -> None:
@@ -88,15 +134,26 @@ class Gauge(Counter):
         samples sharing the prefix first report one scrape of 0, then
         drop off entirely — a drained gauge must not keep its last
         value, and churned label sets must not accumulate forever
-        (reference metrics.go zero-fill + DeleteLabelValues)."""
+        (reference metrics.go zero-fill + DeleteLabelValues).
+
+        Costs what it writes: the samples under ``prefix`` come from
+        the index, not from a walk over every sample of the gauge (the
+        cycle-end flush calls this once per touched ClusterQueue)."""
         n = len(prefix)
         with self._lock:
-            for key in list(self._values):
-                if key[:n] == prefix and key[n:] not in updates:
-                    if self._values[key] == 0.0:
-                        del self._values[key]
-                    else:
-                        self._values[key] = 0.0
+            index = self._by_prefix.get(n)
+            if index is None:
+                index = self._by_prefix[n] = {}
+                for key in self._values:
+                    index.setdefault(key[:n], set()).add(key[n:])
+            values = self._values
+            for suffix in [s for s in index.get(prefix, ())
+                           if s not in updates]:
+                key = prefix + suffix
+                if values[key] == 0.0:
+                    self._drop(key)
+                else:
+                    values[key] = 0.0
         for suffix, v in updates.items():
             self.set(*(prefix + tuple(suffix)), value=v)
 
@@ -185,6 +242,12 @@ class Histogram(_Series):
                         if all(k[i] == v for i, v in idx.items())]:
                 del self._values[key]
                 self._exemplars.pop(key, None)
+
+    def reset(self) -> None:
+        """Drop every sample and exemplar (``reset_all``)."""
+        with self._lock:
+            self._values = {}
+            self._exemplars = {}
 
     def collect(self):
         # copy the per-key bucket lists too: observe() mutates them in
@@ -1073,9 +1136,7 @@ def clear_cluster_queue_metrics(cq: str) -> None:
 def reset_all() -> None:
     """Test helper: drop every recorded sample (registry keeps its series)."""
     for s in registry._series_snapshot():
-        s._values = {}  # type: ignore[attr-defined]
-        if isinstance(s, Histogram):
-            s._exemplars = {}
+        s.reset()  # type: ignore[attr-defined]
     # the span totals are read from their owner at scrape time
     from kueue_oss_tpu.obs import spans
 

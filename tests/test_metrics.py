@@ -237,6 +237,183 @@ def test_gauge_replace_prefix_zero_fill_then_drop():
     assert all(k[0] != "a" for k in g.collect())
 
 
+class _ParentGauge(metrics.Gauge):
+    """The parent commit's ``Gauge`` kept as the plain reference: one
+    flat dict, and a ``replace_prefix`` that walks every sample of the
+    gauge. The indexed gauge has to read the same after every call."""
+
+    def set(self, *label_values, value):
+        key = self._key(label_values)
+        with self._lock:
+            self._values[key] = float(value)
+
+    def inc(self, *label_values, by=1.0):
+        key = self._key(label_values)
+        with self._lock:
+            self._values[key] = self._values.get(key, 0.0) + by
+
+    def delete_matching(self, **by_label):
+        idx = {self.labels.index(k): v for k, v in by_label.items()}
+        with self._lock:
+            for key in [k for k in self._values
+                        if all(k[i] == v for i, v in idx.items())]:
+                del self._values[key]
+
+    def reset(self):
+        self._values = {}
+
+    def replace_prefix(self, prefix, updates):
+        n = len(prefix)
+        with self._lock:
+            for key in list(self._values):
+                if key[:n] == prefix and key[n:] not in updates:
+                    if self._values[key] == 0.0:
+                        del self._values[key]
+                    else:
+                        self._values[key] = 0.0
+        for suffix, v in updates.items():
+            self.set(*(prefix + tuple(suffix)), value=v)
+
+
+#: the label sets of the gauges that ``replace_prefix`` is called on:
+#: the cycle-end flush (prefix lengths 2 and 1) and obs/health.py's
+#: starvation gauge (the empty prefix)
+_PREFIXED_GAUGES = [
+    metrics.local_queue_resource_usage,
+    metrics.cluster_queue_resource_pending,
+    metrics.starvation_oldest_pending_seconds,
+]
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("like", _PREFIXED_GAUGES, ids=lambda g: g.name)
+def test_gauge_prefix_index_equals_parent_sweep(like, seed, monkeypatch):
+    """The same random sequence of every call that adds or removes a
+    key, driven through the indexed gauge and through the parent's:
+    ``collect()``, the order of ``_values`` and the rendered text are
+    equal after every step."""
+    import random
+
+    rng = random.Random(seed)
+    width = len(like.labels)
+    sides = []
+    for cls in (metrics.Gauge, _ParentGauge):
+        reg = metrics.Registry()
+        sides.append((reg, reg.register(cls(like.name, like.help,
+                                            like.labels))))
+    names = ["a", "b", "c", "d"]
+
+    def key(n):
+        return tuple(rng.choice(names) for _ in range(n))
+
+    def step(call):
+        for reg, g in sides:
+            call(reg, g)
+        (reg_new, new), (reg_old, old) = sides
+        assert new.collect() == old.collect()
+        assert list(new._values) == list(old._values)
+        assert reg_new.render() == reg_old.render()
+
+    def reset_all(reg, g):
+        monkeypatch.setattr(metrics, "registry", reg)
+        metrics.reset_all()
+
+    for _ in range(600):
+        op = rng.random()
+        if op < 0.25:
+            k, v = key(width), rng.choice([0.0, 1.0, 2.5, 7.0])
+            step(lambda reg, g: g.set(*k, value=v))
+        elif op < 0.35:
+            k, by = key(width), rng.choice([-1.0, 1.0])
+            step(lambda reg, g: g.inc(*k, by=by))
+        elif op < 0.85:
+            n = rng.randrange(0, min(width, 2) + 1)
+            prefix = key(n)
+            updates = {key(width - n): rng.choice([0.0, 1.0, 3.0])
+                       for _ in range(rng.randrange(0, 4))}
+            # the same call again is what turns a 0 into a dropped key
+            for _ in range(rng.choice([1, 1, 2, 3])):
+                step(lambda reg, g: g.replace_prefix(prefix, updates))
+        elif op < 0.97:
+            label, v = rng.choice(like.labels), rng.choice(names)
+            step(lambda reg, g: g.delete_matching(**{label: v}))
+        else:
+            step(reset_all)
+
+
+def _fill(g, prefixes):
+    for i in range(prefixes):
+        g.replace_prefix((f"lq-{i}", "ns"), {("f", "cpu"): 1.0,
+                                             ("f", "mem"): 2.0})
+
+
+class _CountingDict(dict):
+    """``_values`` with its key visits counted: one for each key an
+    iteration yields, one for each lookup, store and delete."""
+
+    visits = 0
+
+    def __iter__(self):
+        for k in super().__iter__():
+            self.visits += 1
+            yield k
+
+    def __getitem__(self, k):
+        self.visits += 1
+        return super().__getitem__(k)
+
+    def __setitem__(self, k, v):
+        self.visits += 1
+        super().__setitem__(k, v)
+
+    def __delitem__(self, k):
+        self.visits += 1
+        super().__delitem__(k)
+
+
+def test_replace_prefix_work_independent_of_other_prefixes():
+    """One call touches the samples under its prefix and its updates,
+    however many other prefixes the gauge holds (the flush at 1,000
+    ClusterQueues made 3,000 calls that each walked ~1,000 keys)."""
+    visits = {}
+    for others in (10, 1000):
+        g = metrics.Gauge("t_scale", "t", ("lq", "ns", "flavor", "resource"))
+        _fill(g, others)
+        g.replace_prefix(("mine", "ns"), {("f", "cpu"): 1.0,
+                                          ("f", "mem"): 2.0,
+                                          ("f", "gpu"): 3.0})
+        g._values = _CountingDict(g._values)
+        # one stale sample zeroed, two rewritten, one new
+        g.replace_prefix(("mine", "ns"), {("f", "cpu"): 4.0,
+                                          ("f", "mem"): 5.0,
+                                          ("f", "tpu"): 6.0})
+        visits[others] = g._values.visits
+        assert g.collect()[("mine", "ns", "f", "gpu")] == 0.0
+        assert len(g.collect()) == 2 * others + 4
+    assert visits[10] == visits[1000] <= 8
+
+
+def test_reset_all_leaves_no_ghost_under_a_prefix(monkeypatch):
+    """After ``reset_all`` the index is as empty as ``_values``: a
+    ``replace_prefix`` on a gauge that had been full finds nothing
+    stale, raises nothing and brings no old sample back."""
+    reg = metrics.Registry()
+    g = reg.register(metrics.Gauge(
+        "t_ghost", "t", ("lq", "ns", "flavor", "resource")))
+    monkeypatch.setattr(metrics, "registry", reg)
+    _fill(g, 50)
+    metrics.reset_all()
+    assert g.collect() == {}
+    g.replace_prefix(("lq-3", "ns"), {("f", "gpu"): 9.0})
+    g.replace_prefix(("lq-4", "ns"), {})
+    assert g.collect() == {("lq-3", "ns", "f", "gpu"): 9.0}
+    # and the old samples under the prefix are not zero-filled either
+    g.replace_prefix(("lq-3", "ns"), {})
+    assert g.collect() == {("lq-3", "ns", "f", "gpu"): 0.0}
+    g.replace_prefix(("lq-3", "ns"), {})
+    assert g.collect() == {}
+
+
 def test_histogram_bucket_edge_values_inclusive():
     """Prometheus le buckets are INCLUSIVE upper bounds: an observation
     exactly on a bucket edge counts in that bucket (and all above)."""

@@ -464,3 +464,157 @@ class TestPartialAdmission:
         h.submit("elastic", "cq", cpu=1000, count=5, min_count=2)
         h.settle()
         assert h.admitted() == []
+
+
+class TestCycleEndGaugeFlush:
+    """The cycle-end flush over several hundred ClusterQueues writes the
+    per-LocalQueue usage and per-ClusterQueue pending gauges through
+    ``Gauge.replace_prefix``. The samples are held to a plain model
+    kept here: what the store holds, and for a sample that went stale
+    one flush of 0 and then none."""
+
+    N = 300
+    NS = "default"
+
+    def _harness(self):
+        cqs = []
+        for i in range(self.N):
+            cqs.append(ClusterQueue(
+                name=f"cq-{i}",
+                resource_groups=[ResourceGroup(
+                    covered_resources=["cpu", "memory"],
+                    flavors=[FlavorQuotas(name="default", resources=[
+                        ResourceQuota(name="cpu", nominal=16000),
+                        ResourceQuota(name="memory", nominal=16000)])])]))
+        h = Harness(cqs)
+        for cq in cqs:
+            h.store.upsert_local_queue(
+                LocalQueue(name=f"lq2-{cq.name}", cluster_queue=cq.name))
+        return h
+
+    def _submit(self, h):
+        for i in range(self.N):
+            for j in range(2 + i % 3):
+                requests = {"cpu": 1000 * (1 + j % 2)}
+                if (i + j) % 3 == 0:
+                    requests["memory"] = 500
+                h._t += 1.0
+                h.store.add_workload(Workload(
+                    name=f"w-{i}-{j}", creation_time=h._t,
+                    queue_name=f"lq{'2' if j % 2 else ''}-cq-{i}",
+                    podsets=[PodSet(count=1, requests=requests)]))
+
+    @staticmethod
+    def _replace(samples, prefix, updates):
+        """``replace_prefix`` as a sweep over every sample."""
+        n = len(prefix)
+        for key in [k for k in samples if k[:n] == prefix]:
+            if key[n:] not in updates:
+                if samples[key] == 0.0:
+                    del samples[key]
+                else:
+                    samples[key] = 0.0
+        for suffix, v in updates.items():
+            samples[prefix + suffix] = float(v)
+
+    def _state(self, h):
+        """(ClusterQueue -> LocalQueue -> (flavor, resource) -> quantity
+        reserved, ClusterQueue -> resource -> quantity pending)."""
+        usage, pending = {}, {}
+        for wl in h.store.workloads.values():
+            if wl.is_finished:
+                continue
+            cq = h.store.cluster_queue_for(wl)
+            requests = wl.podsets[0].requests
+            if wl.is_quota_reserved:
+                by_fr = usage.setdefault(cq, {}).setdefault(
+                    wl.queue_name, {})
+                for r, v in requests.items():
+                    by_fr[("default", r)] = by_fr.get(("default", r), 0) + v
+            else:
+                by_r = pending.setdefault(cq, {})
+                for r, v in requests.items():
+                    by_r[r] = by_r.get(r, 0) + v
+        return usage, pending
+
+    def test_samples_equal_the_model_through_drain_and_refill(self):
+        from kueue_oss_tpu import metrics
+
+        metrics.reset_all()
+        h = self._harness()
+        self._submit(h)
+        lq_usage, cq_pending, reported = {}, {}, {}
+        touched = set()
+
+        def cycle():
+            # usage is the cycle's snapshot's, taken before it admits;
+            # pending is the queues' own count after it
+            usage, pending = self._state(h)
+            touched.update(pending)          # a head each: all of them fit
+            h.cycle()
+            _, pending = self._state(h)
+            for cq in touched:
+                active = usage.get(cq, {})
+                for lq, by_fr in active.items():
+                    self._replace(lq_usage, (lq, self.NS), by_fr)
+                for lq in reported.get(cq, set()) - set(active):
+                    self._replace(lq_usage, (lq, self.NS), {})
+                reported[cq] = set(active)
+                self._replace(cq_pending, (cq,), {
+                    (r,): v for r, v in pending.get(cq, {}).items()})
+            touched.clear()
+            assert metrics.local_queue_resource_usage.collect() == lq_usage
+            assert (metrics.local_queue_resource_reservation.collect()
+                    == lq_usage)
+            assert (metrics.cluster_queue_resource_pending.collect()
+                    == cq_pending)
+
+        def nth(wl):
+            return int(wl.queue_name.rsplit("-", 1)[1])
+
+        def finish(keep):
+            for wl in list(h.store.workloads.values()):
+                if (wl.is_quota_reserved and not wl.is_finished
+                        and not keep(wl)):
+                    touched.add(h.store.cluster_queue_for(wl))
+                    h.finish(wl.key)
+
+        try:
+            for _ in range(3):
+                cycle()
+            assert len(lq_usage) > 2 * self.N
+            # a LocalQueue drains in every 5th ClusterQueue, a resource
+            # leaves a LocalQueue that stays busy in every 7th
+            finish(lambda wl: not (
+                (wl.queue_name.startswith("lq2-") and nth(wl) % 5 == 0)
+                or ("memory" in wl.podsets[0].requests
+                    and nth(wl) % 7 == 0)))
+            cycle()
+            assert 0.0 in lq_usage.values()
+            cycle()
+            cycle()
+            assert not any(cq_pending.values())
+            # every 4th ClusterQueue drains whole, then fills again
+            finish(lambda wl: nth(wl) % 4)
+            cycle()
+            self._submit_again(h)
+            cycle()
+            assert any(cq_pending.values())
+            cycle()
+            cycle()
+        finally:
+            metrics.reset_all()
+
+    def _submit_again(self, h):
+        for i in range(0, self.N, 2):
+            h._t += 1.0
+            h.store.add_workload(Workload(
+                name=f"again-{i}", creation_time=h._t,
+                queue_name=f"lq2-cq-{i}",
+                podsets=[PodSet(count=1, requests={"cpu": 250,
+                                                   "memory": 250})]))
+            h._t += 1.0
+            h.store.add_workload(Workload(
+                name=f"again2-{i}", creation_time=h._t,
+                queue_name=f"lq-cq-{i}",
+                podsets=[PodSet(count=1, requests={"cpu": 250})]))
