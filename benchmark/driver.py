@@ -28,21 +28,43 @@ import time
 from benchmark import deployment
 
 
+#: what a kind's ``scheduler_options`` may set: deployment settings that
+#: upstream's ``Configuration`` API has. A router threshold
+#: (``solver_min_backlog``, ``solver_reengage_fraction``,
+#: ``solver_config``) is none: the driver changes no router setting
+SCHEDULER_OPTIONS = frozenset(("enable_fair_sharing",
+                               "enable_partial_admission"))
+
+
+def scheduler_options(cfg: dict) -> dict:
+    opts = dict(deployment.kind_of(cfg).scheduler_options(cfg))
+    refused = sorted(set(opts) - SCHEDULER_OPTIONS)
+    if refused:
+        raise ValueError(
+            f"configs/{cfg.get('name')}.json: its kind sets {refused} on "
+            f"the Scheduler; a kind may set {sorted(SCHEDULER_OPTIONS)} "
+            "and nothing else")
+    return opts
+
+
 class Replay:
-    def __init__(self, cfg: dict, arrivals, *, solver,
-                 nominal=None) -> None:
+    """``cfg`` is the deployment the program is given (the
+    configuration as stated, or a control's)."""
+
+    def __init__(self, cfg: dict, arrivals, *, solver) -> None:
         from kueue_oss_tpu.core.queue_manager import QueueManager
         from kueue_oss_tpu.scheduler.scheduler import Scheduler
 
+        kind = deployment.kind_of(cfg)
         self.cfg = cfg
         self.arrivals = arrivals
         self.by_key = {a.key: a for a in arrivals}
-        res = cfg.get("resource", "cpu")
-        self.workloads = {a.key: deployment.make_workload(a, res)
+        self.workloads = {a.key: kind.make_workload(a, cfg)
                           for a in arrivals}
-        self.store = deployment.build_store(cfg, nominal=nominal)
+        self.store = kind.build_store(cfg)
         self.queues = QueueManager(self.store)
-        self.sched = Scheduler(self.store, self.queues, solver=solver)
+        self.sched = Scheduler(self.store, self.queues, solver=solver,
+                               **scheduler_options(cfg))
         self.engine = None
         if solver is not None:
             # the program's own provision for one compiled program
@@ -228,12 +250,11 @@ def first_difference(n: int, rec: dict, got: dict) -> dict:
     return out
 
 
-def replay_log(cfg: dict, arrivals, preloaded, pass_log, *,
-               nominal=None) -> dict:
+def replay_log(cfg: dict, arrivals, preloaded, pass_log) -> dict:
     """The host-only twin: the program's scheduler with no solver, fed
     the recorded log pass by pass; per pass, whether the same workloads
     (by key) gained and lost a reservation as in the program."""
-    twin = Replay(cfg, arrivals, solver=None, nominal=nominal)
+    twin = Replay(cfg, arrivals, solver=None)
     for key in preloaded:
         twin.store.add_workload(twin.workloads[key])
     differing, first = 0, None

@@ -1,13 +1,29 @@
-"""The plain reference: the configuration's guarantees, replayed from the
-driver's own record.
+"""The kind ``flat``: upstream's generator shape, and its plain reference.
 
-Imports nothing of the program and takes nothing it has made: its
-inputs are the configuration file, the schedule as plain data
-(``deployment.Arrival``) and the driver's pass log (which events each
-pass applied, and which workloads gained or lost a quota reservation
-between the end of the pass before and the end of this one). It keeps
-its own books (who holds quota, usage per ClusterQueue and cohort,
-who waits) and holds every pass to what the configuration states:
+One ResourceFlavor (``default``), one resource, cohorts of equal
+ClusterQueues, no fair sharing, no topology; one podset of count 1 per
+workload. A configuration file of this kind holds ``cohorts``,
+``cqs_per_cohort``, ``nominal``, ``borrowing_limit``,
+``reclaim_within_cohort``, ``within_cluster_queue``, ``classes`` (name,
+count, request, priority, runtime_ms, creation_interval_ms: all per
+ClusterQueue) and ``guarantees``. The interface is ``kinds/__init__``'s.
+
+Copied from ``kueue_oss_tpu/perf/generator.py`` (which has no seed and
+lives inside the program) so that a later PR to the program cannot move
+the yardstick. What the seed does: the i-th workload of a class is due
+somewhere inside its i-th creation interval, and WHICH ClusterQueue
+gets which point of that interval is the seed's permutation. Every seed
+therefore plays the same multiset of arrival times, sizes, priorities
+and runtimes; only their assignment to queues differs.
+
+**The plain reference** (``audit``) imports nothing of the program and
+takes nothing it has made: its inputs are the configuration file, the
+schedule as plain data (``Arrival``) and the driver's pass log (which
+events each pass applied, and which workloads gained or lost a quota
+reservation between the end of the pass before and the end of this
+one). It keeps its own books (who holds quota, usage per ClusterQueue
+and cohort, who waits) and holds every pass to what the configuration
+states:
 
 ``over_quota``     a ClusterQueue above nominal + borrowingLimit, or a
                    cohort above the sum of its queues' nominal quota,
@@ -38,6 +54,138 @@ exact.
 from __future__ import annotations
 
 from collections import Counter, defaultdict
+from dataclasses import dataclass
+
+import numpy as np
+
+REQUIRED = ("cohorts", "cqs_per_cohort", "nominal", "borrowing_limit",
+            "classes", "guarantees")
+
+
+def load(cfg: dict) -> dict:
+    for key in REQUIRED:
+        if key not in cfg:
+            raise ValueError(f"configs/{cfg.get('name')}.json lacks {key!r}")
+    return cfg
+
+
+def scaled(cfg: dict, cohorts: int | None, cqs_per_cohort: int | None,
+           count_div: int) -> dict:
+    """A smaller copy for the CPU rehearsal and the tests; a measurement
+    run never calls this."""
+    out = dict(cfg)
+    if cohorts:
+        out["cohorts"] = cohorts
+    if cqs_per_cohort:
+        out["cqs_per_cohort"] = cqs_per_cohort
+    if count_div > 1:
+        out["classes"] = [
+            {**c, "count": max(1, c["count"] // count_div)}
+            for c in cfg["classes"]]
+    return out
+
+
+@dataclass(frozen=True)
+class Arrival:
+    """One workload of the schedule, as plain data (the reference reads
+    these, the program gets Workload objects built from them)."""
+
+    key: str
+    name: str
+    cq: str
+    cohort: str
+    klass: str
+    request: int
+    priority: int
+    runtime_s: float
+    due_s: float
+
+
+def cq_names(cfg: dict) -> list[tuple[str, str]]:
+    return [(f"cq-{ci}-{qi}", f"cohort-{ci}")
+            for ci in range(cfg["cohorts"])
+            for qi in range(cfg["cqs_per_cohort"])]
+
+
+def schedule(cfg: dict, seed: int) -> list[Arrival]:
+    """The arrival schedule, sorted by due time."""
+    rng = np.random.default_rng(seed)
+    cqs = cq_names(cfg)
+    n = len(cqs)
+    #: the fixed points of a creation interval that the queues share out
+    lattice = (np.arange(n) + 0.5) / n
+    out: list[Arrival] = []
+    for wc in cfg["classes"]:
+        interval_s = wc["creation_interval_ms"] / 1000.0
+        for i in range(wc["count"]):
+            frac = lattice[rng.permutation(n)]
+            for (cq, cohort), u in zip(cqs, frac):
+                name = f"{wc['name']}-{cq}-{i}"
+                out.append(Arrival(
+                    key=f"default/{name}", name=name, cq=cq, cohort=cohort,
+                    klass=wc["name"], request=int(wc["request"]),
+                    priority=int(wc["priority"]),
+                    runtime_s=wc["runtime_ms"] / 1000.0,
+                    due_s=float((i + u) * interval_s)))
+    out.sort(key=lambda a: (a.due_s, a.key))
+    return out
+
+
+def top_class(cfg: dict) -> str:
+    return max(cfg["classes"], key=lambda c: c["priority"])["name"]
+
+
+def build_store(cfg: dict):
+    """The program's Store holding the deployment (no workloads)."""
+    from kueue_oss_tpu.api.types import (
+        ClusterQueue,
+        Cohort,
+        FlavorQuotas,
+        LocalQueue,
+        PreemptionPolicy,
+        ResourceFlavor,
+        ResourceGroup,
+        ResourceQuota,
+    )
+    from kueue_oss_tpu.core.store import Store
+
+    res = cfg.get("resource", "cpu")
+    store = Store()
+    store.upsert_resource_flavor(ResourceFlavor(name="default"))
+    seen = set()
+    for cq, cohort in cq_names(cfg):
+        if cohort not in seen:
+            seen.add(cohort)
+            store.upsert_cohort(Cohort(name=cohort))
+        store.upsert_cluster_queue(ClusterQueue(
+            name=cq, cohort=cohort,
+            preemption=PreemptionPolicy(
+                reclaim_within_cohort=cfg["reclaim_within_cohort"],
+                within_cluster_queue=cfg["within_cluster_queue"]),
+            resource_groups=[ResourceGroup(
+                covered_resources=[res],
+                flavors=[FlavorQuotas(name="default", resources=[
+                    ResourceQuota(
+                        name=res,
+                        nominal=cfg["nominal"],
+                        borrowing_limit=cfg["borrowing_limit"])])])]))
+        store.upsert_local_queue(
+            LocalQueue(name=f"lq-{cq}", cluster_queue=cq))
+    return store
+
+
+def make_workload(a: Arrival, cfg: dict):
+    from kueue_oss_tpu.api.types import PodSet, Workload
+
+    return Workload(
+        name=a.name, queue_name=f"lq-{a.cq}", priority=a.priority,
+        creation_time=a.due_s,
+        podsets=[PodSet(count=1, requests={
+            cfg.get("resource", "cpu"): a.request})])
+
+
+def scheduler_options(cfg: dict) -> dict:
+    return {}
 
 
 class Books:
@@ -181,3 +329,14 @@ def audit(cfg: dict, arrivals, preloaded, pass_log) -> dict:
     for rec in pass_log:
         books.apply_pass(rec)
     return books.result()
+
+
+def double_nominal(cfg: dict) -> dict:
+    """The program is given twice the nominal quota the configuration
+    states, and the reference holds it to the stated one."""
+    return {**cfg, "nominal": 2 * cfg["nominal"]}
+
+
+#: name -> (the deployment the program gets, the count that then has
+#: to read above 0)
+controls = {"double_nominal": (double_nominal, "over_quota")}

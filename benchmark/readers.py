@@ -14,8 +14,15 @@ reader the window's ``facts``:
 ``span_s``    the benchmark's own spans -> seconds
 ``window``    what the driver's record of the window says of its
               workloads (the top class's waits)
+``program``   the program's own spans and counters over the window
+              (``progspans.TotalsLog``): {"spans": name -> {"s", "n",
+              "self_s"}, "counts": name -> n}
 ``trace``     the trace reduction's result, or None without a device
               trace
+
+Seven kinds. ``program`` and ``trace_scope`` take the program's span,
+counter and ``jax.named_scope`` names as arguments, so a new span or
+scope in the program is read by a data file alone.
 
 A reader that finds nothing to read returns None and the metric is left
 out of the line.
@@ -74,8 +81,54 @@ def trace_busy(facts: dict, value):
     raise ValueError(f"trace_busy: unknown value {value!r}")
 
 
+def _count_of(prog: dict, name: str):
+    if name in prog["counts"]:
+        return prog["counts"][name]
+    if name in prog["spans"]:
+        return prog["spans"][name]["n"]
+    return None
+
+
+def program(facts: dict, spans=(), minus=(), count=None, per=None,
+            scale=1.0):
+    """Seconds of ``spans`` less seconds of ``minus`` (or the count
+    ``count``: a counter's or a span's), over ``per`` (``passes``,
+    ``window_s``, or a count's or a span's name), times ``scale``."""
+    prog = facts.get("program")
+    if not prog:
+        return None
+    sp = prog["spans"]
+    if count is not None:
+        value = _count_of(prog, count)
+        if value is None:
+            return None
+    else:
+        if not any(s in sp for s in spans):
+            return None
+        value = (sum(sp[s]["s"] for s in spans if s in sp)
+                 - sum(sp[s]["s"] for s in minus if s in sp))
+    if per is not None:
+        den = facts.get(per) if per in ("passes", "window_s") else (
+            _count_of(prog, per))
+        if not den:
+            return None
+        value = value / den
+    return scale * value
+
+
+def trace_scope(facts: dict, scopes):
+    """Self time of the device operations whose innermost program scope
+    is one of ``scopes``, as a share (%) of the device's busy time."""
+    tr = facts.get("trace")
+    if not tr or tr.get("busy_s", 0) <= 0 or not tr.get("scope_s"):
+        return None
+    return 100.0 * sum(tr["scope_s"].get(s, 0.0)
+                       for s in scopes) / tr["busy_s"]
+
+
 READERS = {"ledger_phase": ledger_phase, "counter": counter, "span": span,
-           "window": window, "trace_busy": trace_busy}
+           "window": window, "trace_busy": trace_busy, "program": program,
+           "trace_scope": trace_scope}
 
 
 def read(layer: dict, facts: dict):

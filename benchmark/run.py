@@ -10,10 +10,10 @@ throws the twin away. The window replays the schedule on the real clock
 through ``Store -> QueueManager -> Scheduler(solver="auto")`` and
 nothing else; the router decides what reaches the device. After the
 window the driver's record is held to the configuration's guarantees by
-the plain reference (``reference.py``). The last line of standard
-output is the result.
+the plain reference of the configuration's kind (``kinds/<kind>.py``).
+The last line of standard output is the result.
 
-``--self-test`` checks the trace reduction on the recorded sample.
+``--self-test`` checks the trace reduction on the recorded samples.
 ``--rehearse`` (builder's tool) runs on the CPU at sizes given by
 arguments, labels its device ``cpu`` and prints no device metric; a
 measurement run that finds no TPU fails.
@@ -28,7 +28,6 @@ T_PROCESS = time.monotonic()
 import argparse  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
-import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -60,10 +59,11 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--cohorts", type=int, default=None)
     ap.add_argument("--cqs-per-cohort", type=int, default=None)
     ap.add_argument("--count-div", type=int, default=1)
-    ap.add_argument("--control", default=None, choices=("double_nominal",),
-                    help="run the control: the program is given twice the "
-                         "nominal quota the configuration states, and the "
-                         "reference holds it to the stated one")
+    ap.add_argument("--control", default=None,
+                    help="run a control of the configuration's kind (its "
+                         "``controls``): the program is given a deployment "
+                         "that breaks a stated guarantee, and the "
+                         "reference holds it to the configuration")
     ap.add_argument("--twin", action="store_true",
                     help="replay the log through the program's own "
                          "host-only scheduler as well and report where "
@@ -103,6 +103,28 @@ def metrics_of(bench: dict, group: str, cell: str) -> list[dict]:
 # ---------------------------------------------------------------------------
 
 
+def program_key() -> str:
+    """A name for the program's solver sources as they stand. The
+    persistent cache's key ignores op metadata, so a cache that another
+    tree has filled serves executables with THAT tree's
+    ``jax.named_scope`` names (or none), and the scope readers then
+    find nothing under the names this tree's layer files give. A
+    directory per state of ``kueue_oss_tpu/solver/`` cannot: fixed for
+    a tree, and new only when a source changes that could rename a
+    scope (the program then compiles anew anyway, but for a change of
+    names alone)."""
+    import hashlib
+
+    h = hashlib.sha256()
+    src = os.path.join(ROOT, "kueue_oss_tpu", "solver")
+    for name in sorted(os.listdir(src)):
+        if name.endswith(".py"):
+            h.update(name.encode())
+            with open(os.path.join(src, name), "rb") as f:
+                h.update(f.read())
+    return h.hexdigest()[:16]
+
+
 def own_the_chip(chips: int, rehearse: bool) -> dict:
     import jax
 
@@ -110,7 +132,12 @@ def own_the_chip(chips: int, rehearse: bool) -> dict:
 
     cache_dir = None
     if not rehearse:
-        cache_dir = xla_cache.enable()
+        # under $JAX_COMPILATION_CACHE_DIR where set, else under the
+        # checkout's .xla_cache/; the program's own enable() leaves a
+        # directory chosen in jax.config alone
+        cache_dir = os.path.join(xla_cache.enable(),
+                                 "solver-" + program_key())
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
         # every program goes to the cache, the small scatter programs
         # too: set-up then does the same work in every run after the
         # first
@@ -305,7 +332,7 @@ class DrainRows:
         for r in self.obs.cycle_ledger.rows(last=1024):
             if r.kind == self.obs.SOLVER_DRAIN and r.seq > self.seen:
                 self.rows.append({
-                    "ts": r.ts, "phases": dict(r.phases),
+                    "phases": dict(r.phases),
                     "rounds": r.rounds, "admitted": r.admitted,
                     "evicted": r.evicted, "frame": r.frame_kind,
                     "arm": r.solver_arm, "pass": rec["n"]})
@@ -313,19 +340,16 @@ class DrainRows:
         self.seen = last.seq if last is not None else self.seen
 
 
-def window_facts(replay, win: dict, rows: list, compiles: int) -> dict:
+def window_facts(replay, win: dict, rows: list, compiles: int,
+                 totals=None) -> dict:
+    from benchmark import endtoend
+
     t0, end = win["t0"], win["t_end"]
-    passes = 0.0
+    parts = endtoend.pass_parts(replay.passes, end)
+    passes = sum(parts)
     with_drain = 0.0
     span_s = {"run_until_quiet": 0.0, "apply_events": 0.0}
-    parts = []
-    for p in replay.passes:
-        # the pass under way when the window ends counts as its part,
-        # and so does everything inside it
-        part = 1.0 if p["t_end"] <= end else (
-            (end - p["t_start"]) / (p["t_end"] - p["t_start"]))
-        parts.append(part)
-        passes += part
+    for p, part in zip(replay.passes, parts):
         if p["drains"]:
             with_drain += part
         span_s["run_until_quiet"] += part * (p["t_end"] - p["t_applied"])
@@ -338,56 +362,13 @@ def window_facts(replay, win: dict, rows: list, compiles: int) -> dict:
     reservations = sum(1 for _k, t in replay.reservations if t0 <= t <= end)
     evictions = sum(1 for _k, t, _s in replay.evictions if t0 <= t <= end)
     return {
-        "passes": passes, "window_s": end - t0,
+        "passes": passes, "parts": parts, "window_s": end - t0,
         "phase_s": phase_s, "span_s": span_s, "trace": None,
+        "program": (totals.window(parts[-1] if parts else 1.0)
+                    if totals is not None else None),
         "counters": {"passes": passes, "passes_with_drain": with_drain,
                      "drains": len(rows), "reservations": reservations,
                      "evictions": evictions, "compiles": compiles}}
-
-
-def p95(values: list) -> float:
-    s = sorted(values)
-    return s[max(0, math.ceil(0.95 * len(s)) - 1)]
-
-
-def end_to_end(replay, win: dict, facts: dict, cfg: dict,
-               setup_s: float) -> tuple[dict, dict]:
-    from benchmark import deployment
-
-    t0, end = win["t0"], win["t_end"]
-    seconds = end - t0
-    lost = {(k, since) for k, t, since in replay.evictions if t <= end}
-    kept = sum(1 for k, t in replay.reservations
-               if t0 <= t <= end and (k, t) not in lost)
-    top = deployment.top_class(cfg)
-    start_at = replay.start_at
-    first_reserved: dict = {}
-    for k, t in replay.reservations:
-        first_reserved.setdefault(k, t)
-    waits, still = [], 0
-    for a in replay.arrivals:
-        if a.klass != top or a.due_s >= start_at + seconds:
-            continue
-        due = max(a.due_s, start_at)
-        t = first_reserved.get(a.key)
-        if t is None or t > end:
-            still += 1
-            waits.append(start_at + seconds - due)
-        else:
-            waits.append(max(0.0, start_at + (t - t0) - due))
-    out = {
-        "adm_per_s": kept / seconds,
-        "pass_s": seconds / facts["passes"] if facts["passes"] else None,
-        "tta_top_p95_s": p95(waits) if waits else None,
-        "setup_s": setup_s,
-    }
-    info = {"kept_reservations": kept, "top_class": top,
-            "top_due": len(waits), "top_still_waiting": still,
-            "top_wait_median_s": (sorted(waits)[len(waits) // 2]
-                                  if waits else None),
-            "top_wait_mean_s": (sum(waits) / len(waits)
-                                if waits else None)}
-    return out, info
 
 
 # ---------------------------------------------------------------------------
@@ -396,14 +377,16 @@ def end_to_end(replay, win: dict, facts: dict, cfg: dict,
 
 
 def check(replay, cfg: dict, fallbacks0: dict, *,
-          control: str | None, with_twin: bool) -> tuple[bool, dict, dict]:
+          with_twin: bool) -> tuple[bool, dict, dict]:
     """Every number compared, each beside its limit (all 0: integer
-    kernels, exact comparison)."""
-    from benchmark import driver, reference
+    kernels, exact comparison). ``cfg`` is the configuration as stated
+    (``replay.cfg`` is what the program was given: a control's differs);
+    the counts of the guarantees are its kind's own."""
+    from benchmark import deployment, driver
 
     t = time.monotonic()
-    audit = reference.audit(cfg, replay.arrivals, replay.preloaded,
-                            replay.passes)
+    audit = deployment.kind_of(cfg).audit(
+        cfg, replay.arrivals, replay.preloaded, replay.passes)
     t_audit = time.monotonic() - t
     compared = {k: {"value": v, "limit": 0}
                 for k, v in audit["counts"].items()}
@@ -419,11 +402,14 @@ def check(replay, cfg: dict, fallbacks0: dict, *,
         and (store.workloads[k].is_quota_reserved
              and not store.workloads[k].is_finished)
         != (k in replay.holding))
-    compared["lost"] = {"value": lost + held_wrong, "limit": 0}
-    compared["refused"] = {"value": len(replay.failed), "limit": 0}
-    for k, v in fallback_counts().items():
-        compared[k] = {"value": max(0, v - fallbacks0.get(k, 0)),
-                       "limit": 0}
+    own = {"lost": lost + held_wrong, "refused": len(replay.failed)}
+    own.update((k, max(0, v - fallbacks0.get(k, 0)))
+               for k, v in fallback_counts().items())
+    if set(own) & set(compared):
+        raise ValueError(f"the kind's reference counts "
+                         f"{sorted(set(own) & set(compared))}: the "
+                         "harness's own names")
+    compared.update((k, {"value": v, "limit": 0}) for k, v in own.items())
     t = time.monotonic()
     # the second witness, asked for by --twin and part of no verdict:
     # the program's own host-only scheduler fed the same log. Up to and
@@ -431,16 +417,14 @@ def check(replay, cfg: dict, fallbacks0: dict, *,
     # from the same state; later passes inherit each side's history.
     twin = None
     if with_twin:
-        nominal = (2 * cfg["nominal"] if control == "double_nominal"
-                   else None)
         first_drain = next((n for n, p in enumerate(replay.passes)
                             if p["drains"]), len(replay.passes) - 1)
         twin = {"to_first_drain": driver.replay_log(
-                    cfg, replay.arrivals, replay.preloaded,
-                    replay.passes[:first_drain + 1], nominal=nominal),
+                    replay.cfg, replay.arrivals, replay.preloaded,
+                    replay.passes[:first_drain + 1]),
                 "whole_log": driver.replay_log(
-                    cfg, replay.arrivals, replay.preloaded, replay.passes,
-                    nominal=nominal)}
+                    replay.cfg, replay.arrivals, replay.preloaded,
+                    replay.passes)}
     t_twin = time.monotonic() - t
     ok = all(c["value"] <= c["limit"] for c in compared.values())
     detail = {"audit_first": audit["first"], "twin": twin,
@@ -481,7 +465,27 @@ def self_test() -> int:
         "self_times": tracered.self_times(
             [(0, 10e9, "a"), (1e9, 4e9, "b"), (2e9, 3e9, "c")])
         == {"c": 1.0, "b": 2.0, "a": 7.0},
+        "no_scopes_no_names": r["scope_s"] is None
+        and r["program_spans"] == 0,
     }
+    # the second sample: three rounds of a 400-step while_loop whose
+    # body runs stage_search under vmap and stage_scan, a 30 ms sleep
+    # inside the program's span ``entries`` between them, in a 184 ms
+    # window (tests/record_sample_spans.py)
+    r = tracered.reduce_trace(
+        os.path.join(HERE, "data", "sample_spans.xplane.pb"))
+    checks.update({
+        "spans_busy_s": 0.0215 < r["busy_s"] < 0.0235,
+        "program_spans": r["program_spans"] == 51,
+        "gaps_named_by_program_span":
+        [g[0] for g in r["idle_gaps"][:3]] == ["entries"] * 3,
+        "ops_named_by_scope": r["device_ops"][0][0]
+        == "round_body/stage_search:add_select_fusion",
+        "scopes": set(r["scope_s"]) == {"stage_search", "stage_scan", ""}
+        and abs(sum(r["scope_s"].values()) - r["busy_s"])
+        < 0.01 * r["busy_s"],
+        "scoped_share": 0.80 < r["scoped_share_of_listed"] < 0.85,
+    })
     for k, v in checks.items():
         log(f"self-test {k}: {'ok' if v else 'FAILED'}")
     print(json.dumps({"self_test": all(checks.values()), "checks": checks}))
@@ -510,12 +514,24 @@ def main(argv=None) -> int:
 
     import jax
 
-    from benchmark import deployment, driver, readers, tracered
+    from kueue_oss_tpu.obs import spans
+
+    from benchmark import (deployment, driver, endtoend, progspans, readers,
+                           tracered)
 
     cfg = deployment.load_config(cfg_entry["name"])
+    kind = deployment.kind_of(cfg)
     if a.rehearse:
         cfg = deployment.scaled(cfg, a.cohorts, a.cqs_per_cohort,
                                 a.count_div)
+    #: what the program is given: the configuration, or a control's
+    #: deployment that breaks one guarantee the configuration states
+    deployed = cfg
+    if a.control is not None:
+        if a.control not in kind.controls:
+            raise SystemExit(f"--control {a.control!r}: this cell's kind "
+                             f"has {sorted(kind.controls)}")
+        deployed = kind.controls[a.control][0](cfg)
     traffic = deployment.load_traffic(cell["traffic"])
     start_at = float(traffic["start_at_s"])
     compiles = CompileCounter()
@@ -526,12 +542,11 @@ def main(argv=None) -> int:
     arrivals = deployment.schedule(cfg, a.seed)
     twin_arrivals = deployment.schedule(cfg, a.seed + 1)
     t_schedule = time.monotonic() - t
-    nominal = 2 * cfg["nominal"] if a.control == "double_nominal" else None
     # the twin is replayed on the real clock until its first drain has
     # run: what it traces and loads is what the window then finds loaded
     warm = traffic.get("warmup", {})
     t = time.monotonic()
-    twin = driver.Replay(cfg, twin_arrivals, solver="auto", nominal=nominal)
+    twin = driver.Replay(deployed, twin_arrivals, solver="auto")
     caps = record_caps(twin.engine)
     twin.preload(start_at)
     twin.run(float(warm.get("max_seconds", seconds)),
@@ -551,13 +566,25 @@ def main(argv=None) -> int:
     del twin
     gc.collect()
     t = time.monotonic()
-    replay = driver.Replay(cfg, arrivals, solver="auto", nominal=nominal)
+    replay = driver.Replay(deployed, arrivals, solver="auto")
     replay.preload(start_at)
     t_build = time.monotonic() - t
     gc.collect()
     rows = DrainRows()
+    on_pass = rows.on_pass
+    totals = None
     trace_dir = os.path.join(OUT, "trace")
     if a.trace:
+        # the program's own account, in traced runs only: its totals
+        # over the window, and its spans in the trace (the switch adds
+        # +0.3 % of a pass: PERF.md, Findings, PR 24)
+        totals = progspans.TotalsLog(spans)
+
+        def on_pass(rec: dict) -> None:
+            rows.on_pass(rec)
+            totals.on_pass()
+
+        spans.trace_on("benchmark")
         shutil.rmtree(trace_dir, ignore_errors=True)
         os.makedirs(trace_dir, exist_ok=True)
         replay.span = jax.profiler.TraceAnnotation
@@ -565,26 +592,27 @@ def main(argv=None) -> int:
         opts.python_tracer_level = 0
         opts.host_tracer_level = 1
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        totals.start()
 
     c0 = compiles.snapshot()
     setup_s = time.monotonic() - T_PROCESS
 
     # -- the window -------------------------------------------------------
-    win = replay.run(seconds, on_pass=rows.on_pass)
+    win = replay.run(seconds, on_pass=on_pass)
 
     if a.trace:
         jax.profiler.stop_trace()
+        spans.trace_off("benchmark")
     c1 = compiles.snapshot()
     peak = memory_peak_bytes()
     facts = window_facts(replay, win, rows.rows,
-                         c1["requests"] - c0["requests"])
-    e2e, info = end_to_end(replay, win, facts, cfg, setup_s)
-    facts["window"] = {"top_wait_p95_s": e2e["tta_top_p95_s"]}
+                         c1["requests"] - c0["requests"], totals)
+    e2e, facts["window"], info = endtoend.measure(
+        replay, win, facts["parts"], kind.top_class(cfg), setup_s)
     attempted = sum(len(p["events"]) for p in replay.passes)
 
     # -- the check, outside the window ------------------------------------
-    ok, compared, detail = check(replay, cfg, fallbacks0,
-                                 control=a.control, with_twin=a.twin)
+    ok, compared, detail = check(replay, cfg, fallbacks0, with_twin=a.twin)
     failed = int(compared["refused"]["value"] + compared["lost"]["value"])
 
     # -- the result ---------------------------------------------------------
@@ -595,29 +623,9 @@ def main(argv=None) -> int:
     if a.trace:
         t = time.monotonic()
         if device["platform"] == "tpu":
-            epoch = time.time() - time.monotonic()
             path = tracered.find_xplane(trace_dir)
             trace_bytes = os.path.getsize(path)
-
-            def engine_phases(window_start_ns: float) -> list:
-                """Each drain's phases, laid back from the moment its
-                ledger row was written (the engine times device_put
-                inside solve, at its start)."""
-                out = []
-                for r in rows.rows:
-                    at = window_start_ns + (
-                        r["ts"] - epoch - win["t0"]) * 1e9
-                    ph = dict(r["phases"])
-                    ph["solve"] = max(0.0, ph.get("solve", 0.0)
-                                      - ph.get("device_put", 0.0))
-                    for k in reversed(PHASES):
-                        d = ph.get(k, 0.0) * 1e9
-                        out.append((k, at - d, at))
-                        at -= d
-                return out
-
-            facts["trace"] = tracered.reduce_trace(
-                path, engine_phases, window_s=seconds)
+            facts["trace"] = tracered.reduce_trace(path, window_s=seconds)
             info["trace_bytes"] = trace_bytes
             tr = facts["trace"]
             if tr is not None:
@@ -625,7 +633,9 @@ def main(argv=None) -> int:
                 device_out["window_s"] = tr["window_s"]
                 breakdown = {"device_ops": tr["device_ops"],
                              "idle_gaps": tr["idle_gaps"]}
-                info["trace_device_events"] = tr["device_events"]
+                info["trace"] = {k: tr[k] for k in (
+                    "device_events", "program_spans", "scope_s",
+                    "scoped_share_of_listed")}
         shutil.rmtree(trace_dir, ignore_errors=True)
         info["trace_read_s"] = time.monotonic() - t
         for m in metrics_of(bench, "per_layer", cell["name"]):
@@ -653,7 +663,9 @@ def main(argv=None) -> int:
                   "variants": variants, "prefilled": prefilled,
                   "build_s": t_build, "compile_cache": device["cache_dir"]},
         "compiles_window": {k: c1[k] - c0[k] for k in c1},
-        "check": detail, "control": a.control,
+        "program": facts["program"],
+        "check": detail, "control": a.control and {
+            "name": a.control, "has_to_count": kind.controls[a.control][1]},
         "run_s": time.monotonic() - T_PROCESS})
     log(json.dumps({"info": info}))
     log(f"top class {info['top_class']}: {info['top_still_waiting']} of "

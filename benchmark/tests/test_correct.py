@@ -17,7 +17,8 @@ import json
 import numpy as np
 import pytest
 
-from benchmark import deployment, reference, run
+from benchmark import deployment, run
+from benchmark.kinds import flat
 
 SMALL = ["--workload", "large-scale-replay", "--seconds", "4", "--trace",
          "0", "--rehearse", "--cohorts", "2", "--cqs-per-cohort", "8"]
@@ -89,9 +90,8 @@ def test_altered_plan_is_not_correct(capsys, monkeypatch):
 
 
 def books():
-    cfg = deployment.scaled(deployment.load_config("upstream-baseline"),
-                            1, 2, 50)
-    arrivals = deployment.schedule(cfg, 1)
+    cfg = flat.scaled(deployment.load_config("upstream-baseline"), 1, 2, 50)
+    arrivals = flat.schedule(cfg, 1)
     return cfg, arrivals, {a.klass + a.cq[-1]: a.key for a in arrivals
                            if a.key.endswith("-0")}
 
@@ -106,7 +106,7 @@ def test_reference_counts_each_guarantee():
     keys = [a.key for a in arrivals]
 
     def audit(*passes):
-        return reference.audit(cfg, arrivals, keys, passes)["counts"]
+        return flat.audit(cfg, arrivals, keys, passes)["counts"]
 
     # 2 queues x 20 cpu: two larges fill the cohort; nothing else fits
     full = one(added=[k["large0"], k["large1"]])

@@ -40,6 +40,17 @@ def test_layer_file_matches_its_entry(metric):
     for key in ("name", "layer", "unit", "better", "source", "moves"):
         assert layer[key] == metric[key], key
     assert layer["reader"] in readers.READERS
+    # with nothing to read (no spans, no trace) a reader of the program's
+    # spans or scopes gives nothing, and never a made-up 0
+    if layer["reader"] in ("program", "trace_scope"):
+        empty = {"passes": 1.0, "window_s": 1.0, "trace": None,
+                 "program": None}
+        assert readers.read(layer, empty) is None
+
+
+def test_an_unknown_reader_kind_is_refused():
+    with pytest.raises(ValueError, match="no_such_kind"):
+        readers.read({"name": "x", "reader": "no_such_kind"}, {})
 
 
 def test_readers_read_facts_and_leave_out_what_is_not_there():
