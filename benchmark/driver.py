@@ -16,7 +16,9 @@ driver keeps a log of each pass (its ``now``, the events it applied,
 and who gained or lost a quota reservation between the end of the pass
 before and the end of this one) and stamps every reservation and
 eviction with the host clock as the store reports it (a store watch,
-as ``Simulator`` has).
+as ``Simulator`` has). For a kind that asks (``GIVEN``), a pass's record
+also lists what every reservation of the pass was given: which flavor
+was charged and which nodes were assigned (``given_as_data``).
 """
 
 from __future__ import annotations
@@ -36,15 +38,37 @@ SCHEDULER_OPTIONS = frozenset(("enable_fair_sharing",
                                "enable_partial_admission"))
 
 
-def scheduler_options(cfg: dict) -> dict:
-    opts = dict(deployment.kind_of(cfg).scheduler_options(cfg))
-    refused = sorted(set(opts) - SCHEDULER_OPTIONS)
+#: what a kind's ``feature_gates`` may set: the gates of upstream's
+#: ``pkg/features/kube_features.go`` (``featureGates`` is a field of the
+#: same ``Configuration`` API) that change what the scheduler decides
+#: and that the program has. A gate of the program's own
+#: (``TASDeviceFillCounts``: what runs where) is no deployment setting
+FEATURE_GATES = frozenset((
+    "TASBalancedPlacement", "TASProfileMixed", "TASMultiLayerTopology",
+    "PartialAdmission", "FlavorFungibility", "PrioritySortingWithinCohort",
+    "LendingLimit", "AdmissionFairSharing"))
+
+
+def _only(cfg: dict, got: dict, allowed: frozenset, where: str) -> dict:
+    refused = sorted(set(got) - allowed)
     if refused:
         raise ValueError(
-            f"configs/{cfg.get('name')}.json: its kind sets {refused} on "
-            f"the Scheduler; a kind may set {sorted(SCHEDULER_OPTIONS)} "
-            "and nothing else")
-    return opts
+            f"configs/{cfg.get('name')}.json: its kind sets {refused} "
+            f"{where}; a kind may set {sorted(allowed)} and nothing else")
+    return got
+
+
+def scheduler_options(cfg: dict) -> dict:
+    return _only(cfg, dict(deployment.kind_of(cfg).scheduler_options(cfg)),
+                 SCHEDULER_OPTIONS, "on the Scheduler")
+
+
+def feature_gates(cfg: dict) -> dict:
+    """The gates the configuration's kind states (none, for a kind
+    without ``feature_gates``)."""
+    stated = getattr(deployment.kind_of(cfg), "feature_gates", None)
+    return _only(cfg, dict(stated(cfg)) if stated else {}, FEATURE_GATES,
+                 "among the feature gates")
 
 
 class Replay:
@@ -86,6 +110,11 @@ class Replay:
         self._seq = 0
         self._added: set = set()
         self._removed: set = set()
+        #: for a kind that asks: (key, the store's Admission) of every
+        #: reservation of the pass under way. A reference, no copy: each
+        #: reservation gets an Admission of its own and an eviction
+        #: drops it whole (``given_as_data`` says what is not covered)
+        self._given = [] if getattr(kind, "GIVEN", False) else None
         self.passes: list = []
         self.failed: list = []
         self.t0 = None
@@ -107,6 +136,8 @@ class Replay:
             self.holding.add(key)
             self.reserved_at[key] = t
             self.reservations.append((key, t))
+            if self._given is not None:
+                self._given.append((key, wl.status.admission))
             if self.t0 is not None:
                 due = (self.start_at + (t - self.t0)
                        + self.by_key[key].runtime_s)
@@ -197,6 +228,8 @@ class Replay:
                "drains": (self.engine.drain_count - drains0
                           if self.engine else 0)}
         self._added, self._removed = set(), set()
+        if self._given is not None:
+            rec["given"], self._given = self._given, []
         self.passes.append(rec)
         return rec
 
@@ -240,6 +273,41 @@ class Replay:
                 "idle_s": idle_s}
 
 
+def _podset_as_data(psa) -> dict:
+    ta = psa.topology_assignment
+    return {"name": psa.name, "count": psa.count,
+            "flavors": dict(psa.flavors),
+            "usage": dict(psa.resource_usage),
+            "topology": None if ta is None else {
+                "levels": list(ta.levels),
+                "domains": [[list(d.values), d.count]
+                            for d in ta.domains]}}
+
+
+def given_as_data(pass_log: list) -> None:
+    """After the window: every ``given`` entry of the log, (key, the
+    store's ``Admission``) as the watch kept it, becomes plain data that
+    a reference can read without the program's types:
+    ``{"key", "podsets": [{"name", "count", "flavors": resource ->
+    flavor name, "usage": resource -> quantity, "topology": {"levels",
+    "domains": [[values, count], ...]} or None}, ...]}`` (``podsets``
+    None where the store reported a reservation with no admission).
+
+    The program writes a new ``Admission`` for every reservation
+    (``scheduler._admit``, ``engine._commit_admission``) and changes one
+    in place in two places that no replay reaches: the second pass of a
+    delayed topology request (an admission check of a provisioning
+    request) and ``failure_recovery``'s node replacement. A deployment
+    with either would read the later placement under the earlier
+    reservation."""
+    for rec in pass_log:
+        if "given" in rec:
+            rec["given"] = [
+                {"key": key, "podsets": None if adm is None else [
+                    _podset_as_data(psa) for psa in adm.podset_assignments]}
+                for key, adm in rec["given"]]
+
+
 def first_difference(n: int, rec: dict, got: dict) -> dict:
     out = {"pass": n, "drains": rec["drains"]}
     for side in ("added", "removed"):
@@ -253,7 +321,8 @@ def first_difference(n: int, rec: dict, got: dict) -> dict:
 def replay_log(cfg: dict, arrivals, preloaded, pass_log) -> dict:
     """The host-only twin: the program's scheduler with no solver, fed
     the recorded log pass by pass; per pass, whether the same workloads
-    (by key) gained and lost a reservation as in the program."""
+    (by key) gained and lost a reservation as in the program. What the
+    log's reservations were ``given`` is neither read nor changed."""
     twin = Replay(cfg, arrivals, solver=None)
     for key in preloaded:
         twin.store.add_workload(twin.workloads[key])

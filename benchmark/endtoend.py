@@ -24,6 +24,11 @@ per-layer metrics as files, and is judged on these as they stand).
                    reports it; one still waiting at the window's end
                    counts the time it had waited
 ``setup_s``        process start to window start
+
+Beside them, for the reader kind ``window`` and no end-to-end metric:
+``wait_mean_s.<klass>`` and ``wait_p95_s.<klass>`` for every ``klass``
+of the schedule, by the tail's rule (upstream's ``rangespec.yaml`` files
+state the average time to admission by class).
 """
 
 from __future__ import annotations
@@ -75,17 +80,21 @@ def measure(replay, win: dict, parts: list, top: str,
     first_reserved: dict = {}
     for k, t in replay.reservations:
         first_reserved.setdefault(k, t)
-    waits, still = [], 0
+    #: klass -> the wait of every workload of it due in the window
+    waits_of: dict = {}
+    still = 0
     for a in replay.arrivals:
-        if a.klass != top or a.due_s >= start_at + seconds:
+        if a.due_s >= start_at + seconds:
             continue
         due = max(a.due_s, start_at)
         t = first_reserved.get(a.key)
         if t is None or t > end:
-            still += 1
-            waits.append(start_at + seconds - due)
+            still += a.klass == top
+            wait = start_at + seconds - due
         else:
-            waits.append(max(0.0, start_at + (t - t0) - due))
+            wait = max(0.0, start_at + (t - t0) - due)
+        waits_of.setdefault(a.klass, []).append(wait)
+    waits = waits_of.get(top, [])
     out = {
         "adm_per_s": admitted / seconds,
         "pass_s": seconds / passes if passes else None,
@@ -104,4 +113,8 @@ def measure(replay, win: dict, parts: list, top: str,
                                   if waits else None),
             "top_wait_mean_s": (sum(waits) / len(waits)
                                 if waits else None)}
-    return out, {"top_wait_p95_s": out["tta_top_p95_s"]}, info
+    window = {"top_wait_p95_s": out["tta_top_p95_s"]}
+    for klass, w in waits_of.items():
+        window[f"wait_mean_s.{klass}"] = sum(w) / len(w)
+        window[f"wait_p95_s.{klass}"] = p95(w)
+    return out, window, info

@@ -30,6 +30,19 @@ deployment's shape. A kind gives:
                               any key outside
                               ``driver.SCHEDULER_OPTIONS``, so that no kind
                               sets a router threshold
+``feature_gates(cfg)``        optional: upstream's ``featureGates`` of the
+                              deployment, name -> bool. The harness
+                              refuses any name outside
+                              ``driver.FEATURE_GATES`` (the gates of
+                              upstream's ``kube_features.go`` that change
+                              what the scheduler decides), the program's
+                              own gates among them, and sets them once,
+                              before anything of the program is built.
+                              A kind without it sets none
+``GIVEN``                     optional: ``True`` asks for ``given`` in
+                              every pass-log record (below): a kind
+                              whose guarantees are about WHICH quota and
+                              WHICH nodes
 ``audit(cfg, arrivals, preloaded, pass_log)``
                               the kind's plain reference. It imports
                               nothing of the program and returns
@@ -45,5 +58,13 @@ deployment's shape. A kind gives:
 
 A pass-log record holds ``events`` [(``arrive`` | ``finish``, key, due_s)],
 ``added`` and ``removed`` (keys that gained or lost a quota reservation
-between the end of the pass before and the end of this one).
+between the end of the pass before and the end of this one): a workload
+that loses and regains its reservation inside one pass stands in neither.
+For a kind with ``GIVEN`` a record also holds ``given``: one entry for
+EVERY reservation the store reported in the pass, in order, such a
+workload's among them, as plain data (``driver.given_as_data``):
+``{"key", "podsets": [{"name", "count", "flavors": resource -> flavor
+name, "usage": resource -> quantity, "topology": {"levels", "domains":
+[[values, count], ...]} or None}, ...]}``. A record of a kind that does
+not ask has no ``given``.
 """

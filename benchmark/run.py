@@ -381,10 +381,13 @@ def check(replay, cfg: dict, fallbacks0: dict, *,
     """Every number compared, each beside its limit (all 0: integer
     kernels, exact comparison). ``cfg`` is the configuration as stated
     (``replay.cfg`` is what the program was given: a control's differs);
-    the counts of the guarantees are its kind's own."""
+    the counts of the guarantees are its kind's own, and may be about
+    flavors and placement: what the log's reservations were ``given``
+    becomes plain data here, after the window."""
     from benchmark import deployment, driver
 
     t = time.monotonic()
+    driver.given_as_data(replay.passes)
     audit = deployment.kind_of(cfg).audit(
         cfg, replay.arrivals, replay.preloaded, replay.passes)
     t_audit = time.monotonic() - t
@@ -514,6 +517,7 @@ def main(argv=None) -> int:
 
     import jax
 
+    from kueue_oss_tpu import features
     from kueue_oss_tpu.obs import spans
 
     from benchmark import (deployment, driver, endtoend, progspans, readers,
@@ -534,6 +538,10 @@ def main(argv=None) -> int:
         deployed = kind.controls[a.control][0](cfg)
     traffic = deployment.load_traffic(cell["traffic"])
     start_at = float(traffic["start_at_s"])
+    # upstream's featureGates, as the kind states them: once, before
+    # anything of the program is built (one process runs one cell)
+    gates = driver.feature_gates(deployed)
+    features.set_gates(gates)
     compiles = CompileCounter()
     fallbacks0 = fallback_counts()
 
@@ -650,7 +658,8 @@ def main(argv=None) -> int:
                                       "unit": m["unit"]}
     info.update({
         "cell": cell["name"], "seed": a.seed, "seconds": seconds,
-        "e2e": e2e, "passes": facts["passes"],
+        "feature_gates": gates, "e2e": e2e, "window": facts["window"],
+        "passes": facts["passes"],
         "counters": facts["counters"], "phase_s": facts["phase_s"],
         "span_s": facts["span_s"], "idle_s": win["idle_s"],
         "overshoot_s": win["closed"] - win["t_end"],

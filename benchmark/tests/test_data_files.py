@@ -59,7 +59,8 @@ def test_readers_read_facts_and_leave_out_what_is_not_there():
              "span_s": {"run_until_quiet": 5.0},
              "counters": {"passes": 4.0, "passes_with_drain": 1.0,
                           "evictions": 0, "reservations": 0},
-             "window": {"top_wait_p95_s": 1.5}, "trace": None}
+             "window": {"top_wait_p95_s": 1.5, "wait_mean_s.large": 0.5},
+             "trace": None}
     assert readers.ledger_phase(facts, ["solve"], per="passes") == 0.5
     assert readers.ledger_phase(facts, ["solve"], per="window_s",
                                 scale=100.0) == 25.0
@@ -74,7 +75,9 @@ def test_readers_read_facts_and_leave_out_what_is_not_there():
     # nothing reserved: no share to report, and never a made-up 0
     assert readers.counter(facts, "evictions", "reservations") is None
     assert readers.window(facts, "top_wait_p95_s") == 1.5
-    assert readers.window(facts, "absent") is None
+    # a class's waits, and a class with nobody due in the window
+    assert readers.window(facts, "wait_mean_s.large") == 0.5
+    assert readers.window(facts, "wait_mean_s.absent") is None
     assert readers.trace_busy(facts, "idle_share") is None
     facts["trace"] = {"busy_s": 2.0, "window_s": 8.0}
     assert readers.trace_busy(facts, "idle_share") == 75.0

@@ -69,7 +69,10 @@ def test_pass_s_and_the_top_class_wait():
                        a(key="c0", klass="top", due_s=-1.0),
                        a(key="zz", klass="top", due_s=2.0),
                        a(key="late", klass="top", due_s=11.0),
-                       a(key="a1", klass="other", due_s=0.0)]
+                       a(key="a1", klass="other", due_s=0.0),
+                       a(key="b0", klass="other", due_s=5.0),
+                       a(key="nobody", klass="other", due_s=9.0),
+                       a(key="later", klass="idle", due_s=10.5)]
     out, window, info = endtoend.measure(replay, win, parts, "top", 7.0)
     assert out["pass_s"] == pytest.approx(10.0 / 2.5)
     assert out["setup_s"] == 7.0
@@ -77,7 +80,19 @@ def test_pass_s_and_the_top_class_wait():
     # start; zz never got a seat and had waited 8 s at the end
     assert info["top_due"] == 3 and info["top_still_waiting"] == 1
     assert out["tta_top_p95_s"] == pytest.approx(10.0)
-    assert window == {"top_wait_p95_s": out["tta_top_p95_s"]}
+    assert window["top_wait_p95_s"] == out["tta_top_p95_s"]
+    # every class of the schedule by the same rule: ``top`` waited 0.5,
+    # 10 (created before the window) and 8 s (still waiting); ``other``
+    # 1.01 s, 0 s (b0 was seated at 4.0, before it was due) and 1 s
+    # (still waiting); ``idle`` has nobody due in the window
+    assert window["wait_mean_s.top"] == pytest.approx(18.5 / 3)
+    assert window["wait_mean_s.top"] == info["top_wait_mean_s"]
+    assert window["wait_p95_s.top"] == out["tta_top_p95_s"]
+    assert window["wait_mean_s.other"] == pytest.approx(2.01 / 3)
+    assert window["wait_p95_s.other"] == pytest.approx(1.01)
+    assert set(window) == {"top_wait_p95_s"} | {
+        f"wait_{stat}_s.{k}" for stat in ("mean", "p95")
+        for k in ("top", "other")}
     assert info["top_wait_median_s"] == pytest.approx(8.0)
     empty = a(passes=[], reservations=[], evictions=[], arrivals=[],
               start_at=0.0)

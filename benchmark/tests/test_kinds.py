@@ -11,41 +11,24 @@
 """
 
 import json
-import os
-import shutil
 
 import pytest
 
-from benchmark import deployment, driver, kinds, run
+from benchmark import deployment, driver, run
 from benchmark.tests import record_flat_golden, toy_kind
+from benchmark.tests.kind_tree import add_kind, rehearse
 
 
 @pytest.fixture
 def toy_tree(tmp_path, monkeypatch):
     """The benchmark's data directories copied as they are, and the toy's
     files added beside them."""
-    for sub in ("configs", "traffic", "layers"):
-        shutil.copytree(os.path.join(deployment.HERE, sub), tmp_path / sub)
-    (tmp_path / "kinds").mkdir()
-    (tmp_path / "kinds" / "toy.py").write_text(toy_kind.KIND)
-    (tmp_path / "configs" / "toy.json").write_text(
-        json.dumps(toy_kind.CONFIG))
-    (tmp_path / "traffic" / "toy-backlog.json").write_text(
-        json.dumps(toy_kind.TRAFFIC))
-    bench = run.load_benchmark()
-    bench["configs"].append(toy_kind.CONFIG_ENTRY)
-    bench["workloads"].append(toy_kind.CELL)
-    monkeypatch.setattr(deployment, "HERE", str(tmp_path))
-    monkeypatch.setattr(kinds, "__path__",
-                        [*kinds.__path__, str(tmp_path / "kinds")])
-    monkeypatch.setattr(run, "load_benchmark", lambda: bench)
+    add_kind(tmp_path, monkeypatch, toy_kind)
     return tmp_path
 
 
 def drive(capsys, *extra) -> dict:
-    assert run.main(["--workload", "toy-backlog", "--seconds", "1.5",
-                     "--trace", "0", "--rehearse", *extra]) == 0
-    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    return rehearse(capsys, "toy-backlog", *extra)[0]
 
 
 def test_toy_kind_runs_as_added_files(toy_tree, capsys, monkeypatch):

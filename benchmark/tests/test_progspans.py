@@ -201,9 +201,12 @@ def test_traced_run_reports_the_programs_spans_through_run_main(capsys):
             want.add(m["name"])
         elif layer["reader"] in ("trace_scope", "trace_busy"):
             assert m["name"] not in r["metrics"]
-    assert len(want) == 17 and want <= set(r["metrics"])
+    assert len(want) == 18 and want <= set(r["metrics"])
     v = {k: m["value"] for k, m in r["metrics"].items()}
     assert v["cycles_per_pass"] >= 1.0
+    # parking is a part of apply: a share of the window like its siblings
+    assert 0.0 <= v["apply_park_share"] < 100.0 * (
+        v["apply_s_per_pass"] * info["passes"] / 4.0)
     # PERF.md section 5's identity: the cycle's parts, the router and
     # the drain's untimed rest are the host cycles (the benchmark's span
     # around run_until_quiet less the drains' phases), in % of the window
