@@ -187,7 +187,7 @@ def run_scenario(scenario: str) -> dict:
         # the timing window ENDS at a host-side scalar fetch: only a
         # materialized result bounds the wall
         (admitted, opt, admit_round, parked, rounds, usage, wl_usage,
-         _reason) = out
+         _reason) = out[:8]
         n_admitted = int(np.asarray(admitted).sum())
         n_rounds = int(rounds)
         elapsed = time.monotonic() - t0

@@ -54,6 +54,12 @@ class DrainResult:
     admitted_keys: list[str] = field(default_factory=list)
     #: initially-admitted workload keys preempted by the drain
     evicted_keys: list[str] = field(default_factory=list)
+    #: the victim search's liveness gate over the drain's rounds
+    #: (full_kernels._run_searches): lanes offered, and lanes that ran
+    #: the heavy search; 0 for a lean drain and for a sidecar's, whose
+    #: wire carries the plan alone
+    search_lanes: int = 0
+    search_live_lanes: int = 0
 
 
 class SolverEngine:
@@ -659,7 +665,10 @@ class SolverEngine:
             parked=parked_n, rounds=result.rounds, solver_arm=arm,
             frame_kind=frame_kind, frame_bytes=frame_bytes,
             frame_reason=frame_reason, session=session,
-            grant_wait_ms=grant_wait_ms, device=device)
+            grant_wait_ms=grant_wait_ms, device=device,
+            detail=({"searchLanes": result.search_lanes,
+                     "searchLiveLanes": result.search_live_lanes}
+                    if result.search_lanes else None))
 
     # -- mesh routing (solver/meshutil.py, solver/sharded.py) --------------
 
@@ -1045,11 +1054,10 @@ class SolverEngine:
                 with spans.span("dispatch"):
                     if full:
                         from kueue_oss_tpu.solver.full_kernels import (
-                            solve_backlog_full,
+                            full_solver,
                         )
 
-                        out = solve_backlog_full(tensors, mesh=mesh,
-                                                 **caps)
+                        out = full_solver(mesh=mesh, **caps)(tensors)
                     else:
                         out = meshutil.lean_mesh_solver(mesh)(tensors)
                 out = self._fetch(out)
@@ -1077,10 +1085,10 @@ class SolverEngine:
             with spans.span("dispatch"):
                 if full:
                     from kueue_oss_tpu.solver.full_kernels import (
-                        solve_backlog_full,
+                        full_solver,
                     )
 
-                    out = solve_backlog_full(tensors, **caps)
+                    out = full_solver(**caps)(tensors)
                 else:
                     out = solve_backlog(tensors)
             out = self._fetch(out)
@@ -1755,11 +1763,16 @@ class SolverEngine:
                     g_max=g_max, h_max=h_max, p_max=p_max,
                     fs_enabled=self.enable_fair_sharing)
             else:
+                # the program's own return (full_kernels.full_solver):
+                # the plan, then the liveness gate's two counts
                 (admitted, opt, admit_round, parked, rounds, _usage,
-                 _wl_usage, victim_reason) = self._local_solve(
+                 _wl_usage, victim_reason, search_lanes,
+                 search_live_lanes) = self._local_solve(
                     problem, frame, full=True, n_live=n_live,
                     g_max=g_max, h_max=h_max, p_max=p_max,
                     fs_enabled=self.enable_fair_sharing)
+                result.search_lanes = int(search_lanes)
+                result.search_live_lanes = int(search_live_lanes)
             admitted = np.asarray(admitted)
             opt = np.asarray(opt)
             admit_round = np.asarray(admit_round)
@@ -1779,6 +1792,8 @@ class SolverEngine:
                                   verify=verify)
         result.apply_time_s = sp.seconds
         spans.count("drain_admitted", result.admitted)
+        spans.count("search_lanes", result.search_lanes)
+        spans.count("search_live_lanes", result.search_live_lanes)
         W = problem.n_workloads
         with spans.span("record"):
             self._ledger_record(

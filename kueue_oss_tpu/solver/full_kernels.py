@@ -563,45 +563,47 @@ def build_candidate_table(t: FullTensors, admitted, admit_rank, wl_usage,
     return table.at[row, col].set(sorted_w, mode="drop")
 
 
-@jax.named_scope("classical_search")
-def classical_search(t: FullTensors, usage0_round, wl_usage, admitted,
-                     evicted_f, ts, head_w, req, avail_cq,
-                     cands, p_max: int):
-    """Victim search for ONE preemptor (vmap over lanes).
+def _frs_needing_preemption(req, avail_cq):
+    """[F] bool: the FRs a preemptor requests beyond what its queue has
+    left. None: nothing to search for."""
+    return (req > 0) & (req > avail_cq)
 
-    ``cands`` is the preemptor root's row of build_candidate_table:
-    round-start admitted workloads in the shared candidate order, W_null
-    padded — the only workloads that can ever be victims, on an axis
-    bounded by cohort capacity instead of cohort population.
 
-    Returns (success, victim_w [P] int32 (W_null padded), victim_valid [P]
-    bool, victim_reason [P] int8, any_same_cq bool, borrow_after int32).
-    Mirrors Preemptor._classical_preemptions: candidate generation +
-    ordering, two allow-borrowing attempts of the remove-until-fits walk,
-    then fillBackWorkloads. The walk is a bulk-skip loop: pop-time
-    validity (over-quota predicates, candidate_generator.go _valid) is
-    monotone non-increasing under removals, so all currently-invalid
-    candidates are skipped in one parallel step and each iteration
-    removes exactly one true victim — the loop trips #victims times, not
-    p_max times. ``borrow_after`` is the FindHeightOfLowestSubtreeThatFits
-    level computed on the usage with the chosen victims removed
-    (round-start usage when the search fails), maxed over the FRs needing
-    preemption — simulate_preemption's borrow-after that ranks preempt
-    flavors in the assigner's granular mode; ``any_same_cq`` distinguishes
-    Preempt from Reclaim possibilities (preemption_oracle.go).
-    """
-    W1 = t.wl_cqid.shape[0]
-    W_null = W1 - 1
+class _SearchStage1(NamedTuple):
+    """What the liveness stage of one lane's victim search hands to the
+    walk stage (:func:`_search_stage1` -> :func:`_search_stage2`)."""
+
+    frs_mask: jnp.ndarray      # [F] bool FRs needing preemption
+    lca_d: jnp.ndarray         # [P] int32 LCA index on the preemptor's path
+    hier_adv: jnp.ndarray      # [P] bool hierarchical advantage at the LCA
+    other_legal: jnp.ndarray   # [P] bool legal candidate of another CQ
+    legal_all: jnp.ndarray     # [P] bool legal candidate
+
+
+def _search_lane(t: FullTensors, head_w):
+    """(cqid, cqi, cq_node, my_path [D]) of one lane's preemptor."""
+    cqid = t.wl_cqid[head_w]
+    cqi = jnp.minimum(cqid, t.cq_node.shape[0] - 1)
+    cq_node = t.cq_node[cqi]
+    return cqid, cqi, cq_node, t.path[cq_node]
+
+
+def _search_stage1(t: FullTensors, usage0_round, wl_usage, admitted, ts,
+                   head_w, req, avail_cq, cands) -> _SearchStage1:
+    """Stage 1 of :func:`classical_search`, the cheap half: candidate
+    legality, the LCA ring, the hierarchical advantage and the
+    within-nominal pruning, all elementwise over the candidate axis. A
+    lane whose ``legal_all`` has a true entry is LIVE; any other lane's
+    result is already decided (:func:`_dead_search_result`). A lane
+    without a head is never live: round_body gives it no request, so no
+    FR needs preemption and no candidate uses one."""
+    W_null = t.wl_cqid.shape[0] - 1
     C_n = t.cq_node.shape[0]
     null_node = t.parent.shape[0] - 1
     D = t.path.shape[1]
-    cqid = t.wl_cqid[head_w]
-    cqi = jnp.minimum(cqid, C_n - 1)
-    cq_node = t.cq_node[cqi]
-    my_path = t.path[cq_node]                    # [D]
+    cqid, cqi, cq_node, my_path = _search_lane(t, head_w)
 
-    # FRs needing preemption: requested and not fitting current avail
-    frs_mask = (req > 0) & (req > avail_cq)      # [F]
+    frs_mask = _frs_needing_preemption(req, avail_cq)      # [F]
 
     # ---- candidate legality (candidate_generator.go:34-160) -------------
     present = cands != W_null
@@ -686,6 +688,25 @@ def classical_search(t: FullTensors, usage0_round, wl_usage, admitted,
     other_legal = legal & ~same_cq & other_ok & cand_over & path_over
     same_legal = legal & same_cq
     legal_all = other_legal | same_legal
+    return _SearchStage1(frs_mask, lca_d, hier_adv, other_legal, legal_all)
+
+
+def _search_stage2(t: FullTensors, usage0_round, wl_usage, evicted_f,
+                   head_w, req, cands, s1: _SearchStage1, p_max: int):
+    """Stage 2 of :func:`classical_search`, the heavy half: variants,
+    the candidate ordering (an argsort over ``p_max``), the two
+    remove-until-fits attempts (each opens with a ``p_max``-row
+    scatter-add and a cohort refresh), fill-back and ``borrow_after``.
+    Only a live lane needs it."""
+    W_null = t.wl_cqid.shape[0] - 1
+    C_n = t.cq_node.shape[0]
+    null_node = t.parent.shape[0] - 1
+    D = t.path.shape[1]
+    cqid, cqi, cq_node, my_path = _search_lane(t, head_w)
+    frs_mask, lca_d, hier_adv, other_legal, legal_all = s1
+    same_cq = t.wl_cqid[cands] == cqid
+    prio_p = t.wl_prio[head_w]
+    prio_c = t.wl_prio[cands]
 
     # ---- variants & groups ----------------------------------------------
     thr = t.cq_bwc_threshold[cqi]
@@ -844,6 +865,71 @@ def classical_search(t: FullTensors, usage0_round, wl_usage, admitted,
     victim_same = victims & (t.wl_cqid[cand_w] == cqid)
     any_same_cq = jnp.any(victim_same & cand_valid)
     return success, cand_w, victims, reason, any_same_cq, borrow_after
+
+
+def _dead_search_result(t: FullTensors, usage0_round, head_w, req,
+                        avail_cq, p_max: int):
+    """What :func:`classical_search` returns for a lane that is not
+    live (no legal candidate), without searching. Exact, not an
+    approximation: with ``legal_all`` all false every bucket is 6, so
+    ``cand_valid`` is all false and ``cand_w`` all W_null;
+    ``removable`` is then empty in both attempts, the walk finds no
+    slot to pop and ``fitted`` stays false, so ``success`` is false and
+    no slot is a victim; and ``usage_after`` takes its ``usage0_round``
+    branch, which leaves ``borrow_after`` as the round-start height
+    over the FRs needing preemption."""
+    W_null = t.wl_cqid.shape[0] - 1
+    _cqid, _cqi, cq_node, _path = _search_lane(t, head_w)
+    frs_mask = _frs_needing_preemption(req, avail_cq)
+    level_f, _ = _height_along_path(t, usage0_round, cq_node, req)
+    false = jnp.zeros((), dtype=bool)
+    return (false, jnp.full((p_max,), W_null, dtype=jnp.int32),
+            jnp.zeros((p_max,), dtype=bool),
+            jnp.full((p_max,), V_NEVER, dtype=jnp.int8), false,
+            jnp.max(jnp.where(frs_mask, level_f, 0)))
+
+
+@jax.named_scope("classical_search")
+def classical_search(t: FullTensors, usage0_round, wl_usage, admitted,
+                     evicted_f, ts, head_w, req, avail_cq,
+                     cands, p_max: int):
+    """Victim search for ONE preemptor (vmap over lanes).
+
+    ``cands`` is the preemptor root's row of build_candidate_table:
+    round-start admitted workloads in the shared candidate order, W_null
+    padded — the only workloads that can ever be victims, on an axis
+    bounded by cohort capacity instead of cohort population.
+
+    Returns (success, victim_w [P] int32 (W_null padded), victim_valid [P]
+    bool, victim_reason [P] int8, any_same_cq bool, borrow_after int32).
+    Mirrors Preemptor._classical_preemptions: candidate generation +
+    ordering, two allow-borrowing attempts of the remove-until-fits walk,
+    then fillBackWorkloads. The walk is a bulk-skip loop: pop-time
+    validity (over-quota predicates, candidate_generator.go _valid) is
+    monotone non-increasing under removals, so all currently-invalid
+    candidates are skipped in one parallel step and each iteration
+    removes exactly one true victim — the loop trips #victims times, not
+    p_max times. ``borrow_after`` is the FindHeightOfLowestSubtreeThatFits
+    level computed on the usage with the chosen victims removed
+    (round-start usage when the search fails), maxed over the FRs needing
+    preemption — simulate_preemption's borrow-after that ranks preempt
+    flavors in the assigner's granular mode; ``any_same_cq`` distinguishes
+    Preempt from Reclaim possibilities (preemption_oracle.go).
+
+    The search is two stages run one after the other:
+    :func:`_search_stage1` (legality, cheap) and :func:`_search_stage2`
+    (ordering and the walks, heavy). A lane is LIVE when stage 1 finds
+    a legal candidate; for any other lane (one without a head among
+    them) stage 2 can only return :func:`_dead_search_result` (see there
+    for why that is exact), which is what lets the drain run stage 2 on
+    the live lanes alone (:func:`_run_searches`). This function runs
+    both stages whatever the lane holds, so a caller that vmaps it gets
+    every lane's result the long way.
+    """
+    s1 = _search_stage1(t, usage0_round, wl_usage, admitted, ts,
+                        head_w, req, avail_cq, cands)
+    return _search_stage2(t, usage0_round, wl_usage, evicted_f,
+                          head_w, req, cands, s1, p_max)
 
 
 # ---------------------------------------------------------------------------
@@ -1048,10 +1134,105 @@ def full_round_scan(t: FullTensors, state, cand_w, mode, k_chosen, req_c,
 # ---------------------------------------------------------------------------
 
 
+#: Lanes a trip of the gated search's loop runs stage 2 on (see
+#: :func:`_gated_searches`). A search of no more lanes than one chunk
+#: is left ungated (:func:`_run_searches`): the gate could save it one
+#: trip at most, and at 32 lanes (``baseline-replay``) a gated program
+#: took 2.8 s longer to trace and load than the ungated one on the
+#: chip's host, four programs a set-up (+25 % of ``setup_s``).
+#: Settled on the chip at 1,024 lanes x 2,048 candidates, 20 victims a
+#: live lane (tools/search_chunk_sweep.py): a trip costs ~2.4 ms a lane
+#: of the chunk whatever the chunk, so the smallest of 32 / 128 / 256 /
+#: 1,024 tried wins with few live lanes (0.31 / 0.52 / 0.88 / 3.68 s
+#: with 1 to 32 live) and loses 6 % to 128 with all of them live.
+_STAGE2_CHUNK = 32
+
+
+def _gated_searches(t, usage, wl_usage, admitted, evicted, ts,
+                    flat_w, flat_req, flat_avail, flat_cands, p_max):
+    """``jax.vmap(classical_search)`` over the lanes, with stage 2 run
+    on the LIVE lanes only. Returns (the six per-lane results, the
+    number of lanes that ran stage 2).
+
+    Stage 1 runs on every lane; the live lanes (a legal candidate) are
+    sorted to the front, stably; stage 2 runs on chunks of ``B`` of
+    them in a loop of ``ceil(n_live / B)`` trips and its results
+    scatter back to lane order. Every other lane keeps
+    :func:`_dead_search_result`, which is what the whole search would
+    have returned for it. No live lane, no trip: a round in which
+    nobody can be evicted pays for stage 1 alone. And a round in which
+    no lane has an FR needing preemption (every head fits, or there are
+    no heads) skips stage 1 as well: stage 1 is a gather per candidate
+    and lane, 0.18 s at 1,024 x 2,048 on the chip, and with no such FR
+    no candidate ``uses`` one, so ``legal_all`` is false throughout.
+    """
+    L = flat_w.shape[0]
+    B = _STAGE2_CHUNK
+
+    def stage1():
+        return jax.vmap(
+            lambda hw, rq, av, cd: _search_stage1(
+                t, usage, wl_usage, admitted, ts, hw, rq, av, cd))(
+            flat_w, flat_req, flat_avail, flat_cands)
+
+    P = flat_cands.shape[1]
+    no_p = jnp.zeros((L, P), dtype=bool)
+    s1 = jax.lax.cond(
+        jnp.any(_frs_needing_preemption(flat_req, flat_avail)), stage1,
+        lambda: _SearchStage1(
+            jnp.zeros(flat_req.shape, dtype=bool),
+            jnp.zeros((L, P), dtype=jnp.int32), no_p, no_p, no_p))
+    live = jnp.any(s1.legal_all, axis=1)
+    n_live = jnp.sum(live.astype(jnp.int32))
+    dead = jax.vmap(
+        lambda hw, rq, av: _dead_search_result(
+            t, usage, hw, rq, av, p_max))(flat_w, flat_req, flat_avail)
+
+    # live lanes first, in lane order; padded to whole chunks with the
+    # out-of-range lane L, which gathers clamp and scatters drop
+    order = jnp.concatenate([
+        jnp.argsort(~live, stable=True).astype(jnp.int32),
+        jnp.full(((-L) % B,), L, dtype=jnp.int32)])
+
+    def cond(carry):
+        i, _out = carry
+        return i * B < n_live
+
+    def body(carry):
+        i, out = carry
+        lanes = jax.lax.dynamic_slice(order, (i * B,), (B,))
+        src = jnp.minimum(lanes, L - 1)
+        res = jax.vmap(
+            lambda hw, rq, cd, s: _search_stage2(
+                t, usage, wl_usage, evicted, hw, rq, cd, s, p_max))(
+            flat_w[src], flat_req[src], flat_cands[src],
+            jax.tree_util.tree_map(lambda a: a[src], s1))
+        # the last chunk's tail holds dead lanes: they keep ``dead``
+        dst = jnp.where(
+            i * B + jnp.arange(B, dtype=jnp.int32) < n_live, lanes, L)
+        return i + 1, tuple(
+            o.at[dst].set(r, mode="drop") for o, r in zip(out, res))
+
+    _, out = jax.lax.while_loop(
+        cond, body, (jnp.zeros((), dtype=jnp.int32), dead))
+    return out, n_live
+
+
 def _run_searches(t, usage, wl_usage, admitted, evicted, ts,
                   flat_w, flat_req, flat_avail, flat_cands, p_max,
                   fs_enabled, lendable_r, mesh, axis):
     """Run the per-lane victim searches, optionally SPMD over a mesh.
+    Returns (the six per-lane results, lanes that ran the heavy search).
+
+    On one device the classical search over more lanes than one chunk
+    (``_STAGE2_CHUNK``) is gated by liveness (:func:`_gated_searches`):
+    a lane with no legal candidate (no head, or nobody it may evict)
+    gets its result without the search. A search that fits one chunk,
+    the fair search and the mesh arm keep the ungated call on purpose,
+    and every lane counts as run: one chunk has at most one trip to
+    save, ``fair_search``'s dead-lane results are not established, and
+    sorting the live lanes to the front before a lane-sharded
+    ``shard_map`` would pile them onto one device.
 
     The victim search is the round's dominant cost and lanes are
     independent, so multi-chip scaling shards the LANE axis: each
@@ -1060,6 +1241,15 @@ def _run_searches(t, usage, wl_usage, admitted, evicted, ts,
     back. Per-round collective volume is the lane results only
     (L x p_max ints over ICI); the tree/usage state never moves.
     """
+    if mesh is None and not fs_enabled and flat_w.shape[0] > _STAGE2_CHUNK:
+        # the stages are called bare here, so the scope is opened here:
+        # liveness, compaction and the loop all read as the search's cost
+        with jax.named_scope("classical_search"):
+            return _gated_searches(
+                t, usage, wl_usage, admitted, evicted, ts,
+                flat_w, flat_req, flat_avail, flat_cands, p_max)
+    n_lanes = jnp.asarray(flat_w.shape[0], dtype=jnp.int32)
+
     def vsearch(hw, rq, av, cd, t_, usage_, wl_usage_, admitted_,
                 evicted_, ts_, lendable_):
         if fs_enabled:
@@ -1076,7 +1266,8 @@ def _run_searches(t, usage, wl_usage, admitted, evicted, ts,
 
     if mesh is None:
         return vsearch(flat_w, flat_req, flat_avail, flat_cands, t, usage,
-                       wl_usage, admitted, evicted, ts, lendable_r)
+                       wl_usage, admitted, evicted, ts,
+                       lendable_r), n_lanes
 
     from jax.sharding import PartitionSpec as P
 
@@ -1116,7 +1307,7 @@ def _run_searches(t, usage, wl_usage, admitted, evicted, ts,
                   wl_usage, admitted, evicted, ts, lend)
     if pad:
         out = tuple(o[:L] for o in out)
-    return out
+    return out, n_lanes
 
 
 @jax.named_scope("round_body")
@@ -1205,8 +1396,9 @@ def round_body(t: FullTensors, state, pot, g_max: int, h_max: int,
     flat_req = t.wl_req[lane_w].reshape(h_max * K, -1)
     flat_avail = jnp.repeat(lane_avail, K, axis=0)
     flat_cands = jnp.repeat(lane_cands, K, axis=0)
-    (s_succ, s_cand_w, s_victims, s_reason, s_same, s_borrow) = search(
-        flat_w, flat_req, flat_avail, flat_cands)
+    (s_succ, s_cand_w, s_victims, s_reason, s_same,
+     s_borrow), lanes_run = search(flat_w, flat_req, flat_avail, flat_cands)
+    lanes_offered = h_max * K
 
     # granular-mode table per (lane, option)
     sim_pmode = jnp.where(
@@ -1250,7 +1442,9 @@ def round_body(t: FullTensors, state, pot, g_max: int, h_max: int,
         # multi-group: GetTargets re-runs on the combined assignment
         # usage (preemption.py get_targets with all preempt-mode frs)
         (lane_success, lane_cand_w, lane_victims, lane_reason,
-         _s, _b) = search(lane_w, l_req, lane_avail, lane_cands)
+         _s, _b), run2 = search(lane_w, l_req, lane_avail, lane_cands)
+        lanes_run = lanes_run + run2
+        lanes_offered += h_max
     lane_success = (lane_success & lane_valid & (l_mode == M_PREEMPT))
 
     # compact victims to the front of each lane's slot axis: the entry
@@ -1360,6 +1554,10 @@ def round_body(t: FullTensors, state, pot, g_max: int, h_max: int,
         "victim_reason": out["victim_reason"],
         "lq_penalty": out["lq_penalty"], "progress": progress,
         "rounds": rounds + 1,
+        # how often the liveness gate engages (_run_searches): lanes
+        # offered to the victim search, and lanes that ran its stage 2
+        "search_lanes": state["search_lanes"] + lanes_offered,
+        "search_live_lanes": state["search_live_lanes"] + lanes_run,
     }
     debug = {
         "cand_w": cand_w, "mode": mode, "req_c": req_c,
@@ -1389,6 +1587,8 @@ def _init_state(t: FullTensors, g_max: int):
         "class_nofit": jnp.zeros((t.class_root.shape[0],), dtype=bool),
         "progress": jnp.ones((), dtype=bool),
         "rounds": jnp.zeros((), dtype=jnp.int32),
+        "search_lanes": jnp.zeros((), dtype=jnp.int32),
+        "search_live_lanes": jnp.zeros((), dtype=jnp.int32),
     }
 
 
@@ -1428,7 +1628,8 @@ def _solve_full_impl(t: FullTensors, g_max: int, h_max: int, p_max: int,
     parked = final["parked"].at[W_null].set(False)
     return (admitted, final["opt"], final["admit_round"], parked,
             final["rounds"], final["usage"], final["wl_usage"],
-            final["victim_reason"])
+            final["victim_reason"], final["search_lanes"],
+            final["search_live_lanes"])
 
 
 def lane_work_budget() -> int:
@@ -1518,10 +1719,14 @@ def debug_drain(problem: SolverProblem, g_max: int, h_max: int = 8,
 _solver_cache: dict = {}
 
 
-def solve_backlog_full(t: FullTensors, g_max: int, h_max: int = 32,
-                       p_max: int = 128, fs_enabled: bool = False,
-                       mesh=None, axis: str = "wl"):
-    """Cached-jit entry point; (g_max, h_max, p_max, fs) are compile-time.
+def full_solver(g_max: int, h_max: int = 32, p_max: int = 128,
+                fs_enabled: bool = False, mesh=None, axis: str = "wl"):
+    """The cached jitted drain for static caps; (g_max, h_max, p_max,
+    fs) are compile-time. Called on the tensors it returns the plan's
+    eight arrays (:func:`solve_backlog_full`) and, after them, the two
+    int32 sums of the victim search's liveness gate over the drain's
+    rounds: lanes offered, and lanes that ran the heavy search
+    (:func:`_run_searches`).
 
     The fair-sharing gates are baked in at trace time, so they join the
     cache key — a gate flip must not serve a stale compilation. With a
@@ -1541,7 +1746,17 @@ def solve_backlog_full(t: FullTensors, g_max: int, h_max: int = 32,
         fn = make_full_solver(g_max, h_max, p_max, fs_enabled,
                               mesh=mesh, axis=axis)
         _solver_cache[key] = fn
-    return fn(t)
+    return fn
+
+
+def solve_backlog_full(t: FullTensors, g_max: int, h_max: int = 32,
+                       p_max: int = 128, fs_enabled: bool = False,
+                       mesh=None, axis: str = "wl"):
+    """The plan of :func:`full_solver`'s program for ``t``: (admitted,
+    opt, admit_round, parked, rounds, usage, wl_usage, victim_reason).
+    The same on one device and over a mesh, which the search counts
+    that follow them in the program's return are not."""
+    return full_solver(g_max, h_max, p_max, fs_enabled, mesh, axis)(t)[:8]
 
 
 #: FullTensors fields the scenario overlay layer varies — the FULL
@@ -1572,8 +1787,9 @@ def solve_backlog_full_batched(t: FullTensors, overrides: dict,
     ``overrides`` maps FullTensors field names to stacked [S, ...]
     scenario variants; unnamed fields broadcast unbatched (the large
     ``wl_req`` tensor on quota-only sweeps costs one copy, not S).
-    Returns the solve_backlog_full 8-tuple with a leading scenario
-    axis on every output. The victim-search lane memory scales as
+    Returns the solve_backlog_full 8-tuple and the two search counts
+    that follow it (:func:`full_solver`), with a leading scenario axis
+    on every output. The victim-search lane memory scales as
     S x h_max x K x p_max — callers size S from a
     :class:`~kueue_oss_tpu.sim.batch.LaneBudget`, not from the sweep
     width. Mesh lane-sharding never composes with the scenario axis
