@@ -454,7 +454,7 @@ def observe_compiles() -> None:
 def fallback_counts() -> dict:
     """Every degradation hop the process took: the fallback counter by
     slug (device_error, mesh_error, backend_error, plan_rejected,
-    breaker_open, unsupported, relax_*: whichever moved), the plan
+    breaker_open, unsupported: whichever moved), the plan
     entries the oracle refused, and the ladder's active levels."""
     from kueue_oss_tpu import metrics, resilience
 
@@ -863,8 +863,8 @@ def tas_store(n_workloads: int):
     """The upstream ``tas`` performance config: 640 nodes as 1 block x
     10 racks x 64 hosts (96 cpu each), the baseline's 5 cohorts x 6
     ClusterQueues over the one topology, and a backlog of required /
-    preferred / unconstrained rack requests (as bench.py's tas_drain
-    scenario builds it)."""
+    preferred / unconstrained rack requests (BASELINE.md's TAS
+    config row)."""
     from kueue_oss_tpu.api.types import (
         ClusterQueue,
         Cohort,
@@ -1065,17 +1065,16 @@ def phase_mesh(a) -> dict:
     from kueue_oss_tpu.solver.engine import SolverEngine
 
     class RecordingEngine(SolverEngine):
-        """Keeps the raw plan arrays each exact solve returned."""
+        """Keeps the raw plan arrays each local solve returned."""
 
-        def _solve_exact(self, problem, frame, **kw):
-            out = super()._solve_exact(problem, frame, **kw)
+        def _local_solve(self, problem, frame, **kw):
+            out = super()._local_solve(problem, frame, **kw)
             self.plans = getattr(self, "plans", []) + [out]
             return out
 
     def build(preemption: bool, arm: str) -> RecordingEngine:
         store, _ = large_scale_store(a, preemption)
         engine = RecordingEngine(store, QueueManager(store))
-        engine.relax_enabled = False  # the exact arms are compared
         if arm == "mesh":
             # the default floor (1,024 live rows) routes this size to
             # the mesh arm by itself; the rehearsal sizes need the pin

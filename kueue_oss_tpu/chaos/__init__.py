@@ -7,7 +7,7 @@ stalling admissions. This module *proves* it: a seeded injector decides,
 per request, which failure mode the sidecar exhibits, and a chaos
 server wraps the real solve path with those faults. The same injector
 drives the `chaos`-marked tests (tier-1: fully deterministic, injected
-clocks, no sleeps in the fast subset) and bench.py's chaos scenario.
+clocks, no sleeps in the fast subset).
 
 Failure modes (FAULTS):
 
@@ -86,10 +86,9 @@ class FaultInjector:
       (deterministic tests: "crash, then serve"). After the schedule is
       exhausted the injector falls through to the random mode.
     - ``weights``: {fault: weight} sampled with the seeded RNG (chaos
-      sweeps in bench.py). With neither, every request is served.
+      sweeps). With neither, every request is served.
 
-    ``injected`` counts what was actually injected, for assertions and
-    the bench JSON tail.
+    ``injected`` counts what was actually injected, for assertions.
     """
 
     def __init__(self, schedule=(), seed: int = 0,

@@ -26,7 +26,7 @@ The convergence oracle (docs/ROBUSTNESS.md "Chaos campaigns"):
 3. **Monotone recovery** — once the storm ends, the max degradation
    level (:mod:`kueue_oss_tpu.resilience`) never rises again and ends
    at 0; every transition is on the controller's history for the
-   bench tail / assertions.
+   assertions.
 
 Everything is deterministic: faults and flap schedules are drawn from
 ``random.Random(seed)`` at plan time, the controller's cooldown clock
@@ -70,7 +70,8 @@ POD_LOSS = "pod-loss"
 FED_PARTITION = "fed-partition"
 KILL_STORM = "kill-storm"
 
-#: every campaign profile bench.py's chaoscampaign scenario sweeps
+#: every campaign profile (the slow sweep of tests/test_chaos_campaign.py
+#: runs them all)
 PROFILES = (SOLVER_STORM, POD_LOSS, FED_PARTITION, KILL_STORM)
 
 #: which degradation subsystem each profile storms — the smoke tests
@@ -276,7 +277,7 @@ class ChaosCampaign:
             for c in range(spec.storm_cycles):
                 for _ in range(1 + (rng.random() < 0.5)):
                     self.fault_plan.setdefault(c, []).append(rng.choice(
-                        ("mesh", "all", "breaker", "relax")))
+                        ("mesh", "all", "breaker")))
         elif spec.profile == FED_PARTITION:
             for c in range(spec.storm_cycles):
                 if rng.random() < 0.6:
@@ -350,10 +351,6 @@ class ChaosCampaign:
             elif action == "breaker":
                 for _ in range(plane.engine.health.failure_threshold):
                     plane.engine.health.record_failure()
-            elif action == "relax":
-                plane.engine._note_relax_failure(
-                    RuntimeError("injected relax fault (campaign)"),
-                    "relax_error")
             elif action == "fsync":
                 plane.manager.wal.fsync_fault += 1
             elif action == "crash":
@@ -377,9 +374,6 @@ class ChaosCampaign:
         if mesh_inj is not None:
             mesh_inj.restore()
             plane.engine.health.record_success()
-            if resilience.controller.active(resilience.SOLVER,
-                                            "relax_broken"):
-                plane.engine._relax_broken = False
         if farm is not None:
             farm.throttle_fault.clear()
         if plane.manager is not None:

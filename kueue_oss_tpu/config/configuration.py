@@ -184,25 +184,6 @@ class SolverBackendConfig:
     coordinator_processes: int = 1
     #: this process's rank in [0, coordinator_processes)
     coordinator_process_id: int = 0
-    #: convex-relaxation fast-path arm (solver/relax.py,
-    #: docs/SOLVER_PROTOCOL.md "Relaxed fast-path arm"): the fourth
-    #: routing arm — projected-gradient LP + exact rounding-and-repair.
-    #: The cost-EMA router still decides per drain; disabling removes
-    #: the arm entirely.
-    relax_enabled: bool = True
-    #: lean backlogs below this many live workloads never route to the
-    #: relaxed arm (the LP amortizes only on huge contended backlogs)
-    relax_min_workloads: int = 4096
-    #: every Nth relax-served drain also runs the exact kernel and
-    #: demotes the arm on plan divergence (0 disables auditing —
-    #: never recommended in production)
-    relax_audit_every: int = 8
-    #: fixed projected-gradient iteration count (deterministic wall)
-    relax_iters: int = 32
-    #: rounding threshold on the fractional admit vector, in (0, 1)
-    relax_support_threshold: float = 0.5
-    #: demoted-arm cooldown before one re-probe drain
-    relax_retry_cooldown_seconds: float = 300.0
 
 
 @dataclass
@@ -561,16 +542,6 @@ def validate(cfg: Configuration) -> list[str]:
     if sv.coordinator_processes > 1 and not sv.coordinator_address:
         errs.append("solver.coordinatorAddress is required when "
                     "coordinatorProcesses > 1")
-    if sv.relax_min_workloads < 0:
-        errs.append("solver.relaxMinWorkloads must be >= 0")
-    if sv.relax_audit_every < 0:
-        errs.append("solver.relaxAuditEvery must be >= 0")
-    if sv.relax_iters < 1:
-        errs.append("solver.relaxIters must be >= 1")
-    if not (0.0 < sv.relax_support_threshold < 1.0):
-        errs.append("solver.relaxSupportThreshold must be in (0, 1)")
-    if sv.relax_retry_cooldown_seconds < 0:
-        errs.append("solver.relaxRetryCooldown must be >= 0")
     if sv.max_sessions is not None and sv.max_sessions < 1:
         errs.append("solver.maxSessions must be >= 1")
     fed = cfg.federation
@@ -793,13 +764,6 @@ def load(data: Optional[dict] = None) -> Configuration:
             "coordinatorAddress": ("coordinator_address", str),
             "coordinatorProcesses": ("coordinator_processes", int),
             "coordinatorProcessId": ("coordinator_process_id", int),
-            "relaxEnabled": ("relax_enabled", bool),
-            "relaxMinWorkloads": ("relax_min_workloads", int),
-            "relaxAuditEvery": ("relax_audit_every", int),
-            "relaxIters": ("relax_iters", int),
-            "relaxSupportThreshold": ("relax_support_threshold", float),
-            "relaxRetryCooldown": ("relax_retry_cooldown_seconds",
-                                   float),
         })
 
     def conv_federation(d: dict) -> FederationConfig:

@@ -184,8 +184,7 @@ class Histogram(_Series):
         exposition; ignored while ``exemplars_enabled`` is False.
         Stored as given — values stringify at render/accessor time, so
         the admission hot path pays one tuple store, not a dict
-        rebuild (the bench.py slo scenario's exemplar_overhead_pct
-        twin measures exactly this path)."""
+        rebuild."""
         key = self._key(label_values)
         if exemplar is not None and not exemplars_enabled:
             exemplar = None
@@ -771,20 +770,6 @@ solver_multihost_processes = registry.register(Gauge(
     "kueue_tpu_solver_multihost_processes",
     "jax processes in the pod-scale solver bootstrap "
     "(1 = single-host; set by service.serve_multihost)", ()))
-
-# -- convex-relaxation fast-path arm (solver/relax.py) -----------------------
-
-solver_relax_drains_total = registry.register(Counter(
-    "kueue_tpu_solver_relax_drains_total",
-    "Relaxed-arm solves by outcome (served = relax plan emitted; "
-    "audit_match / audit_diverged = exact-kernel disagreement audits; "
-    "error = arm fault, drain fell back to an exact arm)",
-    ("outcome",)))
-solver_relax_support_fraction = registry.register(Histogram(
-    "kueue_tpu_solver_relax_support_fraction",
-    "Rounded support size as a fraction of live backlog rows per "
-    "relaxed solve", (),
-    buckets=(0.01, 0.02, 0.05, 0.1, 0.2, 0.35, 0.5, 0.75, 1.0)))
 
 # -- streaming control plane (scheduler/streaming.py) ------------------------
 

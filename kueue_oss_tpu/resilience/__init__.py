@@ -1,8 +1,8 @@
 """Unified degradation ladder — one controller for every fault response.
 
-The control plane has five independent fault responses (solver circuit
-breaker, mesh breaker, relax-arm demotion, farm backpressure, streaming
-fence stalls) that historically each kept private state: a boolean and a
+The control plane has four independent fault responses (solver circuit
+breaker, mesh breaker, farm backpressure, streaming fence stalls) that
+historically each kept private state: a boolean and a
 ``time.monotonic()`` stamp buried in their own module. This package
 makes degraded operation a first-class, observable state machine:
 
@@ -21,7 +21,7 @@ makes degraded operation a first-class, observable state machine:
 
 The ladders (level 0 is the leftmost rung)::
 
-    solver:      mesh -> single -> relax-off -> host
+    solver:      mesh -> single -> host
     persistence: fsync-always -> batch -> wal-off-alarm
     streaming:   wide -> structural -> off
     federation:  farm -> dedicated -> host
@@ -48,7 +48,7 @@ SUBSYSTEMS = (SOLVER, PERSISTENCE, STREAMING, FEDERATION)
 #: subsystem -> ladder rungs, healthiest first. ``rung(sub)`` names the
 #: rung the current level maps to (levels past the last rung clamp).
 LADDERS = {
-    SOLVER: ("mesh", "single", "relax-off", "host"),
+    SOLVER: ("mesh", "single", "host"),
     PERSISTENCE: ("fsync-always", "batch", "wal-off-alarm"),
     STREAMING: ("wide", "structural", "off"),
     FEDERATION: ("farm", "dedicated", "host"),
@@ -56,14 +56,13 @@ LADDERS = {
 
 #: subsystem -> condition -> severity (the level the condition alone
 #: forces). A subsystem's level is the MAX severity among its active
-#: conditions: losing the mesh (1) and tripping the breaker (3) at once
-#: reads level 3, and healing the breaker drops it back to 1, not 0.
+#: conditions: losing the mesh (1) and tripping the breaker (2) at once
+#: reads level 2, and healing the breaker drops it back to 1, not 0.
 SEVERITY = {
     SOLVER: {
         "mesh_broken": 1,      # mesh arm tripped; single-chip still solves
-        "relax_broken": 2,     # relax arm demoted (error or disagreement)
-        "device_error": 3,     # local device solve failed; host cycles
-        "breaker_open": 3,     # sidecar breaker open; host cycles
+        "device_error": 2,     # local device solve failed; host cycles
+        "breaker_open": 2,     # sidecar breaker open; host cycles
     },
     PERSISTENCE: {
         "fsync_degraded": 1,   # fsync fault: dropped one durability rung

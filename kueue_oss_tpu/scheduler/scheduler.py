@@ -442,17 +442,6 @@ class Scheduler:
                     enable_fair_sharing=self.enable_fair_sharing,
                     remote=remote, health=health,
                     mesh_mode=(cfg.mesh if cfg is not None else None))
-                if cfg is not None:
-                    # relaxed fast-path arm knobs (solver/relax.py)
-                    eng = self._solver_instance
-                    eng.relax_enabled = cfg.relax_enabled
-                    eng.relax_min_workloads = cfg.relax_min_workloads
-                    eng.relax_audit_every = cfg.relax_audit_every
-                    eng.relax_iters = cfg.relax_iters
-                    eng.relax_support_threshold = (
-                        cfg.relax_support_threshold)
-                    eng.relax_retry_cooldown_s = (
-                        cfg.relax_retry_cooldown_seconds)
             self._ensure_streaming(self._solver_instance)
             return self._solver_instance
         self._ensure_streaming(self.solver)

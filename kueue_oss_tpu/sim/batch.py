@@ -650,7 +650,7 @@ def solve_scenarios_relax(problem: SolverProblem,
 
     import jax
 
-    from kueue_oss_tpu.solver.relax import (
+    from kueue_oss_tpu.sim.relax import (
         RelaxLP,
         build_lp,
         lp_loop,
@@ -688,14 +688,8 @@ def solve_scenarios_relax(problem: SolverProblem,
         tier=[RELAX_TIER] * S, batch_width=S)
     for i, (p, lp) in enumerate(zip(probs, lps)):
         sel = rounded_support(xs[i], p, np.asarray(lp.live))
-        (admitted, opt, admit_round, parked, rounds, usage), _ = repair(
-            p, sel, np.asarray(lp.live))
-        out.admitted[i] = np.asarray(admitted)
-        out.opt[i] = np.asarray(opt)
-        out.admit_round[i] = np.asarray(admit_round)
-        out.parked[i] = np.asarray(parked)
-        out.rounds[i] = np.asarray(rounds)
-        out.usage[i] = np.asarray(usage)
+        (out.admitted[i], out.opt[i], out.admit_round[i], out.parked[i],
+         out.rounds[i], out.usage[i]) = repair(p, sel, np.asarray(lp.live))
     out.solve_seconds = time.monotonic() - t0
     return out
 

@@ -1,12 +1,13 @@
-"""Convex-relaxation fast path for the batched admission problem.
+"""The what-if simulator's declared-approximate tier: a relaxed LP.
 
-The exact lean kernel (kernels._solve_backlog_impl) replays
-priority-ordered rounds whose count grows with per-CQ backlog depth and
-contention; on huge contended backlogs the round loop dominates the
-drain wall. CvxCluster (arXiv 2605.01614) shows that large granular
-allocation problems admit convex relaxations solved orders of magnitude
-faster as dense matrix iterations — exactly the shape that ``jit``,
-``vmap``, and mesh sharding love. This module is that arm:
+Scenarios the lane budget cannot run through the FULL kernel
+(sim/batch.plan_full_sweep) are answered by ``solve_scenarios_relax``
+with rows labelled ``tier="relax"`` (docs/SIMULATOR.md). This module is
+that tier's arithmetic, and the simulator is its only user: nothing on
+the served path (solver/, scheduler/) imports it, so "an approximate
+answer is allowed" is a decision that lives in sim/ alone. After
+CvxCluster (arXiv 2605.01614): large granular allocation problems admit
+convex relaxations solved as dense matrix iterations.
 
 1. **Relaxation** — the admission LP over a fractional admit vector
    x ∈ [0, 1]^W maximizing priority-weighted admission subject to one
@@ -19,9 +20,7 @@ faster as dense matrix iterations — exactly the shape that ``jit``,
    borrowing allowance, minus the full-charge total of current CQ
    usage. Solved by fixed-iteration projected gradient ascent on a
    quadratic penalty (pure ``jax.numpy``: one fori_loop of segment-sum
-   + ancestor-accumulate + clip per iteration — it jits, vmaps, and
-   shards over the ``wl`` mesh axis trivially; sharded variant in
-   solver/sharded.py:make_sharded_relax_lp).
+   + ancestor-accumulate + clip per iteration, vmapped over scenarios).
 
 2. **Rounding** — deterministic support selection on the host: rows
    with x above the threshold, per-CQ slack rows by relaxed score
@@ -34,27 +33,23 @@ faster as dense matrix iterations — exactly the shape that ``jit``,
 3. **Repair** — the EXACT lean kernel, run on the support rows
    compacted into a small padded subproblem (same node/CQ tensors,
    gathered workload rows). Whatever it admits is exactly feasible by
-   construction; results scatter back to full workload indices and the
-   emitted plan passes ``SolverEngine._check_plan`` unchanged. Rows
+   construction; results scatter back to full workload indices. Rows
    outside the support park (BestEffortFIFO) exactly like the exact
    kernel's quiescent state; StrictFIFO rows never park.
 
 The plan is therefore ALWAYS exactly feasible — approximation error
-can only show up as a different (usually identical, see the router's
-audit in solver/engine.py) admitted set, never as overcommitted quota.
+can only show up as a different admitted set, never as overcommitted
+quota.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
-import time
-from dataclasses import dataclass, field
-from typing import NamedTuple, Optional
+from typing import NamedTuple
 
 import numpy as np
 
-from kueue_oss_tpu.solver.tensors import BIG, SolverProblem, pow2
+from kueue_oss_tpu.solver.tensors import SolverProblem, pow2
 
 #: projected-gradient constants: step size, score (objective) weight,
 #: and the quadratic-penalty ramp rho0 * (1 + growth * i / iters). The
@@ -71,11 +66,7 @@ UNBOUNDED = np.float32(1 << 30)
 
 
 class RelaxLP(NamedTuple):
-    """Device inputs of the relaxed admission LP (jit pytree).
-
-    Workload-axis fields (``r``, ``s``, ``live``, ``wl_cqid``) shard
-    over the mesh ``wl`` axis; node/CQ fields replicate.
-    """
+    """Device inputs of the relaxed admission LP (jit pytree)."""
 
     r: np.ndarray        # [W+1, F] float32 request under the first valid option
     s: np.ndarray        # [W+1] float32 priority-major, FIFO-minor score
@@ -89,25 +80,8 @@ class RelaxLP(NamedTuple):
     scale: np.ndarray    # [N+1, F] float32 max(slack, 1) normalizer
 
 
-@dataclass
-class RelaxStats:
-    """Diagnostics for one relaxed solve (bench/metrics/ledger)."""
-
-    live: int = 0
-    support: int = 0
-    support_padded: int = 0
-    iters: int = 0
-    lp_seconds: float = 0.0
-    repair_seconds: float = 0.0
-    repair_rounds: int = 0
-    #: final fractional solution (tests/diagnostics; [W+1] float32)
-    x: Optional[np.ndarray] = field(default=None, repr=False)
-
-
-def lp_step_body(lp: RelaxLP, x, i, iters: int, psum_axis=None):
-    """One projected-gradient iteration (shared by the single-chip jit
-    and the shard_map variant, which psums the per-CQ loads over the
-    mesh axis)."""
+def lp_step_body(lp: RelaxLP, x, i, iters: int):
+    """One projected-gradient iteration."""
     import jax
     import jax.numpy as jnp
 
@@ -119,8 +93,6 @@ def lp_step_body(lp: RelaxLP, x, i, iters: int, psum_axis=None):
     d_max = lp.path_cq.shape[1]
     load_cq = jax.ops.segment_sum(lp.r * x[:, None], lp.wl_cqid,
                                   num_segments=C + 1)[:C]
-    if psum_axis is not None:
-        load_cq = jax.lax.psum(load_cq, psum_axis)
     u = jnp.zeros((N1, F), lp.r.dtype).at[lp.cq_node].add(load_cq)
     u = accumulate_full_charge(lp.parent, lp.depth, u, d_max)
     # RELATIVE violation, clipped: scale-invariant pricing. Normalizing
@@ -141,7 +113,7 @@ def lp_step_body(lp: RelaxLP, x, i, iters: int, psum_axis=None):
     return jnp.where(lp.live, x, 0.0)
 
 
-def lp_loop(lp: RelaxLP, iters: int, psum_axis=None):
+def lp_loop(lp: RelaxLP, iters: int):
     """The full fixed-iteration LP solve (trace-time body)."""
     import jax
     import jax.numpy as jnp
@@ -149,14 +121,7 @@ def lp_loop(lp: RelaxLP, iters: int, psum_axis=None):
     x0 = jnp.where(lp.live, jnp.float32(0.5), jnp.float32(0.0))
     return jax.lax.fori_loop(
         0, iters,
-        lambda i, x: lp_step_body(lp, x, i, iters, psum_axis), x0)
-
-
-@functools.lru_cache(maxsize=None)
-def _single_lp(iters: int):
-    import jax
-
-    return jax.jit(functools.partial(lp_loop, iters=iters))
+        lambda i, x: lp_step_body(lp, x, i, iters), x0)
 
 
 # ---------------------------------------------------------------------------
@@ -166,8 +131,8 @@ def _single_lp(iters: int):
 
 def _full_charge_np(parent: np.ndarray, depth: np.ndarray,
                     values: np.ndarray, d_max: int) -> np.ndarray:
-    """Numpy twin of kernels.accumulate_full_charge for the per-drain
-    constant headroom tensors."""
+    """Numpy twin of kernels.accumulate_full_charge for the
+    per-scenario constant headroom tensors."""
     u = values.copy()
     for d in range(d_max - 1, 0, -1):
         rows = depth == d
@@ -248,8 +213,8 @@ def strict_rows(problem: SolverProblem) -> np.ndarray:
 
 def rounded_support(x: np.ndarray, problem: SolverProblem,
                     live: np.ndarray, threshold: float = 0.5,
-                    slack_frac: float = 0.25, slack_min: int = 4,
-                    strict: Optional[np.ndarray] = None) -> np.ndarray:
+                    slack_frac: float = 0.25,
+                    slack_min: int = 4) -> np.ndarray:
     """Boolean support mask over the real workload rows [W].
 
     Selected: live rows with x >= threshold; every live StrictFIFO row
@@ -266,9 +231,7 @@ def rounded_support(x: np.ndarray, problem: SolverProblem,
     cq = np.asarray(problem.wl_cqid)[:W]
     livew = np.asarray(live)[:W]
     xw = np.asarray(x)[:W]
-    if strict is None:
-        strict = strict_rows(problem)
-    sel = livew & ((xw >= threshold) | strict[:W])
+    sel = livew & ((xw >= threshold) | strict_rows(problem)[:W])
     cand = np.nonzero(livew & ~sel)[0]
     if cand.size:
         rank = np.asarray(problem.wl_rank)[:W]
@@ -313,33 +276,23 @@ def restrict_problem(problem: SolverProblem, sel_idx: np.ndarray,
     )
 
 
-def repair(problem: SolverProblem, sel: np.ndarray, live: np.ndarray,
-           pad_to: int = 0,
-           strict: Optional[np.ndarray] = None) -> tuple[tuple, RelaxStats]:
+def repair(problem: SolverProblem, sel: np.ndarray,
+           live: np.ndarray) -> tuple:
     """Run the exact lean kernel on the rounded support and scatter the
     plan back to full workload indices.
 
     Returns the full ``solve_backlog`` contract — (admitted, opt,
-    admit_round, parked, rounds, usage), numpy, [W+1]-shaped — plus
-    stats. ``pad_to`` is the caller's sticky support pad target so
-    steady-state drains reuse one compiled repair program.
+    admit_round, parked, rounds, usage), numpy, [W+1]-shaped.
     """
     from kueue_oss_tpu.solver.kernels import solve_backlog, to_device
 
     W1 = problem.wl_cqid.shape[0]
     sel_idx = np.nonzero(sel)[0]
     S = len(sel_idx)
-    target = max(pow2(S + 1) - 1, pad_to)
-    stats = RelaxStats(live=int(np.asarray(live)[:-1].sum()), support=S,
-                       support_padded=target)
+    sub = restrict_problem(problem, sel_idx, pow2(S + 1) - 1)
+    adm_s, opt_s, round_s, _, rounds, usage = (
+        np.asarray(a) for a in solve_backlog(to_device(sub)))
 
-    t0 = time.monotonic()
-    sub = restrict_problem(problem, sel_idx, target)
-    out = solve_backlog(to_device(sub))
-    out = tuple(np.asarray(a) for a in out)
-    stats.repair_seconds = time.monotonic() - t0
-
-    adm_s, opt_s, round_s, parked_s, rounds, usage = out
     admitted = np.zeros(W1, dtype=bool)
     opt = np.zeros(W1, dtype=np.int32)
     admit_round = np.zeros(W1, dtype=np.int32)
@@ -350,71 +303,8 @@ def repair(problem: SolverProblem, sel: np.ndarray, live: np.ndarray,
     # rows the plan leaves unadmitted park exactly like the exact
     # kernel's quiescent state: every live BestEffortFIFO row; never a
     # StrictFIFO row (their heads block in place)
-    if strict is None:
-        strict = strict_rows(problem)
-    parked = np.asarray(live, dtype=bool) & ~admitted & ~strict
+    parked = (np.asarray(live, dtype=bool) & ~admitted
+              & ~strict_rows(problem))
     parked[-1] = False
     admitted[-1] = False
-    stats.repair_rounds = int(rounds)
-    return (admitted, opt, admit_round, parked, rounds, usage), stats
-
-
-# ---------------------------------------------------------------------------
-# The whole arm
-# ---------------------------------------------------------------------------
-
-
-def solve_relaxed(problem: SolverProblem, *, iters: int = 32,
-                  threshold: float = 0.5, mesh=None,
-                  pad_to: int = 0) -> tuple[tuple, RelaxStats]:
-    """Relax → round → repair one padded lean problem.
-
-    With a ``mesh`` (whose width divides the padded axis) the LP
-    iterations run sharded over the ``wl`` axis; the repair subproblem
-    is small by construction and stays single-chip. The emitted plan is
-    exactly feasible (it IS a lean-kernel plan over the support) and
-    passes the engine's ``_check_plan`` unchanged.
-    """
-    lp = build_lp(problem)
-    t0 = time.monotonic()
-    if mesh is not None:
-        from kueue_oss_tpu.solver import meshutil
-
-        if meshutil.mesh_divisible(mesh, lp.r.shape[0]):
-            fn = meshutil.relax_mesh_lp(mesh, iters)
-        else:
-            fn = _single_lp(iters)
-    else:
-        fn = _single_lp(iters)
-    x = np.asarray(fn(lp))
-    lp_seconds = time.monotonic() - t0
-
-    strict = strict_rows(problem)
-    sel = rounded_support(x, problem, lp.live, threshold=threshold,
-                          strict=strict)
-    out, stats = repair(problem, sel, lp.live, pad_to=pad_to,
-                        strict=strict)
-    stats.iters = iters
-    stats.lp_seconds = lp_seconds
-    stats.x = x
-    return out, stats
-
-
-def plans_agree(plan_a: tuple, plan_b: tuple, n_workloads: int) -> bool:
-    """Semantic plan equality over the real rows: same admitted set,
-    same parked set, same chosen flavor option per admitted row.
-    ``admit_round``/``rounds`` are NOT compared — the relaxed arm's
-    repair runs over a compacted axis, so its round numbering differs
-    while the decisions (and the per-round apply order they induce
-    within a CQ) do not.
-    """
-    W = n_workloads
-    adm_a = np.asarray(plan_a[0])[:W].astype(bool)
-    adm_b = np.asarray(plan_b[0])[:W].astype(bool)
-    if not np.array_equal(adm_a, adm_b):
-        return False
-    if not np.array_equal(np.asarray(plan_a[3])[:W].astype(bool),
-                          np.asarray(plan_b[3])[:W].astype(bool)):
-        return False
-    return bool(np.array_equal(np.asarray(plan_a[1])[:W][adm_a],
-                               np.asarray(plan_b[1])[:W][adm_b]))
+    return admitted, opt, admit_round, parked, rounds, usage

@@ -186,8 +186,9 @@ class SolverEngine:
         #: compiles for collectives they cannot amortize
         self.mesh_min_workloads = 1024
         #: pin drains to the mesh arm regardless of cost estimates
-        #: (bench measurement + parity tests; never set in production —
-        #: the whole point of the EMA router is measured routing)
+        #: (chip_smoke.py's mesh phase + parity tests; never set in
+        #: production — the whole point of the EMA router is measured
+        #: routing)
         self.mesh_force = False
         #: adaptive arm routing: measured solve wall PER EXPORTED
         #: WORKLOAD by (kernel kind, arm in {"single", "mesh"}); the
@@ -204,44 +205,12 @@ class SolverEngine:
         #: the router against the arm; only warm samples are recorded
         self._arm_warm: set[tuple[str, str]] = set()
         #: chaos injection point: called with the arm name ("mesh" /
-        #: "single" / "relax") right before each local solve; raising
+        #: "single") right before each local solve; raising
         #: simulates a device loss on that arm (kueue_oss_tpu/chaos
         #: MeshFaultInjector)
         self.solve_fault_hook = None
         #: arm that served the most recent local solve (diagnostics)
         self.last_drain_arm: Optional[str] = None
-        #: convex-relaxation fast-path arm (solver/relax.py,
-        #: docs/SOLVER_PROTOCOL.md "Relaxed fast-path arm"): a
-        #: projected-gradient LP relaxation + exact rounding-and-repair
-        #: through the lean kernel. Lean (fit-only) in-process drains
-        #: only; the fourth arm of the cost-EMA router beside
-        #: host/single-chip/mesh. Knobs mirror SolverBackendConfig.
-        self.relax_enabled = True
-        #: backlogs below this stay on the exact arms (the LP's win is
-        #: amortizing the round loop over HUGE contended backlogs)
-        self.relax_min_workloads = 4096
-        #: every Nth relax-served drain ALSO runs the exact kernel and
-        #: compares plans; divergence demotes the arm (0 = never audit)
-        self.relax_audit_every = 8
-        #: fixed projected-gradient iteration count (determinism)
-        self.relax_iters = 32
-        #: rounding threshold on the fractional admit vector
-        self.relax_support_threshold = 0.5
-        #: demoted-arm cooldown before one re-probe (timed half-open,
-        #: mirroring the mesh breaker)
-        self.relax_retry_cooldown_s = 300.0
-        #: pin lean drains to the relax arm (bench/tests only)
-        self.relax_force = False
-        self._relax_broken = False
-        self._relax_drains = 0
-        #: sticky pow2 pad target for the repair subproblem's support
-        #: axis, so steady-state relax drains reuse ONE compiled repair
-        self._relax_pad_hwm = 0
-        #: stats of the most recent relaxed solve (bench/diagnostics)
-        self.last_relax_stats = None
-        #: result of the most recent disagreement audit (None = the
-        #: last relax drain was not audited)
-        self.last_relax_audit: Optional[bool] = None
         #: streaming micro-batch admitter (scheduler/streaming.py);
         #: every completed full drain re-arms its fences — a full
         #: solve is the oracle-parity baseline boundary
@@ -672,7 +641,7 @@ class SolverEngine:
 
     # -- mesh routing (solver/meshutil.py, solver/sharded.py) --------------
 
-    # The mesh/relax breaker state lives on the process-wide
+    # The mesh breaker state lives on the process-wide
     # DegradationController (resilience package) — one ladder, one
     # cooldown policy, observable levels. These properties keep the
     # historical private names working for tests and diagnostics.
@@ -699,29 +668,6 @@ class SolverEngine:
     def _mesh_broken_at(self, t: float) -> None:
         resilience.controller.cooldowns.set_stamp(
             (resilience.SOLVER, "mesh_broken"), float(t))
-
-    @property
-    def _relax_broken(self) -> bool:
-        return resilience.controller.active(resilience.SOLVER,
-                                            "relax_broken")
-
-    @_relax_broken.setter
-    def _relax_broken(self, v: bool) -> None:
-        resilience.controller.report(
-            resilience.SOLVER, "relax_broken", bool(v),
-            cycle=self._drain_cycle,
-            reason=("relaxed arm demoted" if v
-                    else "relaxed arm re-probed; arm restored"))
-
-    @property
-    def _relax_broken_at(self) -> float:
-        return (resilience.controller.cooldowns.stamp(
-            (resilience.SOLVER, "relax_broken")) or 0.0)
-
-    @_relax_broken_at.setter
-    def _relax_broken_at(self, t: float) -> None:
-        resilience.controller.cooldowns.set_stamp(
-            (resilience.SOLVER, "relax_broken"), float(t))
 
     def _mesh(self):
         """The resolved solver mesh, or None (single device / off /
@@ -851,171 +797,7 @@ class SolverEngine:
 
     def _local_solve(self, problem: SolverProblem, frame, *, full: bool,
                      n_live: Optional[int] = None, **caps):
-        """In-process solve: relax -> mesh -> single-chip fallback chain.
-
-        The relaxed fast-path arm (solver/relax.py) is tried first for
-        lean drains the router picks it for; any relax fault or audit
-        divergence falls through to the exact chain below — the full
-        degradation ladder is relax -> mesh -> single-chip -> host,
-        every hop metered (solver_fallback_total{relax_*/mesh_error/
-        device_error}).
-        """
-        if n_live is None:
-            from kueue_oss_tpu.solver import meshutil
-
-            n_live = meshutil.live_rows(problem.wl_cqid, problem.n_cqs)
-        if not full and self._pick_relax_arm(n_live):
-            out = self._relax_solve(problem, frame, n_live)
-            if out is not None:
-                return out
-        return self._solve_exact(problem, frame, full=full,
-                                 n_live=n_live, **caps)
-
-    # -- relaxed fast-path arm (solver/relax.py) ---------------------------
-
-    def _relax_available(self) -> bool:
-        if not self.relax_enabled:
-            return False
-        if self._relax_broken:
-            # timed half-open via the degradation controller: one probe
-            # drain re-measures once the cooldown elapses; another
-            # fault or divergence re-demotes and restarts the clock
-            if not resilience.controller.begin_probe(
-                    resilience.SOLVER, "relax_broken",
-                    self.relax_retry_cooldown_s):
-                return False
-            self._relax_broken = False
-            self._arm_warm.discard(("lean", "relax"))
-            devtel.collector.forget("lean", "relax")
-        return True
-
-    def _pick_relax_arm(self, n_live: int) -> bool:
-        """Whether this lean drain should try the relaxed arm — the
-        cost-EMA router's fourth arm: probe once above the backlog
-        floor, then engage only while its measured per-workload wall
-        beats the best exact arm's (the loser decays so a regressing
-        winner gets re-measured, exactly like the mesh arm)."""
-        if not self._relax_available():
-            return False
-        if self.relax_force:
-            return True
-        if n_live < self.relax_min_workloads:
-            return False
-        e_relax = self._arm_ema.get(("lean", "relax"))
-        exact = [e for e in (self._arm_ema.get(("lean", "single")),
-                             self._arm_ema.get(("lean", "mesh")))
-                 if e is not None]
-        if e_relax is None:
-            # probe only once an exact baseline exists: the first
-            # drains of a flood must establish the reference cost the
-            # audit and the router compare against
-            return bool(exact)
-        if not exact:
-            return True
-        if e_relax <= min(exact):
-            return True
-        self._arm_ema[("lean", "relax")] = e_relax * 0.98
-        return False
-
-    def _note_relax_failure(self, e: Optional[BaseException],
-                            slug: str) -> None:
-        """Demote the relaxed arm (fault or audit divergence): counted,
-        journaled, cooled down — never silent, never wedged open."""
-        reason = ("relaxed-arm plan diverged from the exact kernel on "
-                  "an audited drain; arm demoted (exact plan emitted)"
-                  if slug == "relax_disagreement" else
-                  f"relaxed solver arm fault ({e!r}); falling back to "
-                  "the exact arms")
-        resilience.controller.report(
-            resilience.SOLVER, "relax_broken", True,
-            cycle=self._drain_cycle, reason=reason)
-        self._arm_ema.pop(("lean", "relax"), None)
-        self._arm_warm.discard(("lean", "relax"))
-        devtel.collector.forget("lean", "relax")
-        metrics.solver_fallback_total.inc(slug)
-        obs.recorder.record(
-            obs.SOLVER_FALLBACK, obs.CYCLE_SCOPE,
-            cycle=self._drain_cycle, path=obs.SOLVER,
-            reason=reason, reason_slug=slug)
-
-    def _relax_solve(self, problem: SolverProblem, frame, n_live: int):
-        """One relaxed-arm attempt. Returns the plan tuple, or None to
-        fall through to the exact chain (arm fault). Audited drains
-        ALSO run the exact chain and emit ITS plan — identical
-        decisions when the audit passes, and the authoritative plan
-        when it does not (plan fidelity never rides on the LP)."""
-        import time as _time
-
-        from kueue_oss_tpu.solver import relax
-
-        self._relax_drains += 1
-        audit = (self.relax_audit_every > 0
-                 and (self._relax_drains == 1
-                      or self._relax_drains % self.relax_audit_every
-                      == 0))
-        self.last_relax_audit = None
-        try:
-            if self.solve_fault_hook is not None:
-                self.solve_fault_hook("relax")
-            t0 = _time.monotonic()   # for _note_arm_wall
-            # solve_relaxed itself falls back to the single-chip LP
-            # when the padded axis does not shard evenly
-            mesh = self._mesh()
-            # the relaxed arm uploads, runs and fetches inside one call
-            with spans.span("dispatch"):
-                out, stats = relax.solve_relaxed(
-                    problem, iters=self.relax_iters,
-                    threshold=self.relax_support_threshold, mesh=mesh,
-                    pad_to=self._relax_pad_hwm)
-            wall = _time.monotonic() - t0
-        except Exception as e:
-            self._note_relax_failure(e, "relax_error")
-            metrics.solver_relax_drains_total.inc("error")
-            return None
-        self._relax_pad_hwm = max(self._relax_pad_hwm,
-                                  stats.support_padded)
-        self.last_relax_stats = stats
-        if stats.live:
-            metrics.solver_relax_support_fraction.observe(
-                value=stats.support / stats.live)
-        self._note_arm_wall("lean", "relax", wall, n_live)
-        if audit:
-            exact = self._solve_exact(problem, frame, full=False,
-                                      n_live=n_live)
-            agree = relax.plans_agree(out, exact, problem.n_workloads)
-            self.last_relax_audit = agree
-            if agree:
-                metrics.solver_relax_drains_total.inc("audit_match")
-            else:
-                metrics.solver_relax_drains_total.inc("audit_diverged")
-                self._note_relax_failure(None, "relax_disagreement")
-            return exact
-        # relax-SERVED drain (no audit ran the exact chain): keep any
-        # EXISTING exact-arm resident device states current by applying
-        # the frame's delta scatter now. Dropping it would leave them
-        # epoch-stuck, forcing the next exact/audit solve into a full
-        # padded re-upload charged to the exact arm's cost EMA (biasing
-        # the router toward relax) and defeating the delta-session
-        # residency while the relax arm serves. Audited drains skip
-        # this — their _solve_exact applies the frame itself.
-        if frame is not None:
-            for key in ("lean", "lean-mesh"):
-                dev = self._device_states.get(key)
-                if dev is None:
-                    continue
-                try:
-                    dev.update(problem, frame, False)
-                except Exception:
-                    # a failed scatter must not fault the drain; the
-                    # next exact solve re-seeds from the host problem
-                    self._device_states.pop(key, None)
-        metrics.solver_relax_drains_total.inc("served")
-        self.last_drain_arm = "relax"
-        return out
-
-    def _solve_exact(self, problem: SolverProblem, frame, *, full: bool,
-                     n_live: Optional[int] = None, **caps):
-        """In-process EXACT solve with the mesh -> single-chip fallback
+        """In-process solve with the mesh -> single-chip fallback
         chain.
 
         The mesh arm (when routed) drains the resident mesh-placed
@@ -2032,8 +1814,8 @@ class SolverEngine:
                 "waitSeconds": round(wait_s, 3),
                 "priority": wl.priority,
                 "priorityClass": pclass,
-                # which solver arm produced this plan (relax / mesh /
-                # single / remote) — joins the ledger row's solver_arm
+                # which solver arm produced this plan (mesh / single /
+                # remote) — joins the ledger row's solver_arm
                 "solver_arm": ("remote" if self.remote is not None
                                else (self.last_drain_arm or "single")),
             })

@@ -135,7 +135,7 @@ def accumulate_full_charge(parent: jnp.ndarray, depth: jnp.ndarray,
 
     The exact algebra absorbs each child's local quota on the way up
     (only the overflow bubbles); the convex relaxation
-    (solver/relax.py) instead prices the AGGREGATE load under each
+    (sim/relax.py) instead prices the AGGREGATE load under each
     node against that node's total headroom, which is exactly this
     full-charge accumulation. ``d_max`` is the static ancestor-path
     width (path.shape[1]).

@@ -1,4 +1,4 @@
-"""Mesh plumbing shared by the engine, the sidecar, and the bench.
+"""Mesh plumbing shared by the engine and the sidecar.
 
 The multi-chip kernels (solver/sharded.py, full_kernels mesh lanes)
 need two things every production call site repeats: mesh *detection*
@@ -275,22 +275,4 @@ def lean_mesh_solver(mesh, axis: str = MESH_AXIS):
 
         fn = jax.jit(make_sharded_drain(mesh, axis))
         _lean_cache[key] = fn
-    return fn
-
-
-#: jitted mesh-sharded relax-LP programs keyed by (mesh, iters, axis)
-_relax_cache: dict = {}
-
-
-def relax_mesh_lp(mesh, iters: int, axis: str = MESH_AXIS):
-    """Cached mesh-sharded projected-gradient LP for the relaxed
-    admission arm (solver/relax.py; body in
-    sharded.make_sharded_relax_lp)."""
-    key = (mesh, int(iters), axis)
-    fn = _relax_cache.get(key)
-    if fn is None:
-        from kueue_oss_tpu.solver.sharded import make_sharded_relax_lp
-
-        fn = make_sharded_relax_lp(mesh, int(iters), axis)
-        _relax_cache[key] = fn
     return fn

@@ -312,34 +312,6 @@ def make_sharded_drain(mesh: Mesh, axis: str = "wl"):
     return drain
 
 
-def make_sharded_relax_lp(mesh: Mesh, iters: int, axis: str = "wl"):
-    """Mesh-sharded projected-gradient iterations of the relaxed
-    admission LP (solver/relax.py).
-
-    The workload-axis inputs (requests, scores, liveness, CQ ids, and
-    the fractional iterate x) block-shard over ``axis``; the node/CQ
-    pricing state replicates. Each iteration's only collective is ONE
-    psum of the [C, F] per-CQ load matrix — per-iteration ICI volume is
-    independent of the backlog size, the same scaling shape as the
-    exact sharded drain above. Results are bit-identical to the
-    single-chip LP up to float summation order (the repair pass is
-    exact either way, so plan fidelity never rides on this).
-    """
-    from kueue_oss_tpu.solver.relax import RelaxLP, lp_loop
-
-    specs = RelaxLP(
-        r=P(axis), s=P(axis), live=P(axis), wl_cqid=P(axis),
-        cq_node=P(), path_cq=P(), parent=P(), depth=P(),
-        slack=P(), scale=P())
-
-    @partial(jax.shard_map, mesh=mesh, in_specs=(specs,),
-             out_specs=P(axis))
-    def run(lp):
-        return lp_loop(lp, iters, psum_axis=axis)
-
-    return jax.jit(run)
-
-
 def full_shardings(mesh: Mesh, axis: str = "wl") -> dict:
     """field -> NamedSharding for mesh-placing FULL problem tensors:
     the [W+1] workload-axis fields (full_kernels.FULL_WL_FIELDS)

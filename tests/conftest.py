@@ -56,11 +56,6 @@ def pytest_configure(config):
         "tests and the crash-point chaos suite (seeded subprocess "
         "kill -9 + recover); deterministic, runs in tier-1")
     config.addinivalue_line(
-        "markers", "relax: convex-relaxation fast-path solver arm tests "
-        "(solver/relax.py): LP/rounding/repair property tests, exact "
-        "plan-feasibility guarantees, 4-arm router audit/demotion "
-        "transitions; deterministic, CPU-backend, runs in tier-1")
-    config.addinivalue_line(
         "markers", "streaming: streaming control plane tests "
         "(scheduler/streaming.py + persist incremental checkpoints / "
         "log shipping): oracle-parity event-replay property tests, "

@@ -324,7 +324,7 @@ IDENTITY_FIELDS = ("wl_cqid", "wl_rank", "wl_prio", "wl_ts", "wl_uid",
 @pytest.mark.megascale
 def test_smoke_50k_1k_cached_reexport_beats_walk():
     # tier-1 smoke: loose 2x bar — the CI margin, not the headline
-    # (bench.py megascale measures the 20x acceptance at 1M x 10k)
+    # (the 20x acceptance at 1M x 10k is the slow test below)
     _scale_harness(50_000, 1_000, 2.0, IDENTITY_FIELDS)
 
 

@@ -172,7 +172,7 @@ def test_compile_detector_fresh_vs_warm_and_forget():
 def test_compile_detector_emits_tracer_span():
     tracer = Tracer(clock=lambda: 10.0)
     det = CompileDetector(tracer=tracer)
-    det.observe_solve("lean", "relax", 30, 0.25)
+    det.observe_solve("lean", "single", 30, 0.25)
     spans = tracer.spans()
     assert len(spans) == 1
     name, tid, ts_us, dur_us, args = spans[0]

@@ -3,7 +3,7 @@
 Covers the tentpole contract of docs/ROBUSTNESS.md "Degradation
 ladder": condition-severity level math, unified cooldown hysteresis
 with single-probe gating, every subsystem's fault handlers reporting
-through the process-wide controller (solver breaker, mesh/relax/device
+through the process-wide controller (solver breaker, mesh/device
 arms, WAL durability rungs, streaming fences, farm backpressure), the
 runtime farm re-weighting satellite, and the /api surfaces.
 """
@@ -38,7 +38,7 @@ class TestDegradationController:
         assert ctl.level(resilience.SOLVER) == 1
         assert ctl.rung(resilience.SOLVER) == "single"
         ctl.report(resilience.SOLVER, "breaker_open", True)
-        assert ctl.level(resilience.SOLVER) == 3
+        assert ctl.level(resilience.SOLVER) == 2
         assert ctl.rung(resilience.SOLVER) == "host"
         # healing the breaker drops to the mesh condition's level, not 0
         ctl.report(resilience.SOLVER, "breaker_open", False)
@@ -290,16 +290,6 @@ class TestEngineLadder:
             (resilience.SOLVER, "mesh_broken")) == eng._mesh_broken_at
         eng._mesh_broken = False
         assert not ctl.active(resilience.SOLVER, "mesh_broken")
-
-    def test_relax_demotion_reports_condition(self):
-        eng = self._engine()
-        eng._note_relax_failure(None, "relax_disagreement")
-        assert resilience.controller.active(resilience.SOLVER,
-                                            "relax_broken")
-        assert resilience.controller.level(resilience.SOLVER) == 2
-        assert eng._relax_broken
-        eng._relax_broken = False
-        assert resilience.controller.level(resilience.SOLVER) == 0
 
 
 # ---------------------------------------------------------------------------

@@ -238,122 +238,83 @@ def test_idle_preemption_cq_keeps_lean_fast_path():
 
 
 # ---------------------------------------------------------------------------
-# 4-arm cost-EMA routing: host / single-chip / mesh / relax
-# (docs/SOLVER_PROTOCOL.md "Relaxed fast-path arm")
+# cost-EMA routing between the exact arms: mesh / single-chip (the host
+# arm is the scheduler's gate above)
 # ---------------------------------------------------------------------------
 
 
 @pytest.fixture
-def relax_engine():
-    from kueue_oss_tpu.core.queue_manager import QueueManager as QM
+def mesh_engine(eight_devices):
     from kueue_oss_tpu.solver.engine import SolverEngine
 
     store = _store()
-    eng = SolverEngine(store, QM(store))
-    eng.relax_min_workloads = 32
+    eng = SolverEngine(store, QueueManager(store))
+    eng.mesh_min_workloads = 32
     return eng
 
 
-def test_four_arm_probe_order_and_floor(relax_engine):
-    """The relax arm probes only after an exact baseline exists, never
-    below its backlog floor, and never while disabled."""
-    eng = relax_engine
-    assert not eng._pick_relax_arm(100)      # exact arms unmeasured
-    eng._arm_ema[("lean", "single")] = 1e-4
-    assert not eng._pick_relax_arm(16)       # below relax_min_workloads
-    assert eng._pick_relax_arm(100)          # probe
-    eng.relax_enabled = False
-    assert not eng._pick_relax_arm(100)
-
-
-def test_four_arm_ema_comparison_and_decay(relax_engine):
-    """With all arms measured, the cheapest per-workload wall wins;
-    the skipped relax estimate decays so it eventually re-probes."""
-    eng = relax_engine
-    eng._arm_ema[("lean", "single")] = 2e-4
+def test_arm_probe_order_and_floor(mesh_engine):
+    """The mesh arm probes first, then the single-chip arm; never below
+    its backlog floor, and never without a mesh."""
+    eng = mesh_engine
+    assert eng._pick_mesh_arm("lean", 16) is None    # below the floor
+    assert eng._pick_mesh_arm("lean", 100) is not None    # probe mesh
     eng._arm_ema[("lean", "mesh")] = 1e-4
-    eng._arm_ema[("lean", "relax")] = 3e-4   # slowest: skipped + decays
-    assert not eng._pick_relax_arm(100)
-    assert eng._arm_ema[("lean", "relax")] == pytest.approx(3e-4 * 0.98)
-    # decay accumulates below the best exact arm => the arm re-engages
-    eng._arm_ema[("lean", "relax")] = 0.99e-4
-    assert eng._pick_relax_arm(100)
+    assert eng._pick_mesh_arm("lean", 100) is None   # then single-chip
+    eng.mesh_mode = "off"
+    eng.refresh_mesh()
+    assert eng._pick_mesh_arm("lean", 100) is None
 
 
-def test_relax_wall_feeds_ema_after_compile_tainted_probe(relax_engine):
-    """First relax sample is discarded (compile-tainted, mirroring the
-    mesh arm); the second lands in the EMA the router compares."""
-    eng = relax_engine
-    eng._note_arm_wall("lean", "relax", 10.0, 100)
-    assert ("lean", "relax") not in eng._arm_ema
-    eng._note_arm_wall("lean", "relax", 1.0, 100)
-    assert eng._arm_ema[("lean", "relax")] == pytest.approx(0.01)
-
-
-def test_relax_demotion_cooldown_and_reprobe(relax_engine):
-    """Breaker-style demotion: a demoted arm refuses to engage during
-    the cooldown, then half-opens for exactly one re-probe; a second
-    demotion restarts the clock."""
-    eng = relax_engine
+def test_arm_ema_comparison_and_decay(mesh_engine):
+    """With both arms measured, the cheaper per-workload wall wins;
+    the skipped arm's estimate decays so it eventually re-probes."""
+    eng = mesh_engine
     eng._arm_ema[("lean", "single")] = 1e-4
-    eng._arm_ema[("lean", "relax")] = 1e-5
-    assert eng._pick_relax_arm(100)
-    eng._note_relax_failure(RuntimeError("boom"), "relax_error")
-    assert eng._relax_broken
-    assert ("lean", "relax") not in eng._arm_ema  # estimate dropped
-    assert not eng._pick_relax_arm(100)           # cooling down
-    eng._relax_broken_at -= eng.relax_retry_cooldown_s + 1
-    assert eng._pick_relax_arm(100)               # half-open re-probe
-    assert not eng._relax_broken
-    eng._note_relax_failure(None, "relax_disagreement")
-    assert not eng._pick_relax_arm(100)           # re-demoted
+    eng._arm_ema[("lean", "mesh")] = 3e-4    # slower: skipped + decays
+    assert eng._pick_mesh_arm("lean", 100) is None
+    assert eng._arm_ema[("lean", "mesh")] == pytest.approx(3e-4 * 0.98)
+    # decay accumulates below the single-chip arm => the mesh re-engages
+    eng._arm_ema[("lean", "mesh")] = 0.99e-4
+    assert eng._pick_mesh_arm("lean", 100) is not None
+    assert eng._arm_ema[("lean", "single")] == pytest.approx(1e-4 * 0.98)
 
 
-def test_relax_disagreement_demotes_but_mesh_and_single_unaffected():
-    """A relax demotion must not disturb the exact arms' routing state
-    (their EMAs keep steering mesh vs single-chip)."""
-    from kueue_oss_tpu.core.queue_manager import QueueManager as QM
+def test_consecutive_lean_floods_stay_on_the_exact_single_arm():
+    """Lean drains of 4,096 live rows or more (no preemption policy on
+    any queue) are served by the exact single-chip arm every time, and
+    commit the plan solve_backlog gives, with no fallback counted and
+    the solver's ladder at level 0: no approximate arm stands in front
+    of the exact chain once the router has a baseline (the third
+    drain is the first that has one)."""
+    import numpy as np
+
+    from kueue_oss_tpu import metrics, resilience
     from kueue_oss_tpu.solver.engine import SolverEngine
+    from kueue_oss_tpu.solver.kernels import solve_backlog, to_device
 
-    store = _store()
-    eng = SolverEngine(store, QM(store))
-    eng._arm_ema[("lean", "single")] = 2e-4
-    eng._arm_ema[("lean", "mesh")] = 1e-4
-    eng._arm_ema[("lean", "relax")] = 1e-5
-    eng._note_relax_failure(None, "relax_disagreement")
-    assert eng._arm_ema[("lean", "single")] == 2e-4
-    assert eng._arm_ema[("lean", "mesh")] == 1e-4
-
-
-def test_audited_drain_refreshes_exact_arm_ema():
-    """Audited relax drains run the exact chain too, so BOTH the relax
-    and an exact arm EMA stay warm — the router never goes stale while
-    the relax arm serves."""
-    from kueue_oss_tpu.api.types import PodSet, Workload
-    from kueue_oss_tpu.core.queue_manager import QueueManager as QM
-    from kueue_oss_tpu.solver.engine import SolverEngine
-
-    store = _store()
-    for i in range(64):
-        store.add_workload(Workload(
-            name=f"w{i}", queue_name=f"lq{i % 4}", uid=i + 1,
-            creation_time=float(i),
-            podsets=[PodSet(name="main", count=1,
-                            requests={"cpu": 1})]))
-    eng = SolverEngine(store, QM(store))
-    eng.relax_force = True
-    eng.relax_audit_every = 1
-    # warm both arms once (first samples are compile-tainted/discarded)
-    eng.drain(now=0.0)
-    assert eng.last_relax_audit is True
-    sched = __import__("kueue_oss_tpu.scheduler.scheduler",
-                       fromlist=["Scheduler"]).Scheduler(store,
-                                                         eng.queues)
-    eng.scheduler = sched
-    for k in [k for k, w in store.workloads.items()
-              if w.is_quota_reserved][:6]:
-        sched.finish_workload(k, now=1.0)
-    eng.drain(now=1.0)
-    assert ("lean", "relax") in eng._arm_ema
-    assert (("lean", "single") in eng._arm_ema
-            or ("lean", "mesh") in eng._arm_ema)
+    store = _store(quota=64)
+    _flood(store, 4400)
+    # one chip: the 8 virtual test devices would offer a mesh
+    eng = SolverEngine(store, QueueManager(store), mesh_mode="off")
+    eng.scheduler = Scheduler(store, eng.queues)
+    fallbacks0 = metrics.solver_fallback_total.total()
+    for drain in range(3):
+        pending = eng.pending_backlog()
+        assert sum(len(v) for v in pending.values()) >= 4096
+        assert not eng.needs_full_kernel(pending)
+        problem, _ = eng.export()
+        want = np.asarray(solve_backlog(to_device(problem))[0])
+        want_keys = {problem.wl_keys[w] for w in
+                     np.nonzero(want[:problem.n_workloads])[0]}
+        result = eng.drain(now=float(drain))
+        assert eng.last_drain_arm == "single"
+        assert set(result.admitted_keys) == want_keys
+        assert len(want_keys) == (256 if drain == 0 else 40)
+        assert metrics.solver_fallback_total.total() == fallbacks0
+        assert resilience.controller.level(resilience.SOLVER) == 0
+        # free 40 seats and refill the backlog for the next drain
+        for k in [k for k, w in store.workloads.items()
+                  if w.is_quota_reserved and not w.is_finished][:40]:
+            eng.scheduler.finish_workload(k, now=float(drain) + 0.5)
+        _flood(store, 300, start=10_000 + 300 * drain)

@@ -598,190 +598,3 @@ def test_viz_round5_resource_views():
         assert "AdmissionChecks" in html and "Topologies" in html
     finally:
         srv.stop()
-
-
-# -- bench JSON-tail schema guard (tools/benchcheck.py) ----------------------
-
-
-def _mega_tail(**over):
-    tail = {
-        "scenario": "megascale", "workloads": 50000, "cqs": 1000,
-        "pending": 50000, "export_ms": 800.0,
-        "export_walk_warm_ms": 200.0,
-        "export_columnar_build_ms": 190.0, "export_ms_unchanged": 0.5,
-        "export_speedup": 1600.0, "export_speedup_warm": 400.0,
-        "export_mode_unchanged": "cached", "columnar_identical": True,
-        "churn_rows": 4096, "export_churn_ms": 120.0,
-        "export_churn_mode": "scatter", "export_churn_dirty_rows": 4096,
-        "delta_encode_ms": 8.0, "delta_frame": "delta", "burst": 8192,
-        "burst_cqs": 256, "micro_solve_ms": 40.0,
-        "micro_export_ms": 180.0, "stream_commit_ms_host": 800.0,
-        "stream_commit_ms_micro": 900.0, "stream_e2e_ms_host": 1600.0,
-        "stream_e2e_ms_micro": 1300.0, "arrivals_per_sec": 200000.0,
-        "arrivals_per_sec_host": 11000.0, "arrivals_speedup": 18.0,
-    }
-    tail.update(over)
-    return tail
-
-
-def test_benchcheck_valid_megascale_tail():
-    from tools.benchcheck import check
-
-    assert check(_mega_tail(), "megascale") == []
-    assert check(_mega_tail(), "megascale", strict=True) == []
-
-
-def test_benchcheck_flags_missing_and_mistyped_keys():
-    from tools.benchcheck import check
-
-    tail = _mega_tail()
-    del tail["arrivals_speedup"]
-    tail["export_ms"] = "fast"          # wrong type
-    tail["columnar_identical"] = 1      # int is not bool
-    tail["workloads"] = True            # bool is not int
-    errs = "\n".join(check(tail, "megascale"))
-    assert "missing key: arrivals_speedup" in errs
-    assert "export_ms: expected number, got str" in errs
-    assert "columnar_identical: expected bool" in errs
-    assert "workloads: expected int, got bool" in errs
-
-
-def test_benchcheck_strict_enforces_floors_and_modes():
-    from tools.benchcheck import check
-
-    bad = _mega_tail(arrivals_speedup=3.0, export_speedup=5.0,
-                     export_mode_unchanged="assemble",
-                     columnar_identical=False)
-    # shape-only validation still passes; --strict flags every floor
-    assert check(bad, "megascale") == []
-    errs = "\n".join(check(bad, "megascale", strict=True))
-    assert "arrivals_speedup" in errs and "export_speedup" in errs
-    assert "export_mode_unchanged" in errs
-    assert "columnar_identical" in errs
-
-
-def test_benchcheck_unknown_scenario_and_cli(tmp_path):
-    import io
-
-    from tools.benchcheck import check, main as bc_main
-
-    assert check({}, "nope") == ["unknown scenario 'nope' (known: "
-                                 "chaoscampaign, federation, fullsweep, "
-                                 "main, megascale, telemetry)"]
-    path = tmp_path / "tail.json"
-    path.write_text("garbage first line\n"
-                    + json.dumps(_mega_tail()) + "\n")
-    buf = io.StringIO()
-    assert bc_main(["--json", str(path), "--strict"], out=buf) == 0
-    assert "tail valid" in buf.getvalue()
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"scenario": "megascale"}))
-    buf = io.StringIO()
-    assert bc_main(["--json", str(bad)], out=buf) == 1
-    assert "missing key" in buf.getvalue()
-
-
-# ---------------------------------------------------------------------------
-# benchcheck: fullsweep tail (docs/SIMULATOR.md "FULL-kernel sweeps")
-# ---------------------------------------------------------------------------
-
-
-def _fullsweep_tail(**over):
-    tail = {
-        "scenario": "fullsweep", "scenarios": 64, "workloads": 12,
-        "padded_workloads": 16, "chunk_width": 64, "chunks": 1,
-        "chunked_wall_s": 0.05, "sequential_wall_s": 0.2,
-        "full_speedup": 4.0, "plans_identical": True,
-        "preemptions_total": 120, "resident_sweep_s": 0.05,
-        "reupload_sweep_s": 0.06, "resident_win": 1.2,
-        "resident_reuses": 3, "resident_full_uploads": 1,
-        "relax_scenarios": 256, "relax_scenarios_per_sec": 300.0,
-    }
-    tail.update(over)
-    return tail
-
-
-def test_benchcheck_valid_fullsweep_tail():
-    from tools.benchcheck import check
-
-    assert check(_fullsweep_tail(), "fullsweep") == []
-    assert check(_fullsweep_tail(), "fullsweep", strict=True) == []
-
-
-def test_benchcheck_fullsweep_strict_bounds():
-    from tools.benchcheck import check
-
-    # the speedup/resident floors, the preemption-evidence floor, and
-    # the exact-true parity bit each fail strict independently
-    bad = _fullsweep_tail(full_speedup=2.0, resident_win=0.8,
-                          preemptions_total=0, plans_identical=False)
-    assert check(bad, "fullsweep") == []  # shape still valid
-    errs = "\n".join(check(bad, "fullsweep", strict=True))
-    assert "full_speedup" in errs and "floor 3.0" in errs
-    assert "resident_win" in errs
-    assert "preemptions_total" in errs
-    assert "plans_identical" in errs
-
-
-def test_benchcheck_fullsweep_types():
-    from tools.benchcheck import check
-
-    tail = _fullsweep_tail(plans_identical=1, chunks=2.5)
-    del tail["full_speedup"]
-    errs = "\n".join(check(tail, "fullsweep"))
-    assert "plans_identical: expected bool" in errs
-    assert "chunks: expected int" in errs
-    assert "missing key: full_speedup" in errs
-
-
-# ---------------------------------------------------------------------------
-# benchcheck: chaoscampaign tail (docs/ROBUSTNESS.md "Chaos campaigns")
-# ---------------------------------------------------------------------------
-
-
-def _campaign_tail(**over):
-    tail = {
-        "scenario": "chaoscampaign", "seed": 42, "seconds": 4.0,
-        "profiles": {"solver-storm": {"converged": True}},
-        "converged_all": True, "recovered_identical": True,
-        "convergence_cycles": 12, "max_degradation_level": 3,
-        "availability": 0.7, "unavailable_wall_ms": 0.4,
-        "invariant_violations": 0, "faults_injected": 36,
-    }
-    tail.update(over)
-    return tail
-
-
-def test_benchcheck_valid_chaoscampaign_tail():
-    from tools.benchcheck import check
-
-    assert check(_campaign_tail(), "chaoscampaign") == []
-    assert check(_campaign_tail(), "chaoscampaign", strict=True) == []
-
-
-def test_benchcheck_chaoscampaign_strict_bounds():
-    from tools.benchcheck import check
-
-    # the convergence ceiling, the availability floor, and the two
-    # exact-true oracle verdicts each fail strict independently
-    bad = _campaign_tail(convergence_cycles=17, availability=0.5,
-                         recovered_identical=False, converged_all=False,
-                         invariant_violations=2)
-    assert check(bad, "chaoscampaign") == []  # shape still valid
-    errs = "\n".join(check(bad, "chaoscampaign", strict=True))
-    assert "convergence_cycles" in errs and "ceiling 16" in errs
-    assert "availability" in errs and "floor 0.6" in errs
-    assert "recovered_identical" in errs
-    assert "converged_all" in errs
-    assert "invariant_violations" in errs
-
-
-def test_benchcheck_chaoscampaign_types():
-    from tools.benchcheck import check
-
-    tail = _campaign_tail(convergence_cycles=True, profiles=[])
-    del tail["availability"]
-    errs = "\n".join(check(tail, "chaoscampaign"))
-    assert "convergence_cycles: expected int, got bool" in errs
-    assert "profiles: expected dict, got list" in errs
-    assert "missing key: availability" in errs
