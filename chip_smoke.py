@@ -483,7 +483,12 @@ def solver_rows() -> list:
 
 def row_summary(row) -> dict:
     dev = dict(row.device or {})
+    # a preemption drain's row says which program it ran (its caps)
+    # and whether it had to build it; a lean drain's has no such detail
+    detail = dict(row.detail or {})
     return {"cycle": row.cycle, "arm": row.solver_arm,
+            "h_max": detail.get("hMax"), "p_max": detail.get("pMax"),
+            "program_builds": detail.get("programBuilds"),
             "frame": row.frame_kind, "frame_bytes": row.frame_bytes,
             "frame_reason": row.frame_reason, "rounds": row.rounds,
             "admitted": row.admitted, "evicted": row.evicted,
@@ -683,25 +688,15 @@ def drive_served(a, w: World, xla: XlaCounters | None = None) -> dict:
     counts each round's compiles where this process does the compiling
     (the manager in front of a sidecar has nothing to compile)."""
     per_round = []
-    # record, without changing, the caps each preemption drain is
-    # compiled for: a drain that compiles again names both sets
     engine = w.sched._solver_engine()
-    caps = []
-    size_caps = engine._size_caps
-
-    def recording_size_caps(problem):
-        caps.append(size_caps(problem))
-        return caps[-1]
-
-    engine._size_caps = recording_size_caps
 
     def settle(name: str, drain_first=None) -> None:
-        n_rows, n_caps = len(solver_rows()), len(caps)
+        n_rows = len(solver_rows())
         before = xla.snapshot() if xla else None
         rec = w.settle(name, drain_first)
+        # each row names the caps its drain was compiled for: a drain
+        # that compiles again shows both sets
         drains = [row_summary(r) for r in solver_rows()[n_rows:]]
-        for d, (h_max, p_max) in zip(drains, caps[n_caps:]):
-            d["h_max"], d["p_max"] = int(h_max), int(p_max)
         per_round.append({
             "round": name, "seconds": rec["seconds"],
             "driven_by": ("engine.drain" if drain_first is not None

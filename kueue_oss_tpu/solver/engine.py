@@ -60,6 +60,14 @@ class DrainResult:
     #: wire carries the plan alone
     search_lanes: int = 0
     search_live_lanes: int = 0
+    #: the static caps of the program a full drain ran (_size_caps; a
+    #: local drain may run one candidate width up, _full_program) and
+    #: the preemption programs it had to build (full_kernels.full_solver
+    #: on a miss of its cache): 0 in a drain that reused one, and in a
+    #: sidecar's, which builds in its own process; 0 caps in a lean drain
+    h_max: int = 0
+    p_max: int = 0
+    program_builds: int = 0
 
 
 class SolverEngine:
@@ -211,6 +219,9 @@ class SolverEngine:
         self.solve_fault_hook = None
         #: arm that served the most recent local solve (diagnostics)
         self.last_drain_arm: Optional[str] = None
+        #: candidate width of the preemption program the last local
+        #: full drain ran (_full_program)
+        self.last_p_max = 0
         #: streaming micro-batch admitter (scheduler/streaming.py);
         #: every completed full drain re-arms its fences — a full
         #: solve is the oracle-parity baseline boundary
@@ -636,8 +647,10 @@ class SolverEngine:
             frame_reason=frame_reason, session=session,
             grant_wait_ms=grant_wait_ms, device=device,
             detail=({"searchLanes": result.search_lanes,
-                     "searchLiveLanes": result.search_live_lanes}
-                    if result.search_lanes else None))
+                     "searchLiveLanes": result.search_live_lanes,
+                     "programBuilds": result.program_builds,
+                     "hMax": result.h_max, "pMax": result.p_max}
+                    if result.p_max else None))
 
     # -- mesh routing (solver/meshutil.py, solver/sharded.py) --------------
 
@@ -795,6 +808,18 @@ class SolverEngine:
                    "single-chip solver arm",
             reason_slug="mesh_error")
 
+    def _full_program(self, mesh, caps: dict):
+        """The preemption program for one arm: at the caps asked, or one
+        candidate width up where the process has that program and not
+        this one (full_kernels.built_p_max). ``last_p_max`` says which."""
+        from kueue_oss_tpu.solver.full_kernels import (
+            built_p_max,
+            full_solver,
+        )
+
+        self.last_p_max = built_p_max(mesh=mesh, **caps)
+        return full_solver(mesh=mesh, **{**caps, "p_max": self.last_p_max})
+
     def _local_solve(self, problem: SolverProblem, frame, *, full: bool,
                      n_live: Optional[int] = None, **caps):
         """In-process solve with the mesh -> single-chip fallback
@@ -835,11 +860,7 @@ class SolverEngine:
                                               mesh=mesh)
                 with spans.span("dispatch"):
                     if full:
-                        from kueue_oss_tpu.solver.full_kernels import (
-                            full_solver,
-                        )
-
-                        out = full_solver(mesh=mesh, **caps)(tensors)
+                        out = self._full_program(mesh, caps)(tensors)
                     else:
                         out = meshutil.lean_mesh_solver(mesh)(tensors)
                 out = self._fetch(out)
@@ -866,11 +887,7 @@ class SolverEngine:
             tensors = self._local_tensors(problem, frame, full=full)
             with spans.span("dispatch"):
                 if full:
-                    from kueue_oss_tpu.solver.full_kernels import (
-                        full_solver,
-                    )
-
-                    out = full_solver(**caps)(tensors)
+                    out = self._full_program(None, caps)(tensors)
                 else:
                     out = solve_backlog(tensors)
             out = self._fetch(out)
@@ -1437,8 +1454,33 @@ class SolverEngine:
         quotas and never surface at the root. Inductively
         sum(cq usage) <= sum(local quotas in the tree) + usage[root]
         and usage[root] <= subtree[root], and every admitted candidate
-        uses >= the smallest positive request on some FR. Rounded up to
-        powers of two to reuse compiled kernels.
+        uses >= the smallest positive request on some FR (partial
+        admission can sit below every full-count option, so admitted
+        usage joins that minimum).
+
+        Workloads admitted BEFORE the drain hold the same quota, so they
+        are not counted on top of it. What they can raise is the ceiling,
+        where usage predates a quota reduction (or a removed flavor). At
+        every moment of a drain, per FR,
+            sum(cq usage) <= max(tree quota, usage held at its start)
+        because an eviction lowers the sum, and an admission of v into a
+        CQ needs v <= available(cq), which is at most tree quota -
+        sum(cq usage): available() is the unused local quota along the
+        CQ's path plus subtree[root] - usage[root], NOT clamped at zero
+        (quota.py, kernels.available_all), the tree's unused quota is
+        the same sum over every node, and what a node uses within its
+        local quota never reaches its parent. So an admission lands the
+        sum at or under the tree's quota, and
+            candidates <= sum over FRs of max(quota, held) // min_req
+        which in a tree whose usage is within its quota is its capacity
+        whatever it holds when the drain starts: held quota does not
+        move the cap. What the drain can newly seat plus the COUNT of
+        the holders bounds the same set from the other side (few large
+        holders above a reduced quota); the smaller of the two is
+        taken. Rounded up to powers of two to reuse compiled kernels.
+        The population term still moves the cap, down as a backlog
+        drains: _full_program runs a built program one width up rather
+        than stall the drain on tracing a narrower one.
         """
         from kueue_oss_tpu.solver.full_kernels import (
             budgeted_lanes,
@@ -1479,22 +1521,20 @@ class SolverEngine:
             tree_quota = np.zeros_like(problem.local_quota)
             np.add.at(tree_quota, root_of_node[:-1],
                       problem.local_quota[:-1])
-            # workloads admitted BEFORE this drain may predate a quota
-            # reduction (usage above today's tree quota is kept), so
-            # they are counted directly; the quota bound covers only
-            # what the drain itself can newly admit
+            held = np.zeros(tree_quota.shape, dtype=np.int64)
+            adm_counts = np.zeros(problem.n_nodes + 1, dtype=np.int64)
             if problem.ad_usage is not None:
                 adm0 = problem.ad_usage[:-1].any(axis=1)
+                np.add.at(held, wl_root[adm0], problem.ad_usage[:-1][adm0])
                 adm_counts = np.bincount(
                     wl_root[adm0], minlength=problem.n_nodes + 1)
-            else:
-                adm_counts = np.zeros(problem.n_nodes + 1, dtype=np.int64)
+            div, asked = np.maximum(min_req, 1), min_req > 0
             cap = 0
             for rn in np.unique(root_of_cq):
                 quota = tree_quota[rn] + problem.subtree[rn]
-                per_fr = quota // np.maximum(min_req, 1)
-                cap = max(cap, int(per_fr[min_req > 0].sum())
-                          + int(adm_counts[rn]))
+                cap = max(cap, min(
+                    int((quota // div)[asked].sum()) + int(adm_counts[rn]),
+                    int((np.maximum(quota, held[rn]) // div)[asked].sum())))
             p_max = min(pop, max(8, cap))
         else:
             p_max = pop
@@ -1529,6 +1569,7 @@ class SolverEngine:
         hint = getattr(problem, "_columnar_hint", None)
         g_max = int(problem.cq_ngroups.max())
         h_max, p_max = self._size_caps(problem)
+        result.h_max, result.p_max = h_max, p_max
         n_live = problem.n_workloads
         self._pad_hwm = max(self._pad_hwm,
                             pow2(max(problem.n_workloads, self.pad_to)))
@@ -1545,6 +1586,9 @@ class SolverEngine:
                     g_max=g_max, h_max=h_max, p_max=p_max,
                     fs_enabled=self.enable_fair_sharing)
             else:
+                from kueue_oss_tpu.solver.full_kernels import solver_builds
+
+                builds0 = solver_builds()
                 # the program's own return (full_kernels.full_solver):
                 # the plan, then the liveness gate's two counts
                 (admitted, opt, admit_round, parked, rounds, _usage,
@@ -1555,6 +1599,8 @@ class SolverEngine:
                     fs_enabled=self.enable_fair_sharing)
                 result.search_lanes = int(search_lanes)
                 result.search_live_lanes = int(search_live_lanes)
+                result.program_builds = solver_builds() - builds0
+                result.p_max = self.last_p_max
             admitted = np.asarray(admitted)
             opt = np.asarray(opt)
             admit_round = np.asarray(admit_round)
@@ -1576,6 +1622,7 @@ class SolverEngine:
         spans.count("drain_admitted", result.admitted)
         spans.count("search_lanes", result.search_lanes)
         spans.count("search_live_lanes", result.search_live_lanes)
+        spans.count("solver_program_builds", result.program_builds)
         W = problem.n_workloads
         with spans.span("record"):
             self._ledger_record(

@@ -1717,6 +1717,30 @@ def debug_drain(problem: SolverProblem, g_max: int, h_max: int = 8,
 
 
 _solver_cache: dict = {}
+#: programs :func:`full_solver` has built since the process started: a
+#: drain that adds to it met static caps this process had not traced
+#: (seconds of Python tracing, and a compile where the persistent cache
+#: has none). SolverEngine._drain_full reports each drain's share.
+_solver_builds = 0
+
+
+def solver_builds() -> int:
+    return _solver_builds
+
+
+def _solver_key(g_max: int, h_max: int, p_max: int, fs_enabled: bool,
+                mesh, axis: str) -> tuple:
+    """The fair-sharing gates are baked in at trace time, so they join
+    the cache key — a gate flip must not serve a stale compilation. The
+    mesh joins it so single-chip and mesh programs coexist."""
+    from kueue_oss_tpu import features
+
+    gates = ()
+    if fs_enabled:
+        gates = (features.enabled("FairSharingPreemptWithinNominal"),
+                 features.enabled("FairSharingPrioritizeNonBorrowing"),
+                 features.enabled("PrioritySortingWithinCohort"))
+    return (g_max, h_max, p_max, fs_enabled, gates, mesh, axis)
 
 
 def full_solver(g_max: int, h_max: int = 32, p_max: int = 128,
@@ -1728,25 +1752,40 @@ def full_solver(g_max: int, h_max: int = 32, p_max: int = 128,
     rounds: lanes offered, and lanes that ran the heavy search
     (:func:`_run_searches`).
 
-    The fair-sharing gates are baked in at trace time, so they join the
-    cache key — a gate flip must not serve a stale compilation. With a
-    ``mesh``, the victim-search lanes shard across its devices
-    (_run_searches); the mesh joins the key so single-chip and mesh
-    programs coexist."""
-    from kueue_oss_tpu import features
-
-    gates = ()
-    if fs_enabled:
-        gates = (features.enabled("FairSharingPreemptWithinNominal"),
-                 features.enabled("FairSharingPrioritizeNonBorrowing"),
-                 features.enabled("PrioritySortingWithinCohort"))
-    key = (g_max, h_max, p_max, fs_enabled, gates, mesh, axis)
+    With a ``mesh``, the victim-search lanes shard across its devices
+    (_run_searches)."""
+    key = _solver_key(g_max, h_max, p_max, fs_enabled, mesh, axis)
     fn = _solver_cache.get(key)
     if fn is None:
+        global _solver_builds
         fn = make_full_solver(g_max, h_max, p_max, fs_enabled,
                               mesh=mesh, axis=axis)
         _solver_cache[key] = fn
+        _solver_builds += 1
     return fn
+
+
+def built_p_max(g_max: int, h_max: int, p_max: int,
+                fs_enabled: bool = False, mesh=None,
+                axis: str = "wl") -> int:
+    """The candidate width a drain that NEEDS ``p_max`` should run at:
+    twice that where :func:`full_solver` has the next width up and not
+    this one, else ``p_max`` itself.
+
+    ``p_max`` only pads the candidate axis, so a wider program gives the
+    same plan bit for bit; what differs is the cost. Building the exact
+    program stalls the drain for seconds of Python tracing and a compile
+    or a cache load (8-11 s and ~50 s at 1,024 lanes on a v5e), and a
+    cap moves down as easily as up: a cohort whose population falls
+    under its capacity is sized by its population (_size_caps). One
+    width up costs at most what the power-of-two rounding already
+    accepts; further up a drain would pay for a burst long after it, so
+    the exact program is built."""
+    def have(p):
+        return _solver_key(g_max, h_max, p, fs_enabled, mesh,
+                           axis) in _solver_cache
+
+    return 2 * p_max if have(2 * p_max) and not have(p_max) else p_max
 
 
 def solve_backlog_full(t: FullTensors, g_max: int, h_max: int = 32,

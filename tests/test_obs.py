@@ -247,9 +247,9 @@ def test_chrome_trace_merges_host_and_sidecar_spans():
     path = os.path.join(tempfile.mkdtemp(), "solver.sock")
     srv = SolverServer(path)
     srv.serve_in_background()
+    tracer = Tracer()
     try:
         sched = Scheduler(store, queues, solver_min_backlog=8)
-        tracer = Tracer()
         attach_to_scheduler(sched, tracer)
         engine = SolverEngine(store, queues, scheduler=sched,
                               remote=SolverClient(path, timeout_s=60.0))
@@ -258,6 +258,10 @@ def test_chrome_trace_merges_host_and_sidecar_spans():
     finally:
         srv.shutdown()
         srv.server_close()
+        # a live tracer holds the span switch on for whatever test this
+        # worker runs next (tests/test_spans.py starts from "off")
+        from kueue_oss_tpu.obs import spans
+        spans.remove_sink(tracer)
     assert sum(1 for w in store.workloads.values()
                if w.is_quota_reserved) == 24  # capacity 32 >= all 24
 

@@ -315,7 +315,9 @@ def test_engine_counts_the_drains_lanes(monkeypatch):
         assert after[name] - before.get(name, 0) == getattr(result, name)
     row = obs.cycle_ledger.last_row(obs.SOLVER_DRAIN)
     assert row.detail == {"searchLanes": result.search_lanes,
-                          "searchLiveLanes": result.search_live_lanes}
+                          "searchLiveLanes": result.search_live_lanes,
+                          "programBuilds": result.program_builds,
+                          "hMax": result.h_max, "pMax": result.p_max}
 
 
 # -- (e) two resource groups: the second search ----------------------------------
