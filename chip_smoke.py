@@ -973,14 +973,14 @@ def phase_tas(a) -> dict:
     calls = []
     placer_for = tas_engine.DeviceTASPlacer._placer_for
 
-    def recording_placer_for(self, levels):
-        placer = placer_for(self, levels)
+    def recording_placer_for(self, levels, bal_cap):
+        placer, key = placer_for(self, levels, bal_cap)
 
         def place(*args):
             calls.append((placer, args))
             return placer(*args)
 
-        return place
+        return place, key
 
     tas_engine.DeviceTASPlacer._placer_for = recording_placer_for
 

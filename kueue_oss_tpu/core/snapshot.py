@@ -27,6 +27,7 @@ from kueue_oss_tpu.core.workload_info import (
     WorkloadInfo,
     effective_per_pod_requests,
 )
+from kueue_oss_tpu.obs import spans
 from kueue_oss_tpu.tas.snapshot import (
     TASAssignmentResult,
     TASFlavorSnapshot,
@@ -345,10 +346,13 @@ def build_snapshot(store: Store, profile_mixed: bool = False) -> Snapshot:
         topology = store.topologies.get(rf.topology_name)
         if topology is None:
             continue
-        tas_flavors[rf.name] = build_tas_flavor_snapshot(
-            topology.name, topology.levels, store.nodes.values(),
-            flavor_node_labels=rf.node_labels, tolerations=rf.tolerations,
-            profile_mixed=profile_mixed)
+        # the topology part of a snapshot: the flavor's tree from the
+        # nodes here, the admitted workloads' usage on it below
+        with spans.span("snapshot.tas"):
+            tas_flavors[rf.name] = build_tas_flavor_snapshot(
+                topology.name, topology.levels, store.nodes.values(),
+                flavor_node_labels=rf.node_labels,
+                tolerations=rf.tolerations, profile_mixed=profile_mixed)
 
     cqs: dict[str, ClusterQueueSnapshot] = {}
     snapshot = Snapshot(
@@ -368,10 +372,17 @@ def build_snapshot(store: Store, profile_mixed: bool = False) -> Snapshot:
         )
     snapshot._node_to_cq = {id(cq.node): cq for cq in cqs.values()}
 
-    for info in store.admitted_infos():
-        # CQ targeting + WorkloadInfo construction live in the store's
-        # admitted index (cached across cycles); skip CQs deleted since.
-        if info.cluster_queue not in cqs:
-            continue
-        snapshot.add_workload(info)
+    admitted = [info for info in store.admitted_infos()
+                # CQ targeting + WorkloadInfo construction live in the
+                # store's admitted index (cached across cycles); skip
+                # CQs deleted since.
+                if info.cluster_queue in cqs]
+    for info in admitted:
+        cq = cqs[info.cluster_queue]
+        cq.workloads[info.key] = info
+        cq.add_usage(info.usage())
+    if tas_flavors:
+        with spans.span("snapshot.tas"):
+            for info in admitted:
+                snapshot._apply_tas_usage(info, +1)
     return snapshot

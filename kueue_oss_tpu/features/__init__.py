@@ -160,6 +160,12 @@ def reset() -> None:
         _overrides.clear()
 
 
+def overrides_key() -> tuple:
+    """The gates set away from their defaults, as a hashable key (what a
+    memo of gate-dependent work is keyed by)."""
+    return tuple(sorted(_overrides.items()))
+
+
 def all_gates() -> dict[str, bool]:
     with _lock:
         return {n: _overrides.get(n, d) for n, d in _DEFAULTS.items()}

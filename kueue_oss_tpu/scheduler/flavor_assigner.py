@@ -24,6 +24,7 @@ from kueue_oss_tpu.api.types import (
     TopologyAssignment,
 )
 from kueue_oss_tpu.core.snapshot import ClusterQueueSnapshot
+from kueue_oss_tpu.obs import spans
 from kueue_oss_tpu.core.workload_info import (
     AssignmentClusterQueueState,
     WorkloadInfo,
@@ -145,6 +146,14 @@ class Assignment:
     podsets: list[PodSetAssignmentResult] = field(default_factory=list)
     usage_quota: dict[FlavorResource, int] = field(default_factory=dict)
     last_state: Optional[AssignmentClusterQueueState] = None
+    #: the topology requests of a Fit whose placement on the tree was
+    #: left to the cycle's entry pass (FlavorAssigner.assign
+    #: ``defer_tas``), until it is made there
+    deferred_tas: Optional[dict] = None
+    #: a Preempt with targets whose placement with the targets gone
+    #: (Scheduler._update_assignment_for_tas) waits for the entry pass
+    #: in the same way
+    tas_after_targets: bool = False
 
     def representative_mode(self) -> int:
         if not self.podsets:
@@ -363,8 +372,16 @@ class FlavorAssigner:
                 and wl.last_assignment.cluster_queue_generation != cq.generation):
             wl.last_assignment = None  # cursor outdated (flavorassigner.go:571)
 
-    def assign(self, counts: Optional[list[int]] = None) -> Assignment:
-        """Compute flavor assignment for all podsets (optionally scaled)."""
+    def assign(self, counts: Optional[list[int]] = None,
+               defer_tas: bool = False) -> Assignment:
+        """Compute flavor assignment for all podsets (optionally scaled).
+
+        ``defer_tas``: a Fit by quota is returned without its placement
+        on the topology tree (``Assignment.deferred_tas``): the caller
+        places it when the cycle is about to seat it
+        (Scheduler._place_deferred), so that a head which then loses its
+        quota to an earlier entry costs no walk of the tree.
+        """
         requests = [
             psr if counts is None else psr.scaled_to(counts[i])
             for i, psr in enumerate(self.wl.total_requests)
@@ -434,10 +451,11 @@ class FlavorAssigner:
                 self._append(assignment, psa, i)
             if failed:
                 return assignment
-        self._update_for_tas(assignment)
+        self._update_for_tas(assignment, defer_tas)
         return assignment
 
-    def _update_for_tas(self, assignment: Assignment) -> None:
+    def _update_for_tas(self, assignment: Assignment,
+                        defer: bool = False) -> None:
         """Topology placement after quota assignment (flavorassigner.go
         assignFlavors TAS tail, :733-765).
 
@@ -452,6 +470,19 @@ class FlavorAssigner:
         tas_requests = workload_topology_requests(self.wl, self.cq, assignment)
         if not tas_requests:
             return
+        if (defer and assignment.representative_mode() == FIT
+                and not self.wl.obj.status.unhealthy_nodes):
+            assignment.deferred_tas = tas_requests
+            return
+        # the host tree's placement of one head: totals only (per-event
+        # work), read as the share of a window the host spends placing
+        t0 = spans.start()
+        try:
+            self._place_on_tree(assignment, tas_requests)
+        finally:
+            spans.add_since("nominate.tas", t0)
+
+    def _place_on_tree(self, assignment: Assignment, tas_requests) -> None:
         if assignment.representative_mode() == FIT:
             result = self.cq.find_topology_assignments_for_workload(
                 tas_requests, workload=self.wl.obj)

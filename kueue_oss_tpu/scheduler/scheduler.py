@@ -193,6 +193,10 @@ class Scheduler:
         from kueue_oss_tpu.util.expectations import ExpectationsStore
 
         self.preemption_expectations = ExpectationsStore()
+        #: preemptor key -> (ClusterQueue, the usage it reserved when it
+        #: issued its preemptions) since the current run_until_quiet
+        #: began (_charge_quiet_reservations); None outside one
+        self._quiet_reserved: Optional[dict] = None
 
     # ------------------------------------------------------------------
     # Cycle
@@ -238,6 +242,8 @@ class Scheduler:
 
         with spans.span("snapshot"):
             snapshot = build_snapshot(self.store)
+            if self._quiet_reserved:
+                self._charge_quiet_reservations(heads, snapshot)
 
         with spans.span("nominate", heads=len(heads)):
             entries, inadmissible = self._nominate(heads, snapshot, now)
@@ -301,6 +307,36 @@ class Scheduler:
                 inadmissible=stats.inadmissible,
                 skip_slugs=dict(self._cycle_skip_slugs))
         return stats
+
+    def _charge_quiet_reservations(self, heads: list[WorkloadInfo],
+                                   snapshot: Snapshot) -> None:
+        """What a preemptor reserved stays reserved until it is its
+        queue's head again, inside one run_until_quiet.
+
+        The reference reserves a preemptor's usage for the rest of the
+        cycle that issued its preemptions (scheduler.go: cq.AddUsage
+        before IssuePreemptions) and its victims hold their quota until
+        their pods are gone, so nobody borrows what a preemption is
+        about to free. Here an eviction is instantaneous and the cycles
+        of one run_until_quiet follow each other with no time between
+        them: without this the victim borrows the freed quota back in
+        the very next cycle, whenever the preemptor's queue shows a
+        re-heaped head of higher priority first, and the reclaim
+        ping-pongs to the cycle limit. The device kernel's rounds keep
+        the same books (full_kernels.round_body ``resv``).
+        """
+        reserved = self._quiet_reserved
+        head_keys = {info.key for info in heads}
+        for key in list(reserved):
+            cq_name, usage = reserved[key]
+            wl = self.store.workloads.get(key)
+            if (key in head_keys or wl is None or wl.is_quota_reserved
+                    or wl.is_finished or not wl.active):
+                del reserved[key]
+                continue
+            cq = snapshot.cluster_queue(cq_name)
+            if cq is not None:
+                cq.add_usage(usage)
 
     def _persist_flush(self) -> None:
         """Cycle-end durability barrier: the WAL's group commit lands
@@ -706,6 +742,14 @@ class Scheduler:
 
     def _run_until_quiet(self, max_cycles: int, now: Optional[float],
                          tick: float) -> int:
+        self._quiet_reserved = {}
+        try:
+            return self._quiet_cycles(max_cycles, now, tick)
+        finally:
+            self._quiet_reserved = None
+
+    def _quiet_cycles(self, max_cycles: int, now: Optional[float],
+                      tick: float) -> int:
         cycles = 0
         prev_probe = None
         while cycles < max_cycles:
@@ -928,24 +972,29 @@ class Scheduler:
             finally:
                 revert()
             return assignment, slice_targets + targets
-        return self._assign(info, snapshot, now)
+        # a plain head's placement on the topology tree waits until the
+        # cycle is about to seat it (_place_deferred)
+        return self._assign(info, snapshot, now, defer_tas=True)
 
-    def _assign(self, info: WorkloadInfo, snapshot: Snapshot,
-                now: float) -> tuple[Assignment, list[Target]]:
+    def _assign(self, info: WorkloadInfo, snapshot: Snapshot, now: float,
+                defer_tas: bool = False) -> tuple[Assignment, list[Target]]:
         cq = snapshot.cluster_queue(info.cluster_queue)
         assert cq is not None
         assigner = FlavorAssigner(
             info, cq, snapshot.resource_flavors, oracle=self.preemptor,
             enable_fair_sharing=self.enable_fair_sharing)
-        full = assigner.assign()
+        full = assigner.assign(defer_tas=defer_tas)
         mode = full.representative_mode()
         if mode == fa.FIT:
             return full, []
         if mode == fa.PREEMPT:
             targets = self.preemptor.get_targets(info, full, snapshot, now)
             if targets:
-                self._update_assignment_for_tas(
-                    info, cq, snapshot, full, targets)
+                if defer_tas:
+                    full.tas_after_targets = True
+                else:
+                    self._update_assignment_for_tas(
+                        info, cq, snapshot, full, targets)
                 return full, targets
 
         from kueue_oss_tpu import features
@@ -977,9 +1026,14 @@ class Scheduler:
                                    cq: ClusterQueueSnapshot,
                                    snapshot: Snapshot,
                                    assignment: Assignment,
-                                   targets: list[Target]) -> None:
+                                   targets: list[Target],
+                                   gone: tuple = ()) -> None:
         """Recompute topology assignments assuming the preemption victims
-        are gone (scheduler.go updateAssignmentForTAS, :759-783)."""
+        are gone (scheduler.go updateAssignmentForTAS, :759-783); for a
+        plain head this waits until the entry pass is about to issue its
+        preemptions (``tas_after_targets``: most heads with targets
+        never get that far in a cycle), with ``gone``, the cycle's
+        earlier victims, off the tree as well."""
         if assignment.representative_mode() != fa.PREEMPT:
             return
         if not any(fa.is_tas_requested(ps, cq) for ps in info.obj.podsets):
@@ -989,12 +1043,15 @@ class Scheduler:
         tas_requests = fa.workload_topology_requests(info, cq, assignment)
         if not tas_requests:
             return
+        t0 = spans.start()
         revert = snapshot.simulate_workload_removal(
-            [t.info for t in targets])
+            list(gone) + [t.info for t in targets])
         try:
             result = cq.find_topology_assignments_for_workload(tas_requests)
         finally:
             revert()
+            spans.add_since("entries.tas" if assignment.tas_after_targets
+                            else "nominate.tas", t0)
         fa.update_for_tas_result(assignment, result)
 
     # ------------------------------------------------------------------
@@ -1041,6 +1098,8 @@ class Scheduler:
             self._record_skip(e, "variant_raced")
             return
 
+        if e.assignment.deferred_tas is not None:
+            self._place_deferred(e, snapshot, preempted_workloads, now)
         mode = e.assignment.representative_mode()
         if mode == fa.NO_FIT:
             stats.skipped += 1
@@ -1095,6 +1154,11 @@ class Scheduler:
                 self._record_skip(e, "pending_preemption")
                 return
 
+        if e.assignment.tas_after_targets:
+            self._update_assignment_for_tas(
+                e.info, cq, snapshot, e.assignment, e.preemption_targets,
+                gone=tuple(preempted_workloads.values()))
+            e.assignment.tas_after_targets = False
         usage = e.assignment_usage()
         if not self._fits(snapshot, cq, usage, preempted_workloads,
                           e.preemption_targets, e):
@@ -1166,6 +1230,41 @@ class Scheduler:
         e.status = NOMINATED
         self._admit(e, now)
         stats.admitted += 1
+
+    def _place_deferred(self, e: Entry, snapshot: Snapshot,
+                        preempted_workloads: dict[str, WorkloadInfo],
+                        now: float) -> None:
+        """The placement nomination left out (FlavorAssigner.assign
+        ``defer_tas``), made for a Fit that still has its quota when its
+        turn comes, on the tree as the cycle's earlier entries left it:
+        the placement the next cycle's nomination would compute. One
+        that has lost its quota is not placed at all (it is skipped
+        below). Where the tree cannot hold it, the entry is assigned
+        again with the placement inside (the reference's order: Preempt
+        for room on the tree, or NoFit)."""
+        cq = e.cq_snapshot
+        tas_requests = e.assignment.deferred_tas
+        e.assignment.deferred_tas = None
+        revert = snapshot.simulate_workload_removal(
+            list(preempted_workloads.values()))
+        try:
+            if not cq.fits(e.assignment_usage()):
+                return
+            t0 = spans.start()
+            try:
+                result = cq.find_topology_assignments_for_workload(
+                    tas_requests, workload=e.info.obj)
+            finally:
+                spans.add_since("entries.tas", t0)
+        finally:
+            revert()
+        if not any(res.failure for res in result.values()):
+            fa.update_for_tas_result(e.assignment, result)
+            return
+        e.assignment, e.preemption_targets = self._assign(
+            e.info, snapshot, now)
+        e.inadmissible_msg = e.assignment.message()
+        e.info.last_assignment = e.assignment.last_state
 
     @staticmethod
     def _delays_topology(e: Entry) -> bool:
@@ -1317,6 +1416,12 @@ class Scheduler:
                      cycle=self.cycle_count,
                      cluster_queue=e.info.cluster_queue)
         delay_tas = self._delays_topology(e)
+        if not delay_tas and any(psa.topology_assignment is not None
+                                 for psa in e.assignment.podsets):
+            # a reservation the host tree placed (a drain's are the
+            # device placer's: solver/engine._compute_tas_assignments)
+            spans.count("tas_host_placements")
+            spans.count("tas_placements")
         admission = Admission(
             cluster_queue=e.info.cluster_queue,
             podset_assignments=[
@@ -1439,6 +1544,9 @@ class Scheduler:
                 now=now,
                 preemption_reason=target.reason,
             )
+        if self._quiet_reserved is not None:
+            self._quiet_reserved[e.info.key] = (
+                e.info.cluster_queue, dict(e.assignment_usage()))
         e.inadmissible_msg += (
             f". Pending the preemption of {len(e.preemption_targets)} workload(s)")
         e.requeue_reason = RequeueReason.PENDING_PREEMPTION

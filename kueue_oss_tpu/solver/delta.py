@@ -970,7 +970,14 @@ class HostDeltaSession:
         max_tok = int(toks.max()) if toks.size else -1
         if p.class_tok_root is not None:
             max_tok = max(max_tok, len(p.class_tok_root) - 1)
-        self._class_cs = max(self._class_cs, pow2(max_tok + 2))
+        # room for 16 shapes a queue from the first drain on (up to
+        # 1,024 classes): a stream whose classes arrive over its first
+        # seconds (upstream tas: 9 a queue, the large ones last) would
+        # otherwise grow the space inside the window, and a new shape of
+        # the program is seconds of tracing even where its compile is
+        # cached
+        self._class_cs = max(self._class_cs, pow2(max_tok + 2),
+                             min(pow2(16 * p.n_cqs), 1024))
         cs = self._class_cs
         wl_class = np.full(W + 1, cs - 1, dtype=np.int32)
         pos = toks >= 0

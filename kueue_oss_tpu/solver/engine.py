@@ -128,6 +128,8 @@ class SolverEngine:
         #: the pre-round-5 host-only TAS behavior
         self.device_tas = True
         self._tas_placer = None
+        #: placer programs the drain under way had to build
+        self._tas_builds = 0
         #: TAS CQs admitted to the device path for the CURRENT drain
         #: (computed by pending_backlog, read by the apply path)
         self._drain_tas_ready: set[str] = set()
@@ -374,11 +376,15 @@ class SolverEngine:
     def _compute_tas_assignments(self, candidates, snapshot=None):
         """Device-place admitted TAS candidates in admission order.
 
-        Returns (kept_candidates, topology_by_workload_key); candidates
-        whose placement failed are dropped — they stay in their heaps
-        for the host mop-up cycles after the drain. ``snapshot`` is the
-        pipelined-dispatch prework (lean drains only — the full path's
-        evictions invalidate a pre-built snapshot)."""
+        Returns (kept_candidates, topology_by_workload_key). A
+        candidate whose placement failed is not committed: it was
+        planned lawfully (the kernel seats by quota, and quota it had),
+        so this is no refused plan entry and no fallback; its quota is
+        never charged, it stays in its heap for the host cycle after
+        the drain, and the rest of the plan stands (counter
+        ``tas_place_failed``). ``snapshot`` is the pipelined-dispatch
+        prework (lean drains) or the one the full path built after its
+        evictions."""
         tas_items = []
         for cand in candidates:
             _wl, cq_name, flavor_of, info, _usage = cand
@@ -391,11 +397,13 @@ class SolverEngine:
         from kueue_oss_tpu.core.snapshot import build_snapshot
         from kueue_oss_tpu.solver.tas_engine import DeviceTASPlacer
 
-        if self._tas_placer is None:
-            self._tas_placer = DeviceTASPlacer(self.store)
-        if snapshot is None:
-            snapshot = build_snapshot(self.store)
-        placements = self._tas_placer.place_batch(snapshot, tas_items)
+        with spans.span("apply.tas_place"):
+            if self._tas_placer is None:
+                self._tas_placer = DeviceTASPlacer(self.store)
+            if snapshot is None:
+                snapshot = build_snapshot(self.store)
+            placements = self._tas_placer.place_batch(snapshot, tas_items)
+        self._tas_builds += self._tas_placer.last_builds
         # only candidates actually submitted for placement can fail out
         # of the plan; a TAS-CQ candidate with no flavored resources has
         # no TAS request at all (workload_topology_requests skips empty
@@ -408,17 +416,22 @@ class SolverEngine:
             if cq_name in self._drain_tas_ready and info.key in submitted:
                 ta = placements.get(info.key)
                 if ta is None:
-                    metrics.solver_plan_fallbacks_total.inc()
                     obs.recorder.record(
-                        obs.SOLVER_FALLBACK, info.key,
+                        obs.SKIPPED, info.key,
                         cycle=self._drain_cycle, cluster_queue=cq_name,
                         path=obs.SOLVER,
-                        reason="device TAS placement failed; workload "
-                               "stays queued for the host mop-up cycle",
+                        reason="the device placer found no topology "
+                               "assignment; the quota is not charged and "
+                               "the workload stays queued for the host "
+                               "cycle",
                         reason_slug="tas_place_failed")
-                    continue  # host mop-up places (or rejects) it
+                    continue  # the host cycle places (or parks) it
                 topo_of[info.key] = ta
             kept.append(cand)
+        placed, failed = len(topo_of), len(submitted) - len(topo_of)
+        spans.count("tas_device_placements", placed)
+        spans.count("tas_place_failed", failed)
+        spans.count("tas_placements", placed + failed)
         return kept, topo_of
 
     def export(
@@ -545,7 +558,9 @@ class SolverEngine:
             self._apply_plan(problem, admitted, opt, admit_round, parked,
                              now, result, verify=verify)
         result.apply_time_s = sp.seconds
+        result.program_builds, self._tas_builds = self._tas_builds, 0
         spans.count("drain_admitted", result.admitted)
+        spans.count("solver_program_builds", result.program_builds)
         with spans.span("record"):
             self._ledger_record(
                 result, frame, "lean", dev0,
@@ -1362,8 +1377,8 @@ class SolverEngine:
                         plan_usage[fr] = plan_usage.get(fr, 0) + q
                 candidates.append((wl, cq_name, flavor, info, plan_usage))
 
-            candidates, topo_of = self._compute_tas_assignments(
-                candidates, snapshot=pre.get("snapshot"))
+        candidates, topo_of = self._compute_tas_assignments(
+            candidates, snapshot=pre.get("snapshot"))
 
         ok = self._verify_candidates(candidates, verify,
                                      snapshot=pre.get("snapshot"))
@@ -1619,6 +1634,8 @@ class SolverEngine:
                                   parked, victim_reason, now, result,
                                   verify=verify)
         result.apply_time_s = sp.seconds
+        result.program_builds += self._tas_builds
+        self._tas_builds = 0
         spans.count("drain_admitted", result.admitted)
         spans.count("search_lanes", result.search_lanes)
         spans.count("search_live_lanes", result.search_live_lanes)
@@ -1734,13 +1751,21 @@ class SolverEngine:
                 candidates.append(
                     (wl, cq_name, flavor_of, info, plan_usage))
 
-            # device-TAS placement in admission order; failed placements
-            # drop out of the plan (host mop-up) BEFORE the oracle verify
-            # so the sequential usage walk matches what actually commits
-            candidates, topo_of = self._compute_tas_assignments(candidates)
+        # the evictions above changed usage: the snapshot is built now,
+        # once, for the placement and for the verify
+        snapshot = None
+        if self._drain_tas_ready and any(
+                c[1] in self._drain_tas_ready for c in candidates):
+            from kueue_oss_tpu.core.snapshot import build_snapshot
 
-        # the evictions above changed usage: the snapshot is built now
-        ok = self._verify_candidates(candidates, verify)
+            snapshot = build_snapshot(self.store)
+        # device-TAS placement in admission order; failed placements
+        # drop out of the plan BEFORE the oracle verify so the
+        # sequential usage walk matches what actually commits
+        candidates, topo_of = self._compute_tas_assignments(
+            candidates, snapshot=snapshot)
+
+        ok = self._verify_candidates(candidates, verify, snapshot=snapshot)
 
         with spans.span("apply.commit"):
             for passed, (wl, cq_name, flavor_of, info, _) in zip(

@@ -1338,6 +1338,25 @@ def round_body(t: FullTensors, state, pot, g_max: int, h_max: int,
 
     cand_w = select_heads_full(t, admitted, parked, ts,
                                lq_penalty=state["lq_penalty"])
+
+    # ---- what a preemptor reserved stays reserved until it is its
+    # queue's head again (Scheduler._charge_quiet_reservations): the
+    # rounds of one drain are one instant, and a victim must not borrow
+    # back what its eviction freed before the preemptor gets to it.
+    # Charged to the round-start usage only; the durable rows never
+    # carry it
+    is_head_w = jnp.zeros((W1,), dtype=bool).at[cand_w].set(True)
+    resv = state["resv"] & ~is_head_w & ~admitted
+    resv = resv.at[W_null].set(False)
+    resv_req = state["resv_req"]
+
+    def _with_reservations():
+        node = t.cq_node[jnp.minimum(t.wl_cqid, C - 1)]
+        rows = state["cq_rows"].at[node].add(
+            jnp.where(resv[:, None], resv_req, 0))
+        return refresh_cohort_usage(t, rows)
+
+    usage = jax.lax.cond(jnp.any(resv), _with_reservations, lambda: usage)
     avail = available_all(t, usage)
     (mode, k_chosen, req_c, borrow, next_cursor,
      opt_fit, opt_preempt, opt_level, group_active, opt_valid) = (
@@ -1482,6 +1501,13 @@ def round_body(t: FullTensors, state, pot, g_max: int, h_max: int,
     wl_usage = out["wl_usage"]
     victims = out["victims_all"]
 
+    # an entry that issued preemptions reserves what it was charged
+    resv = resv.at[cand_w].max(pre_entry)
+    resv_req = resv_req.at[cand_w].set(
+        jnp.where(pre_entry[:, None], req_c.astype(resv_req.dtype),
+                  resv_req[cand_w]), mode="drop")
+    resv = resv.at[W_null].set(False)
+
     # ---- bookkeeping for evicted victims ------------------------
     ts = jnp.where(victims, t.ts_evict_base + rounds, ts)
     evicted_f = state["evicted"] | victims
@@ -1553,7 +1579,7 @@ def round_body(t: FullTensors, state, pot, g_max: int, h_max: int,
         "admit_round": admit_round, "class_nofit": class_nofit,
         "victim_reason": out["victim_reason"],
         "lq_penalty": out["lq_penalty"], "progress": progress,
-        "rounds": rounds + 1,
+        "rounds": rounds + 1, "resv": resv, "resv_req": resv_req,
         # how often the liveness gate engages (_run_searches): lanes
         # offered to the victim search, and lanes that ran its stage 2
         "search_lanes": state["search_lanes"] + lanes_offered,
@@ -1585,6 +1611,8 @@ def _init_state(t: FullTensors, g_max: int):
         "victim_reason": jnp.zeros((W1,), dtype=jnp.int8),
         "lq_penalty": t.lq_penalty0,
         "class_nofit": jnp.zeros((t.class_root.shape[0],), dtype=bool),
+        "resv": jnp.zeros((W1,), dtype=bool),
+        "resv_req": jnp.zeros_like(t.wl_req[:, 0], dtype=t.usage0.dtype),
         "progress": jnp.ones((), dtype=bool),
         "rounds": jnp.zeros((), dtype=jnp.int32),
         "search_lanes": jnp.zeros((), dtype=jnp.int32),
@@ -1765,12 +1793,17 @@ def full_solver(g_max: int, h_max: int = 32, p_max: int = 128,
     return fn
 
 
+#: candidate widths up to this one stand in for any narrower one
+_NARROW_P_MAX = 64
+
+
 def built_p_max(g_max: int, h_max: int, p_max: int,
                 fs_enabled: bool = False, mesh=None,
                 axis: str = "wl") -> int:
     """The candidate width a drain that NEEDS ``p_max`` should run at:
     twice that where :func:`full_solver` has the next width up and not
-    this one, else ``p_max`` itself.
+    this one (for a width under ``_NARROW_P_MAX``: the narrowest built
+    one up to it), else ``p_max`` itself.
 
     ``p_max`` only pads the candidate axis, so a wider program gives the
     same plan bit for bit; what differs is the cost. Building the exact
@@ -1785,7 +1818,18 @@ def built_p_max(g_max: int, h_max: int, p_max: int,
         return _solver_key(g_max, h_max, p, fs_enabled, mesh,
                            axis) in _solver_cache
 
-    return 2 * p_max if have(2 * p_max) and not have(p_max) else p_max
+    if have(p_max):
+        return p_max
+    # one width up; and under _NARROW_P_MAX any built width up to it: a
+    # candidate axis that narrow is all padding cost-wise, and a stream
+    # whose cohorts hold a few large gangs sizes its drains by a
+    # population of 8 to 32 rows, a program each where this stopped
+    wider = 2 * p_max
+    while wider <= max(2 * p_max, _NARROW_P_MAX):
+        if have(wider):
+            return wider
+        wider *= 2
+    return p_max
 
 
 def solve_backlog_full(t: FullTensors, g_max: int, h_max: int = 32,

@@ -20,13 +20,20 @@ jitted JAX:
 
 Scope: the base placer (make_placer) covers single podsets under the
 BestFit / LeastFreeCapacity profiles; the extended placer
-(make_placer_ext) adds single-layer podset slices and a count-1 leader
-podset, both parity-tested against the host tree
-(tests/test_tas_kernel.py, tests/test_tas_kernel_ext.py). Still
-host-only by design: balanced placement (its selectOptimalDomainSetToFit
-is a dict-memoized DP over (leaders, capacity) states whose tie-breaks
-resist an exact dense-tensor port — tas_balanced_placement.go:1-382) and
-nested multi-layer slice constraints.
+(make_placer_ext) adds single-layer podset slices, a count-1 leader
+podset and, built with ``bal_cap``, BALANCED placement
+(tas_balanced_placement.go, gate TASBalancedPlacement) of a
+preferred-level request with no slice and no leader: the greedy
+evaluation, the balance threshold, the pruning, the choice of the best
+sibling group, selectOptimalDomainSetToFit as a dense table over
+(domains chosen, capacity left) that keeps the FIRST subset the host's
+dict-memoized DP finds, the entropy order from the host's own
+fixed-point sums (tas/snapshot.py ``xlog2x_fixed``), and the even
+distribution with front-first extras (``_balanced_at_level``). All are
+parity-tested against the host tree (tests/test_tas_kernel.py,
+test_tas_kernel_ext.py, test_tas_kernel_balanced.py). Still host-only:
+balanced placement of sliced or leader-led podsets or of gangs above
+``BALANCED_MAX_COUNT`` pods, and nested multi-layer slice constraints.
 
 Reference parity: pkg/cache/scheduler/tas_flavor_snapshot.go (two-phase
 algorithm); SURVEY.md §7 step 6 calls this the most TPU-friendly
@@ -53,6 +60,11 @@ class TASLevels:
     leaf_capacity: np.ndarray          # [D_leaf, R] int32
     leaf_names: list[tuple[str, ...]]  # decode table
     resources: list[str]
+    #: per level: [D_l] int32, a domain's place in the host tree's own
+    #: order of that level (``domains_per_level[l]``: creation order),
+    #: which orders siblings as ``Domain.children`` does. The balanced
+    #: placement breaks ties by it where the host iterates its lists
+    ranks: list[np.ndarray] | None = None
 
 
 def build_levels(snapshot) -> TASLevels:
@@ -71,6 +83,14 @@ def build_levels(snapshot) -> TASLevels:
         else:
             parents.append(np.asarray(
                 [index[l - 1][d.id[:-1]] for d in doms], dtype=np.int32))
+    ranks = []
+    for l, doms in enumerate(levels):
+        # children are appended, and a level's dict is filled, in the
+        # one order in which the tree was linked: one rank serves both
+        created = {d.id: i for i, d in enumerate(
+            snapshot.domains_per_level[l].values())}
+        ranks.append(np.asarray([created[d.id] for d in doms],
+                                dtype=np.int32))
     resources = sorted({r for d in levels[-1] for r in d.free_capacity})
     cap = np.zeros((len(levels[-1]), max(1, len(resources))),
                    dtype=np.int64)
@@ -83,6 +103,7 @@ def build_levels(snapshot) -> TASLevels:
         leaf_capacity=np.minimum(cap, BIG).astype(np.int32),
         leaf_names=[d.id for d in levels[-1]],
         resources=resources,
+        ranks=ranks,
     )
 
 
@@ -247,22 +268,38 @@ def make_sequential_placer(parents_np: list[np.ndarray]):
     return place_all
 
 
-def make_sequential_placer_ext(parents_np: list[np.ndarray]):
+def make_sequential_placer_ext(parents_np: list[np.ndarray],
+                               ranks_np=None, bal_cap: int = 0):
     """Sequential on-device drain through the slice/leader-capable
     placer: per-workload slice_size/slice_level and an optional count-1
     leader (``has_leader`` [M] bool — explicit, so a leader podset with
     all-zero requests places identically to place_podset_ext). The
-    capacity carry subtracts worker pods AND the leader's row."""
-    place = make_placer_ext(parents_np)
+    capacity carry subtracts worker pods AND the leader's row. A row of
+    ``count`` 0 (a padded or a pre-rejected one) runs no placement at
+    all. ``ranks_np`` and ``bal_cap`` as in ``make_placer_ext``;
+    ``balanced`` [M] marks the rows that ask for balanced placement."""
+    place = make_placer_ext(parents_np, ranks_np, bal_cap)
 
     @jax.jit
     def place_all(leaf_capacity, per_pod, count, level, required,
                   unconstrained, least_free, slice_size, slice_level,
-                  leader_per_pod, has_leader):
+                  leader_per_pod, has_leader, balanced=None):
+        if balanced is None:
+            balanced = jnp.zeros(count.shape, dtype=bool)
+        n_leaf = leaf_capacity.shape[0]
+
         def step(cap, xs):
-            pp, ct, lv, rq, un, lf, ss, sl, lpp, hl = xs
-            sel, lead_leaf, ok = place(cap, pp, ct, lv, rq, un, lf,
-                                       ss, sl, lpp, hl)
+            pp, ct, lv, rq, un, lf, ss, sl, lpp, hl, bal = xs
+
+            def run(_):
+                return place(cap, pp, ct, lv, rq, un, lf, ss, sl, lpp,
+                             hl, bal)
+
+            def skip(_):
+                return (jnp.zeros((n_leaf,), dtype=jnp.int32),
+                        jnp.int32(-1), jnp.zeros((), dtype=bool))
+
+            sel, lead_leaf, ok = jax.lax.cond(ct > 0, run, skip, None)
             take = jnp.where(ok, sel, 0)
             cap = cap - take[:, None] * pp[None, :]
             lead_onehot = (jnp.arange(cap.shape[0], dtype=jnp.int32)
@@ -271,10 +308,12 @@ def make_sequential_placer_ext(parents_np: list[np.ndarray]):
             return cap, (sel * ok.astype(sel.dtype),
                          jnp.where(ok, lead_leaf, -1), ok)
 
-        cap_after, (sels, leads, oks) = jax.lax.scan(
-            step, leaf_capacity,
-            (per_pod, count, level, required, unconstrained, least_free,
-             slice_size, slice_level, leader_per_pod, has_leader))
+        with jax.named_scope("tas_place_all"):
+            cap_after, (sels, leads, oks) = jax.lax.scan(
+                step, leaf_capacity,
+                (per_pod, count, level, required, unconstrained,
+                 least_free, slice_size, slice_level, leader_per_pod,
+                 has_leader, balanced))
         return sels, leads, oks, cap_after
 
     return place_all
@@ -457,28 +496,260 @@ def _consume_in_order(s_sorted, seg_sorted, need_of_seg, n_seg,
     return full_take + bf_take
 
 
-def make_placer_ext(parents_np: list[np.ndarray]):
+# ---------------------------------------------------------------------------
+# balanced placement (tas_balanced_placement.go; host: tas/snapshot.py
+# _find_best_balanced / _apply_balanced), no slices, no leader
+# ---------------------------------------------------------------------------
+
+from kueue_oss_tpu.solver.tas_engine import (  # noqa: E402
+    BALANCED_MAX_COUNT,
+)
+
+#: the entropy table's reach: a leaf that fits more pods than this is
+#: left to the host tree (the row reads infeasible)
+XLOG_MAX = 4095
+_XLOG_LO_BITS = 20
+
+
+def _xlog_tables():
+    """``xlog2x_fixed(s)`` for s in 0..XLOG_MAX as two int32 words (the
+    sums of 64 hosts pass 2**31 in one)."""
+    from kueue_oss_tpu.tas.snapshot import xlog2x_fixed
+
+    v = np.asarray([xlog2x_fixed(i) for i in range(XLOG_MAX + 1)],
+                   dtype=np.int64)
+    return ((v >> _XLOG_LO_BITS).astype(np.int32),
+            (v & ((1 << _XLOG_LO_BITS) - 1)).astype(np.int32))
+
+
+def _seg_prefix_excl(s_sorted, seg_sorted):
+    """Exclusive running sum inside each run of equal ``seg_sorted``."""
+    csum = jnp.cumsum(s_sorted)
+    is_start = jnp.concatenate([jnp.ones(1, dtype=bool),
+                                seg_sorted[1:] != seg_sorted[:-1]])
+    base = jax.lax.associative_scan(
+        jnp.maximum, jnp.where(is_start, csum - s_sorted, -1))
+    return csum - s_sorted - base
+
+
+def _greedy_eval(state, seg, n_seg, count):
+    """evaluateGreedyAssignment per segment, no leader: domains in
+    (state desc, lex) order until ``count`` is covered. Returns per
+    segment (fits, domains used, the last used domain's state)."""
+    idx = jnp.arange(state.shape[0], dtype=jnp.int32)
+    order = jnp.lexsort((idx, -state, seg))
+    s, sg = state[order], seg[order]
+    taken = (_seg_prefix_excl(s, sg) < count) & (s > 0)
+    used = jax.ops.segment_sum(taken.astype(jnp.int32), sg,
+                               num_segments=n_seg)
+    last = jax.ops.segment_min(jnp.where(taken, s, BIG), sg,
+                               num_segments=n_seg)
+    fits = jax.ops.segment_sum(state, seg, num_segments=n_seg) >= count
+    return fits, used, last
+
+
+def _first_found_subset(state, order_key, n, count, cap):
+    """selectOptimalDomainSetToFit's DP, no leader: of the domains with
+    ``state`` > 0, taken in ``order_key`` order, the subset of exactly
+    ``n`` whose states cover ``count`` most tightly, and of those with
+    one total the FIRST the host's loop stores
+    (``placements[i].setdefault``). ``first[i][c]`` is the position of
+    the domain whose turn first made (i chosen, c left) reachable; a
+    key's subset never changes once stored, so the chosen set is read
+    back along those positions. Returns (mask [T], found)."""
+    T = state.shape[0]
+    perm = jnp.argsort(order_key)
+    s = state[perm]
+    tpos = jnp.arange(T, dtype=jnp.int32)[:, None]
+    caps = jnp.arange(cap + 1, dtype=jnp.int32)[None, :]
+    inf = jnp.int32(T + 1)
+    live = (s > 0)[:, None]
+    src = caps + s[:, None]                 # the key an item extends
+    src_ok = live & (src <= cap) & (caps >= 1)
+    src_c = jnp.minimum(src, cap)
+    table = jnp.full((cap + 1, cap + 1), inf, dtype=jnp.int32)
+    table = table.at[0].set(jnp.where(caps[0] == count, -1, inf))
+
+    def level(i, table):
+        prev = table[i - 1]
+        reach = src_ok & (prev[src_c] < tpos)
+        return table.at[i].set(jnp.min(jnp.where(reach, tpos, inf),
+                                       axis=0))
+
+    table = jax.lax.fori_loop(1, n, level, table)
+    # the n-th domain closes the set: a key <= 0, the largest there is
+    prev = table[jnp.maximum(n - 1, 0)]
+    key = caps - s[:, None]
+    good = live & (caps >= 1) & (prev[None, :] < tpos) & (key <= 0)
+    best = jnp.max(jnp.where(good, key, -BIG))
+    t_n = jnp.min(jnp.where(good & (key == best), tpos, inf))
+    found = jnp.any(good) & (n >= 1)
+    t_n = jnp.minimum(t_n, T - 1)
+    mask = jnp.zeros((T,), dtype=bool).at[t_n].set(True)
+
+    def back(j, carry):
+        mask, c = carry
+        t = jnp.clip(table[n - 1 - j, jnp.clip(c, 0, cap)], 0, T - 1)
+        return mask.at[t].set(True), c + s[t]
+
+    mask, _ = jax.lax.fori_loop(0, n - 1, back, (mask, best + s[t_n]))
+    return jnp.zeros((T,), dtype=bool).at[perm].set(mask) & found, found
+
+
+def _balanced_distribute(state, mask, thr, count):
+    """placeSlicesOnDomainsBalanced's loop: ``thr`` pods to every chosen
+    domain, what is left front-first in (state desc, lex) order."""
+    idx = jnp.arange(state.shape[0], dtype=jnp.int32)
+    order = jnp.lexsort((idx, jnp.where(mask, -state, BIG)))
+    s, m = state[order], mask[order]
+    room = jnp.where(m, s - thr, 0)
+    extra = count - jnp.sum(mask.astype(jnp.int32)) * thr
+    take = jnp.clip(extra - (jnp.cumsum(room) - room), 0, room)
+    ok = (extra >= 0) & (jnp.sum(room) >= extra)
+    given = jnp.where(m, thr + take, 0)
+    return jnp.zeros_like(state).at[order].set(given), ok
+
+
+def _balanced_at_level(parents, ranks, xlog, states, count, l, cap):
+    """Balanced placement of ``count`` pods requested at (static) level
+    ``l``. ``states[k]`` is level k's fit count (no slices: pods).
+    Returns (use, ok, sel per level): ``use`` where some sibling group
+    holds the whole gang (threshold > 0: the host then places balanced
+    or fails), ``sel`` zero but at the fit level (``l + 1``, or ``l``
+    where that is the lowest)."""
+    n_levels = len(parents)
+    leaf = n_levels - 1
+    A = states[l]
+    idx_a = jnp.arange(A.shape[0], dtype=jnp.int32)
+    if l == 0:
+        grp_a = jnp.zeros_like(A)
+        n_grp = 1
+        grp_rank = jnp.zeros((1,), dtype=jnp.int32)
+    else:
+        grp_a = parents[l]
+        n_grp = states[l - 1].shape[0]
+        grp_rank = jnp.asarray(ranks[l - 1])
+    low = l < leaf
+    B = states[l + 1] if low else A
+    par_b = parents[l + 1] if low else idx_a
+    grp_b = grp_a[par_b]
+
+    # findBestDomainsForBalancedPlacement, every sibling group at once
+    fits, used, last = _greedy_eval(B, grp_b, n_grp, count)
+    thr_g = jnp.where(fits, jnp.minimum(count // jnp.maximum(used, 1),
+                                        last), 0)
+    Bp = jnp.where(B >= thr_g[grp_b], B, 0)          # prune the children
+    if low:
+        Ap = jax.ops.segment_sum(Bp, par_b, num_segments=A.shape[0])
+        Ap = jnp.where(Ap >= thr_g[grp_a], Ap, 0)    # then the domain
+        Bp = jnp.where(Ap[par_b] > 0, Bp, 0)
+    else:
+        Ap = Bp
+    ok_g, n_g, _ = _greedy_eval(Ap, grp_a, n_grp, count)
+    valid = fits & ok_g & (thr_g > 0)
+    top = jnp.max(jnp.where(valid, thr_g, 0))
+    c1 = valid & (thr_g == top)
+    c2 = c1 & (n_g == jnp.min(jnp.where(c1, n_g, BIG)))
+    g = jnp.argmin(jnp.where(c2, grp_rank, BIG)).astype(jnp.int32)
+    use = jnp.any(valid)
+    thr, n_dom = thr_g[g], n_g[g]
+    in_g = grp_a == g
+    ok = use
+
+    if low:
+        # applyBalancedPlacementAlgorithm one level up: the optimal set
+        # of domains by (capacity desc, entropy of the children desc)
+        hi, lo = (jnp.asarray(t) for t in xlog)
+        ok = ok & (jnp.max(jnp.where(in_g[par_b], Bp, 0)) <= XLOG_MAX)
+        bc = jnp.minimum(Bp, XLOG_MAX)
+        e_hi = jax.ops.segment_sum(hi[bc], par_b, num_segments=A.shape[0])
+        e_lo = jax.ops.segment_sum(lo[bc], par_b, num_segments=A.shape[0])
+        e_hi = e_hi + (e_lo >> _XLOG_LO_BITS)
+        e_lo = e_lo & ((1 << _XLOG_LO_BITS) - 1)
+        cand = jnp.where(in_g, Ap, 0)
+        rank_a = jnp.asarray(ranks[l])
+        order = jnp.lexsort((rank_a, e_lo, e_hi, -cand))
+        pos = jnp.zeros_like(idx_a).at[order].set(idx_a)
+        chosen, found = _first_found_subset(cand, pos, n_dom, count, cap)
+        ok = ok & found
+        items = jnp.where(chosen[par_b], Bp, 0)
+        item_key = pos[par_b] * B.shape[0] + jnp.asarray(ranks[l + 1])
+        fit_level = l + 1
+    else:
+        items = jnp.where(in_g, Ap, 0)
+        item_key = jnp.asarray(ranks[l])
+        fit_level = l
+    # placeSlicesOnDomainsBalanced on the fit level's domains
+    zero = jnp.zeros_like(items)
+    fits2, n2, _ = _greedy_eval(items, zero, 1, count)
+    mask, found2 = _first_found_subset(items, item_key, n2[0], count, cap)
+    given, ok3 = _balanced_distribute(items, mask, thr, count)
+    ok = ok & fits2[0] & found2 & ok3 & (count <= cap)
+    sel = tuple(jnp.where(use & ok, given, 0) if k == fit_level
+                else jnp.zeros_like(states[k]) for k in range(n_levels))
+    return use, ok, sel
+
+
+
+def make_placer_ext(parents_np: list[np.ndarray], ranks_np=None,
+                    bal_cap: int = 0):
     """Jitted placer with slice + leader support for one tree shape.
 
     ``place(leaf_capacity, per_pod, count, requested_level, required,
     unconstrained, least_free, slice_size, slice_level, leader_per_pod,
-    has_leader)`` returns (worker_leaf_sel [D_leaf] pods,
+    has_leader, balanced)`` returns (worker_leaf_sel [D_leaf] pods,
     leader_leaf int32 (-1 when none), feasible bool). Covers
     findTopologyAssignment for single-layer slices and a count-1 leader
-    podset (tas_flavor_snapshot.go:804-999); nested slice layers and
-    balanced placement stay on the host tree.
+    podset (tas_flavor_snapshot.go:804-999) and, built with the tree's
+    ``ranks_np`` (``TASLevels.ranks``) and ``bal_cap`` > 0, the balanced
+    placement of a row whose ``balanced`` flag is set (a preferred-level
+    request while TASBalancedPlacement is on; no slice, no leader, at
+    most ``bal_cap`` pods); nested slice layers stay on the host tree.
     """
     parents = [jnp.asarray(p) for p in parents_np]
     n_levels = len(parents)
+    xlog = _xlog_tables() if bal_cap else None
+
+    def balanced_seed(cs, count, requested_level, on):
+        """(use, ok, sel per level) of the balanced placement, all
+        zero where the row asks for none."""
+        states = [cs[l]["st"] for l in range(n_levels)]
+        off = (jnp.zeros((), dtype=bool), jnp.zeros((), dtype=bool),
+               tuple(jnp.zeros_like(st) for st in states))
+        if not bal_cap:
+            return off
+
+        def run(_):
+            with jax.named_scope("tas_balanced"):
+                return jax.lax.switch(
+                    jnp.clip(requested_level, 0, n_levels - 1),
+                    [lambda l=l: _balanced_at_level(
+                        parents, ranks_np, xlog, states, count, l,
+                        bal_cap) for l in range(n_levels)])
+
+        return jax.lax.cond(on, run, lambda _: off, None)
 
     @jax.jit
     def place(leaf_capacity, per_pod, count, requested_level, required,
               unconstrained, least_free, slice_size, slice_level,
-              leader_per_pod, has_leader):
-        cs = fill_counts_ext(parents, leaf_capacity, per_pod,
-                             leader_per_pod, has_leader, slice_size,
-                             slice_level)
+              leader_per_pod, has_leader, balanced=False):
+        with jax.named_scope("tas_fill_counts"):
+            cs = fill_counts_ext(parents, leaf_capacity, per_pod,
+                                 leader_per_pod, has_leader, slice_size,
+                                 slice_level)
+        with jax.named_scope("tas_select_domains"):
+            return select(cs, count, requested_level, required,
+                          unconstrained, least_free, slice_size,
+                          slice_level, has_leader, balanced)
+
+    def select(cs, count, requested_level, required, unconstrained,
+               least_free, slice_size, slice_level, has_leader, balanced):
         slice_count = count // jnp.maximum(slice_size, 1)
+        use_bal, ok_bal, sel_bal = balanced_seed(
+            cs, count, requested_level,
+            jnp.asarray(balanced) & ~required & ~unconstrained
+            & ~has_leader & (slice_size == 1)
+            & (slice_level == n_levels - 1))
 
         def units_at(l):
             # placement units at level l (need conversions cross SL)
@@ -552,6 +823,14 @@ def make_placer_ext(parents_np: list[np.ndarray]):
                                           gl & has_leader, lead[l]))
             feasible = feasible | is_single | (use_greedy & cap_ok)
         start = jnp.where(single_fit, chosen_level, greedy_level)
+        # balanced placement seeds its own fit level: one below the
+        # requested one, or the requested one where that is the lowest
+        for l in range(n_levels):
+            sel[l] = jnp.where(use_bal, sel_bal[l], sel[l])
+            lead[l] = lead[l] & ~use_bal
+        feasible = jnp.where(use_bal, ok_bal, feasible)
+        start = jnp.where(
+            use_bal, jnp.minimum(requested_level + 1, n_levels - 1), start)
 
         # ---- descend --------------------------------------------------
         for l in range(n_levels - 1):
@@ -566,6 +845,21 @@ def make_placer_ext(parents_np: list[np.ndarray]):
             computed, comp_lead = _greedy_segment_lead(
                 cs[l + 1], l + 1, slice_level, par, need_par, lead[l],
                 n_par, least_free)
+            # down to the slice level the host pools the children of
+            # ALL chosen domains and places the whole count on them
+            # anew (findTopologyAssignment's first descent); a balanced
+            # placement and the levels below the slices go parent by
+            # parent
+            chosen = ((sel[l] > 0) | lead[l])[par]
+            pool = {k: jnp.where(chosen, v, 0)
+                    for k, v in cs[l + 1].items()}
+            pooled, pooled_lead = _greedy_segment_lead(
+                pool, l + 1, slice_level, jnp.zeros_like(par),
+                jnp.full((1,), units_at(l + 1), dtype=sel[l].dtype),
+                jnp.full((1,), True) & has_leader, 1, least_free)
+            in_pool = ~below_sl & ~use_bal
+            computed = jnp.where(in_pool, pooled, computed)
+            comp_lead = jnp.where(in_pool, pooled_lead, comp_lead)
             keep = jnp.asarray(l + 1) <= start
             sel[l + 1] = jnp.where(keep, sel[l + 1], computed)
             lead[l + 1] = jnp.where(keep, lead[l + 1], comp_lead)
@@ -618,25 +912,56 @@ def place_podset(snapshot, per_pod: dict, count: int,
 
 
 _placer_ext_cache: dict = {}
+_sequential_ext_cache: dict = {}
+
+
+def tree_key(levels: TASLevels, bal_cap: int) -> tuple:
+    """What a placer is compiled for: the FULL parent structure (the
+    placer bakes parents in at trace time, so any relabeled domain must
+    miss the cache) and, for the balanced placement, the host tree's
+    own order of every level."""
+    return (tuple(np.asarray(p, dtype=np.int32).tobytes()
+                  for p in levels.parents),
+            tuple(np.asarray(r, dtype=np.int32).tobytes()
+                  for r in levels.ranks) if bal_cap else (), bal_cap)
+
+
+def sequential_placer_for(levels: TASLevels, bal_cap: int = 0):
+    """The process's sequential placer of one tree: one jitted function
+    for every engine of the process, so a program it has traced for a
+    batch size is traced once (a benchmark's twin deployment and its
+    cell share it). Returns (placer, its tree key, built now)."""
+    key = tree_key(levels, bal_cap)
+    placer = _sequential_ext_cache.get(key)
+    if placer is not None:
+        return placer, key, False
+    placer = make_sequential_placer_ext(levels.parents, levels.ranks,
+                                        bal_cap)
+    _sequential_ext_cache[key] = placer
+    return placer, key, True
 
 
 def place_podset_ext(snapshot, per_pod: dict, count: int,
                      requested_level_idx: int, required: bool = False,
                      unconstrained: bool = False, slice_size: int = 1,
                      slice_level_idx: int | None = None,
-                     leader_per_pod: dict | None = None):
+                     leader_per_pod: dict | None = None,
+                     balanced: bool = False):
     """Host wrapper for the slice/leader-capable placer.
 
     Returns (worker {leaf id: pods}, leader leaf id or None) or None
-    when infeasible. Single slice layer + count-1 leader podset; nested
-    slice layers and balanced placement stay on the host tree
+    when infeasible. Single slice layer + count-1 leader podset, and
+    with ``balanced`` the balanced placement of a preferred-level
+    request (no slice, no leader, up to BALANCED_MAX_COUNT pods); nested
+    slice layers stay on the host tree
     (tas_flavor_snapshot.go:804-999 scope notes in make_placer_ext).
     """
     levels = build_levels(snapshot)
-    key = tuple(tuple(p.tolist()) for p in levels.parents)
+    bal_cap = BALANCED_MAX_COUNT if balanced else 0
+    key = tree_key(levels, bal_cap)
     placer = _placer_ext_cache.get(key)
     if placer is None:
-        placer = make_placer_ext(levels.parents)
+        placer = make_placer_ext(levels.parents, levels.ranks, bal_cap)
         _placer_ext_cache[key] = placer
     R = max(1, len(levels.resources))
     req = np.zeros(R, dtype=np.int32)
@@ -660,7 +985,8 @@ def place_podset_ext(snapshot, per_pod: dict, count: int,
         jnp.asarray(least_free),
         jnp.asarray(max(slice_size, 1), dtype=jnp.int32),
         jnp.asarray(slice_level_idx, dtype=jnp.int32),
-        jnp.asarray(lead), jnp.asarray(has_leader))
+        jnp.asarray(lead), jnp.asarray(has_leader),
+        jnp.asarray(balanced))
     if not bool(feasible):
         return None
     worker_sel = np.asarray(worker_sel)

@@ -28,6 +28,9 @@ LeastFreeCapacity profiles, unhealthy-node replacement
 
 from __future__ import annotations
 
+import functools
+import math
+from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Iterable, Optional
 
@@ -42,6 +45,55 @@ from kueue_oss_tpu.api.types import (
 )
 
 Requests = dict[str, int]
+
+#: fixed point of ``s * log2(s)`` in the balanced placement's entropy:
+#: the host tree and the device placer (solver/tas_kernels.py) add up
+#: the SAME integers, so that the entropy tie-break of
+#: selectOptimalDomainSetToFit is one order on both sides
+XLOG_SHIFT = 22
+
+
+@functools.lru_cache(maxsize=8192)
+def xlog2x_fixed(s: int) -> int:
+    """``round(s * log2(s) * 2**XLOG_SHIFT)`` as an integer (0 for
+    s <= 1)."""
+    if s <= 1:
+        return 0
+    return int(round(s * math.log2(s) * (1 << XLOG_SHIFT)))
+
+
+
+#: what the EMPTY tree answers to a request (``simulate_empty``: the
+#: check that a head which needs preemption could be placed at all), by
+#: everything that answer depends on: the nodes as they were read
+#: (content, not identity: a node changed in place is another tree),
+#: the flavor's tolerations, the feature gates, the request. A pure
+#: function of its key, so nothing ever invalidates it; the newest few
+#: trees are kept. Every capacity-freed flush re-nominates each parked
+#: class of every queue, most of them heads that cannot preempt: the
+#: same few shapes against the same nodes, pass after pass.
+_EMPTY_FIT: "OrderedDict[tuple, dict]" = OrderedDict()
+_EMPTY_FIT_TREES = 4
+
+
+def _empty_fit_of(tree_key: tuple) -> dict:
+    memo = _EMPTY_FIT.get(tree_key)
+    if memo is None:
+        memo = _EMPTY_FIT[tree_key] = {}
+        while len(_EMPTY_FIT) > _EMPTY_FIT_TREES:
+            _EMPTY_FIT.popitem(last=False)
+    else:
+        _EMPTY_FIT.move_to_end(tree_key)
+    return memo
+
+
+def _request_key(tr: "TASPodSetRequest") -> tuple:
+    ps = tr.podset
+    return (ps.name, tr.count, tuple(sorted(tr.single_pod_requests.items())),
+            tr.flavor, tr.implied, tr.podset_group_name,
+            repr(ps.topology_request),
+            tuple(sorted(ps.node_selector.items())),
+            tuple(ps.tolerations))
 
 
 def count_in(requests: Requests, capacity: Requests) -> int:
@@ -154,6 +206,12 @@ class TASFlavorSnapshot:
         #: (balanced DP included) — see the TASDeviceFillCounts gate
         self.use_device_fill = False
         self._device_tree = None  # (parents, lex-ordered domain lists)
+        #: hostname -> its first leaf (assignments of a tree whose
+        #: lowest level is the hostname name their leaves by it alone)
+        self._by_hostname: dict[str, LeafDomain] = {}
+        #: the empty tree's answers so far (``_EMPTY_FIT``), where the
+        #: tree was built by :func:`build_tas_flavor_snapshot`
+        self.empty_fit: Optional[dict] = None
 
     # ------------------------------------------------------------------
     # Construction
@@ -168,6 +226,7 @@ class TASFlavorSnapshot:
         if leaf is None:
             leaf = LeafDomain(values, values)
             self.leaves[values] = leaf
+            self._by_hostname.setdefault(values[-1], leaf)
         if self.is_lowest_level_node:
             leaf.node = node
         _add(leaf.free_capacity, node.allocatable)
@@ -222,14 +281,11 @@ class TASFlavorSnapshot:
         if leaf is not None:
             return leaf
         if len(values) == 1 and self.is_lowest_level_node:
-            for candidate in self.leaves.values():
-                if candidate.level_values[-1] == values[0]:
-                    return candidate
+            return self._by_hostname.get(values[0])
         return None
 
     def has_node(self, hostname: str) -> bool:
-        return any(leaf.level_values[-1] == hostname
-                   for leaf in self.leaves.values())
+        return hostname in self._by_hostname
 
     # ------------------------------------------------------------------
     # Level helpers
@@ -307,6 +363,25 @@ class TASFlavorSnapshot:
         accumulating assumed usage between groups
         (FindTopologyAssignmentsForFlavor, tas_flavor_snapshot.go:519-594).
         """
+        if simulate_empty and workload is None and self.empty_fit is not None:
+            from kueue_oss_tpu import features
+
+            key = (features.overrides_key(),
+                   tuple(_request_key(tr) for tr in requests))
+            hit = self.empty_fit.get(key)
+            if hit is None:
+                hit = self.empty_fit[key] = self._find_topology_assignments(
+                    requests, True, None)
+            return dict(hit)
+        return self._find_topology_assignments(requests, simulate_empty,
+                                               workload)
+
+    def _find_topology_assignments(
+        self,
+        requests: list[TASPodSetRequest],
+        simulate_empty: bool,
+        workload: Optional[Workload],
+    ) -> dict[str, TASAssignmentResult]:
         result: dict[str, TASAssignmentResult] = {}
         assumed: dict[tuple[str, ...], Requests] = {}
 
@@ -1035,17 +1110,17 @@ class TASFlavorSnapshot:
 
     @staticmethod
     def _entropy(sizes: list[int]) -> float:
-        import math
-
+        """Entropy of the split ``sizes`` (bits), from a fixed-point sum:
+        H = log2(T) - (1/T) * sum(s * log2(s)) with every term rounded
+        to 2**-XLOG_SHIFT (``xlog2x_fixed``). Two splits of one total
+        therefore compare by an integer sum, whatever the order of
+        their terms: the order the device placer reproduces exactly
+        (tas_kernels ``_balanced_at_level``)."""
         total = sum(sizes)
         if total <= 0:
             return 0.0
-        e = 0.0
-        for s in sizes:
-            if s > 0:
-                p = s / total
-                e += -p * math.log2(p)
-        return e
+        s_int = sum(xlog2x_fixed(s) for s in sizes if s > 0)
+        return math.log2(total) - s_int / (total * (1 << XLOG_SHIFT))
 
     def _select_optimal_set(self, domains: list[Domain], slice_count: int,
                             leader_count: int, slice_size: int,
@@ -1472,10 +1547,21 @@ def build_tas_flavor_snapshot(
     # phase-2 tie-break (balanced DP, multilayer descent) host-side
     snap.use_device_fill = features.enabled("TASDeviceFillCounts")
     selector = flavor_node_labels or {}
+    content = []
     for node in nodes:
         if not node.ready:
             continue
         if all(node.labels.get(k) == v for k, v in selector.items()):
             snap.add_node(node)
+            # in the dicts' own order: another order is another key,
+            # which costs a miss and nothing else
+            content.append((
+                node.name, tuple(node.labels.items()),
+                tuple(node.allocatable.items()),
+                tuple((t.key, t.value, t.effect) for t in node.taints)
+                if node.taints else ()))
     snap.initialize()
+    snap.empty_fit = _empty_fit_of((
+        topology_name, tuple(levels), tuple(snap.tolerations),
+        profile_mixed, snap.use_device_fill, tuple(content)))
     return snap

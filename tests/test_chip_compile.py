@@ -167,6 +167,45 @@ def test_pallas_leaf_kernel_lowers_natively_at_640_leaves(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_sequential_placer_with_balanced_compiles_for_one_chip(
+        one_chip, monkeypatch):
+    """The drain's placer for upstream's ``tas`` tree (1 x 10 x 64) with
+    the balanced placement, at its first batch bucket: the scan, the
+    per-row conditionals, the dense subset table and the Pallas leaf
+    pass in one program the chip's compiler takes."""
+    from kueue_oss_tpu.api.types import Node
+    from kueue_oss_tpu.solver import pallas_tas, tas_engine
+    from kueue_oss_tpu.solver.tas_kernels import (
+        build_levels,
+        make_sequential_placer_ext,
+    )
+    from kueue_oss_tpu.tas.snapshot import build_tas_flavor_snapshot
+
+    monkeypatch.setattr(pallas_tas, "use_pallas", lambda: True)
+    monkeypatch.setattr(pallas_tas, "interpret_mode", lambda: False)
+    nodes = [Node(name=f"r{r}-h{h}", labels={"b": "b0", "r": f"r{r}"},
+                  allocatable={"cpu": 96_000})
+             for r in range(10) for h in range(64)]
+    levels = build_levels(build_tas_flavor_snapshot(
+        "default", ["b", "r", "kubernetes.io/hostname"], nodes))
+    placer = make_sequential_placer_ext(
+        levels.parents, levels.ranks, tas_engine.BALANCED_MAX_COUNT)
+    m, r = tas_engine.BUCKETS[0], len(levels.resources)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = placer.lower(
+        arg((640, r), jnp.int32), arg((m, r), jnp.int32),
+        arg((m,), jnp.int32), arg((m,), jnp.int32), arg((m,), jnp.bool_),
+        arg((m,), jnp.bool_), arg((m,), jnp.bool_), arg((m,), jnp.int32),
+        arg((m,), jnp.int32), arg((m, r), jnp.int32), arg((m,), jnp.bool_),
+        arg((m,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text      # the leaf pass is Mosaic's
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
 def test_full_drain_compiles_for_one_chip(one_chip):
     """The preemption drain through the same code path as the flagship
     program, at a width the chip's compiler takes in about ten

@@ -50,6 +50,12 @@ from kueue_oss_tpu.solver.tensors import export_problem
 #: bookkeeping dampens the oscillation); parity on livelock seeds is
 #: asserted as: the kernel terminates AND its terminal admitted
 #: set/flavors is a member of the host's limit cycle.
+#: Since PR 32 a run_until_quiet keeps what a preemptor reserved until
+#: it is its queue's head again (Scheduler._charge_quiet_reservations;
+#: the kernel's ``resv``), so none of the 30 seeds livelocks in
+#: run_host any more and each is held to exact parity; single
+#: ``schedule()`` calls, as the probe below makes them, keep no such
+#: books and still show the cycle.
 LIMIT_CYCLE_PROBE = 12
 
 

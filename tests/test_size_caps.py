@@ -398,20 +398,37 @@ def test_a_drain_counts_the_programs_it_built():
     assert (third.h_max, third.p_max, third.program_builds) == (2, 16, 1)
 
 
-# -- (vi) one width up, where that program is built -------------------------------
+# -- (vi) a built width stands in: one up, or any up to 64 ------------------------
 
 
-def test_built_p_max_takes_the_next_width_up_and_no_further():
+def test_built_p_max_takes_a_built_width_one_up_or_any_up_to_64():
+    """The invariant since PR 32 (PR 31's was "one width up and no
+    further" at every width): a drain that needs ``p_max`` runs the
+    exact program where it is built; else the next width up where that
+    is built; else, ONLY for a need under 64, the narrowest built width
+    up to 64 (a cohort of a few large gangs sizes its drains by 8 to 32
+    rows, and a program each is seconds of building inside a window:
+    upstream-tas); else it builds its own. Above 64 two widths up stay
+    too far, as in PR 31."""
     fk._solver_cache.clear()
     assert fk.built_p_max(1, 4, 16) == 16           # nothing built
     fk.full_solver(1, 4, 32)
     assert fk.built_p_max(1, 4, 16) == 32           # one width up
-    assert fk.built_p_max(1, 4, 8) == 8             # two: its own
+    assert fk.built_p_max(1, 4, 8) == 32            # two up, under 64
+    fk.full_solver(1, 4, 512)
+    assert fk.built_p_max(1, 4, 128) == 128         # two up, over 64: its own
+    assert fk.built_p_max(1, 4, 256) == 512         # one up
+    assert fk.built_p_max(1, 4, 64) == 64           # 128 is not built
     assert fk.built_p_max(1, 4, 32) == 32
     assert fk.built_p_max(1, 8, 16) == 16           # other lanes: other program
     assert fk.built_p_max(1, 4, 16, fs_enabled=True) == 16
     fk.full_solver(1, 4, 16)
     assert fk.built_p_max(1, 4, 16) == 16           # the exact one, once built
+    assert fk.built_p_max(1, 4, 8) == 16            # the narrowest built one
+    fk._solver_cache.clear()
+    fk.full_solver(1, 4, 128)
+    assert fk.built_p_max(1, 4, 8) == 8             # 128 is over 64
+    assert fk.built_p_max(1, 4, 64) == 128          # one up
 
 
 def test_a_shrunken_population_runs_the_program_the_flood_built():
