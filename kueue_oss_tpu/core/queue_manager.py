@@ -20,6 +20,7 @@ from kueue_oss_tpu.core.workload_info import (
     effective_priority,
     queue_order_timestamp,
 )
+from kueue_oss_tpu.obs import spans
 
 
 class RequeueReason:
@@ -42,20 +43,30 @@ class ClusterQueuePendingQueue:
     """Heap + inadmissible parking for one ClusterQueue."""
 
     def __init__(self, name: str, strategy: str,
-                 on_change=None) -> None:
+                 on_change=None, on_owe=None) -> None:
         self.name = name
         self.strategy = strategy
         self._heap: list[tuple[tuple, int, WorkloadInfo]] = []
         self._in_heap: dict[str, WorkloadInfo] = {}
         self._counter = itertools.count()
         self.inadmissible: dict[str, WorkloadInfo] = {}
-        #: cycle at which inadmissible workloads were last re-queued
-        self.queue_inadmissible_cycle = -1
+        #: cycle of the last flush of THIS queue; the cohort's flushes
+        #: that passed it by are read through _cohort_flush
+        self._flush_cycle = -1
+        #: [cycle of the cohort root's last capacity-freed flush]: the
+        #: manager shares one cell among the members of a root
+        self._cohort_flush = [-1]
         self.active = True
         #: called with the CQ name on any pending-count mutation (the
         #: manager uses it to keep a dirty set so metric reporting is
         #: O(changed CQs), not O(all CQs))
         self._on_change = on_change or (lambda name: None)
+        #: called with the CQ name when the queue starts to owe a
+        #: capacity-freed flush: a row was parked, or a class became
+        #: NoFit, since its last one (the manager keeps the owing set
+        #: per cohort root and flushes only its members)
+        self._on_owe = on_owe or (lambda name: None)
+        self.owes_flush = False
         #: admission-fair-sharing rank fn (info -> decayed LQ usage);
         #: set by the manager for CQs with UsageBasedAdmissionFairSharing
         self.afs_key = None
@@ -84,6 +95,18 @@ class ClusterQueuePendingQueue:
         self.pending_totals: dict[str, int] = {}
 
     _HEAP, _INADM = 1, 2
+
+    @property
+    def queue_inadmissible_cycle(self) -> int:
+        """Cycle at which inadmissible workloads were last re-queued: by
+        a flush of this queue, or by a flush of its cohort that found
+        nothing owed here and passed it by."""
+        return max(self._flush_cycle, self._cohort_flush[0])
+
+    def _owe(self) -> None:
+        if not self.owes_flush:
+            self.owes_flush = True
+            self._on_owe(self.name)
 
     def _hx(self, key: str, state: int) -> None:
         self.state_hash ^= hash((key, state))
@@ -129,6 +152,7 @@ class ClusterQueuePendingQueue:
                 self._hx(info.key, self._INADM)
             self.inadmissible[info.key] = info
             self._stale_pop(info.key)  # updated shape => freshly parked
+            self._owe()
             self._on_change(self.name)
             return
         if info.key in self.inadmissible:
@@ -200,9 +224,11 @@ class ClusterQueuePendingQueue:
             self.delete(key)
             self.inadmissible[key] = info
             self._hx(key, self._INADM)
+            self._owe()
             self._on_change(self.name)
         elif key in self.inadmissible:
             self._stale_pop(key)
+            self._owe()
 
     def requeue_if_not_present(self, info: WorkloadInfo, reason: str,
                                pop_cycle: int = -1) -> bool:
@@ -225,6 +251,7 @@ class ClusterQueuePendingQueue:
             return True
         self.inadmissible[info.key] = info
         self._hx(info.key, self._INADM)
+        self._owe()
         self._on_change(self.name)
         self._handle_inadmissible_hash(info)
         return False
@@ -241,6 +268,7 @@ class ClusterQueuePendingQueue:
             return
         h = info.scheduling_hash()
         self.no_fit_hashes.add(h)
+        self._owe()
         equivalent = [k for k, i in self._in_heap.items()
                       if i.scheduling_hash() == h]
         for k in equivalent:
@@ -256,8 +284,9 @@ class ClusterQueuePendingQueue:
         pushes. The solver exports stale entries as pending; the host
         path materializes them first (materialize_stale)."""
         self.no_fit_hashes.clear()
+        self.owes_flush = False
+        self._flush_cycle = cycle
         if self.lazy_flush:
-            self.queue_inadmissible_cycle = cycle
             if not self.inadmissible:
                 return False
             changed = False
@@ -270,7 +299,6 @@ class ClusterQueuePendingQueue:
                 self._on_change(self.name)
             return True
         if not self.inadmissible:
-            self.queue_inadmissible_cycle = cycle
             return False
         parked = list(self.inadmissible.values())
         self.inadmissible.clear()
@@ -278,7 +306,6 @@ class ClusterQueuePendingQueue:
             self._stale_pop(info.key)
             self._hx(info.key, self._INADM)
             self.push(info)
-        self.queue_inadmissible_cycle = cycle
         self._on_change(self.name)
         return True
 
@@ -333,6 +360,16 @@ class QueueManager:
         #: the 1s -> 30s exponential backoff
         self._second_pass_heap: list[tuple[float, str]] = []
         self._second_pass_iteration: dict[str, int] = {}
+        #: ClusterQueue name -> its cohort forest's root (a ClusterQueue
+        #: outside any cohort is a root of its own: ``(name,)``); None
+        #: while a ClusterQueue or Cohort event waits to be read
+        #: (_cohort_index builds it, and the two below, on first use)
+        self._root_of: Optional[dict] = None
+        #: root -> the member queues that owe a capacity-freed flush
+        #: (names as dict keys: a set that keeps its order)
+        self._owing: dict = {}
+        #: root -> [cycle of its last flush], shared with its queues
+        self._root_flush: dict = {}
         for cq in store.cluster_queues.values():
             self.add_cluster_queue(cq.name)
         # Initial LIST: enqueue pending workloads already in the store
@@ -378,10 +415,11 @@ class QueueManager:
 
     def add_cluster_queue(self, name: str) -> None:
         spec = self.store.cluster_queues[name]
+        self._root_of = None  # a new member, or one in another cohort
         if name not in self.queues:
             self.queues[name] = ClusterQueuePendingQueue(
                 name, spec.queueing_strategy,
-                on_change=self.dirty_cqs.add)
+                on_change=self.dirty_cqs.add, on_owe=self._on_owe)
             self.queues[name].lazy_flush = self.lazy_flush
         q = self.queues[name]
         q.strategy = spec.queueing_strategy
@@ -407,12 +445,15 @@ class QueueManager:
         verb, kind, obj = event
         if kind == "ClusterQueue":
             if verb == "delete":
+                self._root_of = None
                 q = self.queues.pop(obj.name, None)
                 if q is not None:
                     self.dirty_cqs.add(obj.name)
                 return
             self.add_cluster_queue(obj.name)
             self.queues[obj.name].queue_inadmissible(self.cycle)
+        elif kind == "Cohort":
+            self._root_of = None  # a parent may have changed
         elif kind == "LocalQueue":
             # list(...) snapshots: watchers run outside Store._lock, so a
             # concurrent add_workload may mutate the dict mid-iteration
@@ -634,46 +675,95 @@ class QueueManager:
 
     # -- capacity-freed events ---------------------------------------------
 
-    def _cohort_members(self, cq_name: str) -> Iterable[str]:
-        spec = self.store.cluster_queues.get(cq_name)
-        if spec is None or not spec.cohort:
-            return [cq_name]
-        # All CQs sharing the cohort forest root with cq_name.
+    def _cohort_index(self) -> dict:
+        """ClusterQueue name -> cohort root, built from the store's specs
+        after a ClusterQueue or Cohort event and kept until the next."""
+        if self._root_of is not None:
+            return self._root_of
+        cohorts = self.store.cohorts
         roots: dict[str, str] = {}
 
-        def root_of(cohort_name: str, seen=None) -> str:
-            if cohort_name in roots:
-                return roots[cohort_name]
-            seen = seen or set()
+        def root_of(cohort_name: str) -> str:
+            path = []
             cur = cohort_name
-            while True:
-                if cur in seen:
-                    break
-                seen.add(cur)
-                spec_c = self.store.cohorts.get(cur)
+            while cur not in roots and cur not in path:
+                path.append(cur)
+                spec_c = cohorts.get(cur)
                 if spec_c is None or not spec_c.parent:
                     break
                 cur = spec_c.parent
-            roots[cohort_name] = cur
-            return cur
+            if cur in roots:
+                at, root = len(path), roots[cur]
+            else:
+                # the top of the tree; or where a parent cycle closes,
+                # and each cohort on a cycle is a root, as a walk that
+                # starts there ends there
+                at, root = path.index(cur), cur
+            for name in path[:at]:
+                roots[name] = root
+            for name in path[at:]:
+                roots[name] = name
+            return roots[cohort_name]
 
-        my_root = root_of(spec.cohort)
-        return [
-            name for name, other in list(self.store.cluster_queues.items())
-            if other.cohort and root_of(other.cohort) == my_root
-        ]
+        index = {name: root_of(spec.cohort) if spec.cohort else (name,)
+                 for name, spec in list(self.store.cluster_queues.items())}
+        owing: dict = {}
+        cells: dict = {}
+        for name, q in self.queues.items():
+            root = index.get(name, (name,))
+            # what the queue read of its old root stays its own
+            q._flush_cycle = q.queue_inadmissible_cycle
+            q._cohort_flush = cells.setdefault(root, [-1])
+            if q.owes_flush:
+                owing.setdefault(root, {})[name] = None
+        self._owing, self._root_flush = owing, cells
+        self._root_of = index
+        return index
+
+    def _on_owe(self, name: str) -> None:
+        # the engine parks rows without the manager's lock; a flush that
+        # walks the set holds it
+        with self._mu:
+            # while the index waits to be built, the queue's flag is
+            # enough: _cohort_index reads it
+            if self._root_of is not None:
+                self._owing.setdefault(
+                    self._root_of.get(name, (name,)), {})[name] = None
+
+    def _cohort_members(self, cq_name: str) -> Iterable[str]:
+        """All CQs sharing the cohort forest root with cq_name."""
+        index = self._cohort_index()
+        root = index.get(cq_name)
+        if root is None:
+            return [cq_name]
+        return [name for name, r in index.items() if r == root]
 
     def flush_cohort_for(self, cq_name: str) -> None:
         """Re-queue inadmissible workloads across the whole cohort.
 
         Called when capacity may have freed (workload finished/evicted) —
-        reference: QueueAssociatedInadmissibleWorkloadsAfter.
+        reference: QueueAssociatedInadmissibleWorkloadsAfter. Only the
+        members that owe a flush are visited (a fresh parked row or a
+        NoFit class since their last one); the others read the flush's
+        cycle from the root (queue_inadmissible_cycle).
         """
         with self._mu:
-            for member in self._cohort_members(cq_name):
+            root = self._cohort_index().get(cq_name, (cq_name,))
+            visited = rows = 0
+            for member in self._owing.pop(root, ()):
                 q = self.queues.get(member)
                 if q is not None:
+                    visited += 1
+                    rows += len(q.inadmissible) - (
+                        len(q._stale) if q.lazy_flush else 0)
                     q.queue_inadmissible(self.cycle)
+            if visited:
+                spans.count("flush_queues", visited)
+                spans.count("flush_rows", rows)
+            cell = self._root_flush.get(root)
+            if cell is not None:  # None: a root no queue of ours is under
+                cell[0] = self.cycle
+            spans.count("flush_requests")
             self._cond.notify_all()
 
     def report_workload_finished(self, wl: Workload) -> None:

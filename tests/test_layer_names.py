@@ -140,6 +140,12 @@ def test_guard_fails_on_a_renamed_span_phase_or_scope():
     assert len(layers) >= 29
     for kind, gone, metric in (("timed", "apply.commit", "apply_commit_share"),
                                ("counted", "retrace_s", "retrace_share"),
+                               ("counted", "flush_queues",
+                                "flush_queues_per_request"),
+                               ("counted", "flush_requests",
+                                "flush_queues_per_request.stream"),
+                               ("counted", "flush_rows",
+                                "flush_rows_per_request"),
                                ("phases", "device_put",
                                 "encode_put_s_per_pass"),
                                ("scopes", "classical_search",
