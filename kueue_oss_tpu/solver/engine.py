@@ -60,6 +60,11 @@ class DrainResult:
     #: wire carries the plan alone
     search_lanes: int = 0
     search_live_lanes: int = 0
+    #: the entry scan's victim branch over the drain's rounds
+    #: (full_kernels.full_round_scan): active entries scanned, and
+    #: entries with victims to book; 0 where the search counts are
+    scan_entries: int = 0
+    scan_victim_entries: int = 0
     #: the static caps of the program a full drain ran (_size_caps; a
     #: local drain may run one candidate width up, _full_program) and
     #: the preemption programs it had to build (full_kernels.full_solver
@@ -663,6 +668,8 @@ class SolverEngine:
             grant_wait_ms=grant_wait_ms, device=device,
             detail=({"searchLanes": result.search_lanes,
                      "searchLiveLanes": result.search_live_lanes,
+                     "scanEntries": result.scan_entries,
+                     "scanVictimEntries": result.scan_victim_entries,
                      "programBuilds": result.program_builds,
                      "hMax": result.h_max, "pMax": result.p_max}
                     if result.p_max else None))
@@ -1605,15 +1612,19 @@ class SolverEngine:
 
                 builds0 = solver_builds()
                 # the program's own return (full_kernels.full_solver):
-                # the plan, then the liveness gate's two counts
+                # the plan, then the liveness gate's two counts and the
+                # entry scan's two
                 (admitted, opt, admit_round, parked, rounds, _usage,
                  _wl_usage, victim_reason, search_lanes,
-                 search_live_lanes) = self._local_solve(
+                 search_live_lanes, scan_entries,
+                 scan_victim_entries) = self._local_solve(
                     problem, frame, full=True, n_live=n_live,
                     g_max=g_max, h_max=h_max, p_max=p_max,
                     fs_enabled=self.enable_fair_sharing)
                 result.search_lanes = int(search_lanes)
                 result.search_live_lanes = int(search_live_lanes)
+                result.scan_entries = int(scan_entries)
+                result.scan_victim_entries = int(scan_victim_entries)
                 result.program_builds = solver_builds() - builds0
                 result.p_max = self.last_p_max
             admitted = np.asarray(admitted)
@@ -1639,6 +1650,8 @@ class SolverEngine:
         spans.count("drain_admitted", result.admitted)
         spans.count("search_lanes", result.search_lanes)
         spans.count("search_live_lanes", result.search_live_lanes)
+        spans.count("scan_entries", result.scan_entries)
+        spans.count("scan_victim_entries", result.scan_victim_entries)
         spans.count("solver_program_builds", result.program_builds)
         W = problem.n_workloads
         with spans.span("record"):

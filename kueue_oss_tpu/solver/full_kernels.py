@@ -975,11 +975,15 @@ def full_round_scan(t: FullTensors, state, cand_w, mode, k_chosen, req_c,
     active = (cand_w != W_null) & (mode != M_NOFIT)
     sort_borrow = jnp.where(active, borrow, BIG)
     order = jnp.lexsort((uid, ts_o, -prio, sort_borrow))
+    # the entries that issue preemptions or are refused them: a
+    # Preempt-mode entry whose lane's search found targets
+    victim_entry = (active & (mode == M_PREEMPT) & (lane_of_entry >= 0)
+                    & lane_success[jnp.maximum(lane_of_entry, 0)])
 
     def step(carry, slot):
         (usage_full, usage_net, cq_rows, admitted, parked, wl_usage,
          victims_all, victim_reason, lq_pen, any_adm, any_evict) = carry
-        w, cqid, m, req, brw, lane = slot
+        w, cqid, m, req, brw, lane, is_victim_entry = slot
         cq_node = t.cq_node[jnp.minimum(cqid, C - 1)]
         is_active = (w != W_null) & (m != M_NOFIT)
         searched = lane >= 0
@@ -996,22 +1000,28 @@ def full_round_scan(t: FullTensors, state, cand_w, mode, k_chosen, req_c,
         parked = parked.at[w].set(
             parked[w] | (is_reserve & ~t.cq_strict[jnp.minimum(cqid, C - 1)]))
 
-        # --- overlap check (one conflicting preemption per cycle) --------
-        vm = lane_victims[lane_i]                       # [P]
-        vw = lane_cand_w[lane_i]                        # [P]
-        overlap = jnp.any(vm & victims_all[vw])
-        is_preempt = is_active & (m == M_PREEMPT) & has_targets & ~overlap
+        # --- issue preemptions (scheduler.go issuePreemptions) -----------
+        # only an entry with targets has victims to book: every other
+        # entry skips the P-wide gathers and scatters below, which would
+        # leave the carry as it is (their mask is all-false there)
+        def preempt(ops):
+            (usage_full, usage_net, cq_rows, admitted, victims_all,
+             victim_reason) = ops
+            # overlap check (one conflicting preemption per cycle)
+            vm = lane_victims[lane_i]                   # [P]
+            vw = lane_cand_w[lane_i]                    # [P]
+            overlap = jnp.any(vm & victims_all[vw])
 
-        # --- fits re-check under removal of own targets (the preempted
-        # set is already excluded from usage_net by earlier steps); the
-        # loop is bounded by the lane's last victim slot, not p_max ------
-        n_slots = jnp.max(jnp.where(
-            vm, jnp.arange(p_max, dtype=jnp.int32) + 1, 0))
+            # fits re-check under removal of own targets (the preempted
+            # set is already excluded from usage_net by earlier steps);
+            # the loop is bounded by the lane's last victim slot, not
+            # p_max
+            n_slots = jnp.max(jnp.where(
+                vm, jnp.arange(p_max, dtype=jnp.int32) + 1, 0))
 
-        def remove_victims(u, flag):
             def rv_cond(carry):
                 _, i = carry
-                return flag & (i < n_slots)
+                return ~overlap & (i < n_slots)
 
             def rv_body(carry):
                 u_c, i = carry
@@ -1021,34 +1031,45 @@ def full_round_scan(t: FullTensors, state, cand_w, mode, k_chosen, req_c,
                 return (_remove_usage_along_path(t, u_c, a_node, row),
                         i + 1)
 
-            u, _ = jax.lax.while_loop(
-                rv_cond, rv_body, (u, jnp.zeros((), dtype=jnp.int32)))
-            return u
+            usage_probe, _ = jax.lax.while_loop(
+                rv_cond, rv_body,
+                (usage_net, jnp.zeros((), dtype=jnp.int32)))
+            avail_now = _avail_along_path(t, usage_probe, cq_node)
+            still_fits = jnp.all((req == 0) | (req <= avail_now))
 
-        usage_probe = remove_victims(usage_net, is_preempt)
-        avail_now = _avail_along_path(t, usage_probe, cq_node)
-        still_fits = jnp.all((req == 0) | (req <= avail_now))
+            do_preempt = ~overlap & still_fits
+            usage_net = jnp.where(do_preempt, usage_probe, usage_net)
+            evict_now = do_preempt & vm                 # [P]
+            victims_all = victims_all.at[vw].max(evict_now, mode="drop")
+            victims_all = victims_all.at[W_null].set(False)
+            # record each victim's candidate variant (preemption reason)
+            victim_reason = victim_reason.at[vw].max(
+                jnp.where(evict_now, lane_reason[lane_i], 0), mode="drop")
+            victim_reason = victim_reason.at[W_null].set(0)
+            admitted = admitted.at[vw].min(~evict_now, mode="drop")
+            # durable rows: victims' usage leaves their CQ row (P-sized
+            # scatter)
+            v_nodes = t.cq_node[jnp.minimum(t.wl_cqid[vw], C - 1)]
+            cq_rows = cq_rows.at[v_nodes].add(
+                -jnp.where(evict_now[:, None], wl_usage[vw], 0),
+                mode="drop")
+            # the preemptor charges its assignment usage for the rest of
+            # the round (scheduler.go:434 cq.add_usage before
+            # issuePreemptions)
+            entry_usage = jnp.where(do_preempt, req, 0)
+            usage_full = _add_usage_along_path(
+                t, usage_full, cq_node, entry_usage)
+            usage_net = _add_usage_along_path(
+                t, usage_net, cq_node, entry_usage)
+            return (usage_full, usage_net, cq_rows, admitted, victims_all,
+                    victim_reason, do_preempt)
 
-        # --- issue preemptions (scheduler.go issuePreemptions) -----------
-        do_preempt = is_preempt & still_fits
-        usage_net = jnp.where(do_preempt, usage_probe, usage_net)
-        evict_now = do_preempt & vm                     # [P]
-        victims_all = victims_all.at[vw].max(evict_now, mode="drop")
-        victims_all = victims_all.at[W_null].set(False)
-        # record each victim's candidate variant (preemption reason)
-        victim_reason = victim_reason.at[vw].max(
-            jnp.where(evict_now, lane_reason[lane_i], 0), mode="drop")
-        victim_reason = victim_reason.at[W_null].set(0)
-        admitted = admitted.at[vw].min(~evict_now, mode="drop")
-        # durable rows: victims' usage leaves their CQ row (P-sized scatter)
-        v_nodes = t.cq_node[jnp.minimum(t.wl_cqid[vw], C - 1)]
-        cq_rows = cq_rows.at[v_nodes].add(
-            -jnp.where(evict_now[:, None], wl_usage[vw], 0), mode="drop")
-        # the preemptor charges its assignment usage for the rest of the
-        # round (scheduler.go:434 cq.add_usage before issuePreemptions)
-        entry_usage = jnp.where(do_preempt, req, 0)
-        usage_full = _add_usage_along_path(t, usage_full, cq_node, entry_usage)
-        usage_net = _add_usage_along_path(t, usage_net, cq_node, entry_usage)
+        (usage_full, usage_net, cq_rows, admitted, victims_all,
+         victim_reason, do_preempt) = jax.lax.cond(
+            is_victim_entry, preempt,
+            lambda ops: (*ops, jnp.zeros((), dtype=bool)),
+            (usage_full, usage_net, cq_rows, admitted, victims_all,
+             victim_reason))
         any_evict = any_evict | do_preempt
 
         # --- Fit: re-check then admit ------------------------------------
@@ -1081,7 +1102,7 @@ def full_round_scan(t: FullTensors, state, cand_w, mode, k_chosen, req_c,
     if not fs_enabled:
         slots = (cand_w[order], jnp.arange(C, dtype=jnp.int32)[order],
                  mode[order], req_c[order], borrow[order],
-                 lane_of_entry[order])
+                 lane_of_entry[order], victim_entry[order])
         (usage_full, usage_net, cq_rows, admitted, parked, wl_usage,
          victims_all, victim_reason, lq_pen, any_adm, any_evict), (
             admitted_slot, preempted_slot) = (
@@ -1103,7 +1124,7 @@ def full_round_scan(t: FullTensors, state, cand_w, mode, k_chosen, req_c,
                                 req_c, state["ts"], act)
             ec = jnp.minimum(e, C - 1)
             slot = (cand_w[ec], ec, mode[ec], req_c[ec], borrow[ec],
-                    lane_of_entry[ec])
+                    lane_of_entry[ec], victim_entry[ec])
             inner2, (da, dp) = step(inner, slot)
             picked = e < C
             inner = jax.tree_util.tree_map(
@@ -1126,6 +1147,9 @@ def full_round_scan(t: FullTensors, state, cand_w, mode, k_chosen, req_c,
         "cq_rows": cq_rows, "admitted": admitted, "parked": parked,
         "wl_usage": wl_usage, "victims_all": victims_all,
         "victim_reason": victim_reason, "lq_penalty": lq_pen,
+        # every active entry is scanned once, on both paths
+        "entries": jnp.sum(active.astype(jnp.int32)),
+        "victim_entries": jnp.sum(victim_entry.astype(jnp.int32)),
     }, adm_entry, pre_entry, any_adm, any_evict
 
 
@@ -1310,6 +1334,27 @@ def _run_searches(t, usage, wl_usage, admitted, evicted, ts,
     return out, n_lanes
 
 
+@jax.named_scope("compact_victims")
+def _compact_victims(lane_cand_w, lane_victims, lane_reason):
+    """Each lane's victims moved to the front of its slot axis, stably.
+
+    The entry scan's removal loop runs `last victim slot + 1`
+    iterations, and a victim sitting at slot 3000 of a long candidate
+    list would turn it into thousands of sequential steps per entry.
+    With no victim in any lane every key is ``p_max`` and the stable
+    sort is the identity, so a round without victims skips it."""
+    p_max = lane_victims.shape[1]
+
+    def compact(vw_row, vm_row, re_row):
+        key = jnp.where(vm_row, jnp.arange(p_max, dtype=jnp.int32), p_max)
+        order = jnp.argsort(key)
+        return vw_row[order], vm_row[order], re_row[order]
+
+    return jax.lax.cond(
+        jnp.any(lane_victims), lambda ops: jax.vmap(compact)(*ops),
+        lambda ops: ops, (lane_cand_w, lane_victims, lane_reason))
+
+
 @jax.named_scope("round_body")
 def round_body(t: FullTensors, state, pot, g_max: int, h_max: int,
                p_max: int, fs_enabled: bool = False, lendable_r=None,
@@ -1466,18 +1511,8 @@ def round_body(t: FullTensors, state, pot, g_max: int, h_max: int,
         lanes_offered += h_max
     lane_success = (lane_success & lane_valid & (l_mode == M_PREEMPT))
 
-    # compact victims to the front of each lane's slot axis: the entry
-    # scan's removal loops run `last victim slot + 1` iterations, and a
-    # victim sitting at slot 3000 of a long candidate list would turn
-    # them into thousands of sequential steps per entry
-    def _compact(vw_row, vm_row, re_row):
-        key = jnp.where(vm_row, jnp.arange(p_max, dtype=jnp.int32), p_max)
-        order = jnp.argsort(key)
-        return vw_row[order], vm_row[order], re_row[order]
-
-    with jax.named_scope("compact_victims"):
-        lane_cand_w, lane_victims, lane_reason = jax.vmap(_compact)(
-            lane_cand_w, lane_victims, lane_reason)
+    lane_cand_w, lane_victims, lane_reason = _compact_victims(
+        lane_cand_w, lane_victims, lane_reason)
 
     # park NoFit heads of BestEffortFIFO queues (post-walk modes)
     park_now = is_head & (mode == M_NOFIT) & ~t.cq_strict
@@ -1584,10 +1619,16 @@ def round_body(t: FullTensors, state, pot, g_max: int, h_max: int,
         # offered to the victim search, and lanes that ran its stage 2
         "search_lanes": state["search_lanes"] + lanes_offered,
         "search_live_lanes": state["search_live_lanes"] + lanes_run,
+        # how often the entry scan's victim branch engages
+        # (full_round_scan): active entries scanned, and entries that
+        # took it
+        "scan_entries": state["scan_entries"] + out["entries"],
+        "scan_victim_entries": (state["scan_victim_entries"]
+                                + out["victim_entries"]),
     }
     debug = {
         "cand_w": cand_w, "mode": mode, "req_c": req_c,
-        "victims": victims, "adm_entry": adm_entry,
+        "victims": victims, "adm_entry": adm_entry, "pre_entry": pre_entry,
         "lane_w": lane_w, "lane_success": lane_success,
         "lane_cand_w": lane_cand_w, "lane_victims": lane_victims,
     }
@@ -1617,6 +1658,8 @@ def _init_state(t: FullTensors, g_max: int):
         "rounds": jnp.zeros((), dtype=jnp.int32),
         "search_lanes": jnp.zeros((), dtype=jnp.int32),
         "search_live_lanes": jnp.zeros((), dtype=jnp.int32),
+        "scan_entries": jnp.zeros((), dtype=jnp.int32),
+        "scan_victim_entries": jnp.zeros((), dtype=jnp.int32),
     }
 
 
@@ -1657,7 +1700,8 @@ def _solve_full_impl(t: FullTensors, g_max: int, h_max: int, p_max: int,
     return (admitted, final["opt"], final["admit_round"], parked,
             final["rounds"], final["usage"], final["wl_usage"],
             final["victim_reason"], final["search_lanes"],
-            final["search_live_lanes"])
+            final["search_live_lanes"], final["scan_entries"],
+            final["scan_victim_entries"])
 
 
 def lane_work_budget() -> int:
@@ -1775,10 +1819,11 @@ def full_solver(g_max: int, h_max: int = 32, p_max: int = 128,
                 fs_enabled: bool = False, mesh=None, axis: str = "wl"):
     """The cached jitted drain for static caps; (g_max, h_max, p_max,
     fs) are compile-time. Called on the tensors it returns the plan's
-    eight arrays (:func:`solve_backlog_full`) and, after them, the two
-    int32 sums of the victim search's liveness gate over the drain's
-    rounds: lanes offered, and lanes that ran the heavy search
-    (:func:`_run_searches`).
+    eight arrays (:func:`solve_backlog_full`) and, after them, four
+    int32 sums over the drain's rounds: of the victim search's liveness
+    gate, lanes offered and lanes that ran the heavy search
+    (:func:`_run_searches`); of the entry scan, active entries and
+    entries that took its victim branch (:func:`full_round_scan`).
 
     With a ``mesh``, the victim-search lanes shard across its devices
     (_run_searches)."""
@@ -1870,8 +1915,8 @@ def solve_backlog_full_batched(t: FullTensors, overrides: dict,
     ``overrides`` maps FullTensors field names to stacked [S, ...]
     scenario variants; unnamed fields broadcast unbatched (the large
     ``wl_req`` tensor on quota-only sweeps costs one copy, not S).
-    Returns the solve_backlog_full 8-tuple and the two search counts
-    that follow it (:func:`full_solver`), with a leading scenario axis
+    Returns the solve_backlog_full 8-tuple and the four counts that
+    follow it (:func:`full_solver`), with a leading scenario axis
     on every output. The victim-search lane memory scales as
     S x h_max x K x p_max — callers size S from a
     :class:`~kueue_oss_tpu.sim.batch.LaneBudget`, not from the sweep

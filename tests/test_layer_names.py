@@ -146,6 +146,10 @@ def test_guard_fails_on_a_renamed_span_phase_or_scope():
                                 "flush_queues_per_request.stream"),
                                ("counted", "flush_rows",
                                 "flush_rows_per_request"),
+                               ("counted", "scan_victim_entries",
+                                "scan_victim_entry_share"),
+                               ("counted", "scan_entries",
+                                "scan_victim_entry_share"),
                                ("phases", "device_put",
                                 "encode_put_s_per_pass"),
                                ("scopes", "classical_search",

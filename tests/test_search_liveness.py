@@ -265,7 +265,7 @@ def test_whole_drain_and_its_counts(seed, chunk, monkeypatch):
     jax.clear_caches()
     monkeypatch.setattr(fk, "_gated_searches", _ungated)
     plain = _drain(problem, g_max, H_MAX, 32)
-    assert len(gated) == len(plain) == 10
+    assert len(gated) == len(plain) == 12
     for i in range(8):
         assert gated[i].tobytes() == plain[i].tobytes(), i
     rounds = int(gated[4])
@@ -316,6 +316,8 @@ def test_engine_counts_the_drains_lanes(monkeypatch):
     row = obs.cycle_ledger.last_row(obs.SOLVER_DRAIN)
     assert row.detail == {"searchLanes": result.search_lanes,
                           "searchLiveLanes": result.search_live_lanes,
+                          "scanEntries": result.scan_entries,
+                          "scanVictimEntries": result.scan_victim_entries,
                           "programBuilds": result.program_builds,
                           "hMax": result.h_max, "pMax": result.p_max}
 
