@@ -133,7 +133,8 @@ class FlightRecorder:
     path, and eviction flows — possibly from different threads (the
     serve loop vs controller callbacks), so every mutation holds the
     lock. Recording is cheap (one dataclass + two deque appends + a
-    counter inc); ``enabled = False`` reduces it to one attribute read.
+    counter inc), and its seconds are the span totals ``recorder``;
+    ``enabled = False`` reduces it to one attribute read.
     """
 
     def __init__(self, max_events: int = 65_536, per_workload: int = 64,
@@ -163,6 +164,7 @@ class FlightRecorder:
         incident keeps its breaker tags."""
         if not self.enabled:
             return None
+        t0 = spans.start()   # per event: totals only (``recorder``)
         if breaker is None:
             breaker = breaker_state_name()
         ev = DecisionEvent(
@@ -185,6 +187,7 @@ class FlightRecorder:
         metrics.decision_events_total.inc(kind)
         if kind in (SKIPPED, SOLVER_FALLBACK) and reason_slug:
             metrics.decision_skips_total.inc(reason_slug)
+        spans.add_since("recorder", t0)
         return ev
 
     # -- queries -----------------------------------------------------------
@@ -365,7 +368,7 @@ from kueue_oss_tpu.obs.ledger import (  # noqa: E402
 )
 from kueue_oss_tpu.obs.ledger import ledger as cycle_ledger  # noqa: E402
 from kueue_oss_tpu.obs import devtel  # noqa: E402
-from kueue_oss_tpu.obs import spans  # noqa: E402,F401
+from kueue_oss_tpu.obs import spans  # noqa: E402
 from kueue_oss_tpu.obs.devtel import (  # noqa: E402
     CompileDetector,
     DeepCapture,

@@ -39,10 +39,18 @@ included (``compiles``, ``compile_s``), by span name.
 Per-event work (``finish_workload``, the store's add) gets totals only:
 ``t0 = spans.start()`` ... ``spans.add_since(name, t0)``: two clock
 reads, no object, no annotation.
+
+One ``gc.callbacks`` hook, for the process, times the garbage
+collector the same way: every collection's seconds to the totals
+``gc``, a full (generation 2) one's to ``gc.full`` as well, whatever
+span it fell in (that span's self time keeps them); while the switch is
+on a generation 1 or 2 collection also lies inside a ``kueue:gc``
+annotation, so a full collection sits on the device trace's clock.
 """
 
 from __future__ import annotations
 
+import gc
 import sys
 import threading
 import time
@@ -80,6 +88,10 @@ class _State:
         self.jax_events: dict = {}
         self.annotation = None         # jax.profiler.TraceAnnotation
         self.listening = False
+        #: the collection under way: its start (0: untimed) and its
+        #: open ``kueue:gc`` annotation
+        self.gc_t0 = 0
+        self.gc_ann = None
         #: reentrant: a dying sink's weakref callback may fire inside
         #: a section that already holds it
         self.lock = threading.RLock()
@@ -222,7 +234,8 @@ def start() -> int:
 
 def add_since(name: str, t0: int) -> None:
     if t0:
-        add(name, (now() - t0) * 1e-9)
+        ns = now() - t0
+        _bump(name, ns, 1, ns)
 
 
 def add(name: str, seconds: float, n: int = 1) -> None:
@@ -392,3 +405,36 @@ def _on_jax_duration(event: str, secs: float, **_kw) -> None:
         rec[2] += ns
     else:
         rec[0] += ns
+
+
+# -- the garbage collector ------------------------------------------------------
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks``: totals ``gc`` and, for a full collection,
+    ``gc.full``; a ``kueue:gc`` annotation around a collection of
+    generation 1 or 2 while the switch is on. A collection's start and
+    stop come on one thread, and collections do not nest."""
+    if phase == "start":
+        if not (_ledger.enabled or _S.on):
+            return
+        if _S.on and info["generation"] and _S.annotation is not None:
+            _S.gc_ann = _S.annotation(TRACE_PREFIX + "gc")
+            _S.gc_ann.__enter__()
+        _S.gc_t0 = now()
+        return
+    t0 = _S.gc_t0
+    if not t0:
+        return
+    ns = now() - t0
+    _S.gc_t0 = 0
+    ann, _S.gc_ann = _S.gc_ann, None
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    _bump("gc", ns, 1, ns)
+    if info["generation"] == 2:
+        _bump("gc.full", ns, 1, ns)
+
+
+# one hook for the process, that does nothing while nothing records
+gc.callbacks.append(_on_gc)

@@ -188,7 +188,6 @@ class Scheduler:
         self.admitted_total: dict[str, int] = {}
         self.preempted_total: dict[str, int] = {}
         self.evicted_total: dict[str, int] = {}
-        self.admission_attempt_durations: list[float] = []
         #: in-flight preemption tracking (pkg/util/expectations)
         from kueue_oss_tpu.util.expectations import ExpectationsStore
 
@@ -266,11 +265,13 @@ class Scheduler:
                 self._process_entry(iterator.pop(), snapshot,
                                     preempted_workloads, stats, now)
 
+            t0 = spans.start()   # the parts of a cycle: totals only
             for e in entries:
                 if e.status not in (ASSUMED, EVICTED):
                     self._requeue_and_update(e)
             for e in inadmissible:
                 self._requeue_and_update(e)
+            spans.add_since("entries.requeue", t0)
 
         stats.duration_s = self.clock() - start
         if stats.admitted:
@@ -287,7 +288,6 @@ class Scheduler:
                       preempted=stats.preempted,
                       inadmissible=stats.inadmissible,
                       duration_s=round(stats.duration_s, 6))
-        self.admission_attempt_durations.append(stats.duration_s)
         result = (metrics.CycleResult.SUCCESS if stats.admitted or stats.preempted
                   else metrics.CycleResult.INADMISSIBLE)
         metrics.observe_admission_attempt(result, stats.duration_s)
@@ -977,18 +977,28 @@ class Scheduler:
         return self._assign(info, snapshot, now, defer_tas=True)
 
     def _assign(self, info: WorkloadInfo, snapshot: Snapshot, now: float,
-                defer_tas: bool = False) -> tuple[Assignment, list[Target]]:
+                defer_tas: bool = False, nominating: bool = True,
+                ) -> tuple[Assignment, list[Target]]:
+        """Flavors, then preemption targets where they are needed, then
+        partial admission. While ``nominating``, the flavor assignments
+        are timed as the totals ``nominate.assign`` and the target
+        searches as ``nominate.targets``; an entry assigned again in the
+        entry pass (``_place_deferred``) is left to ``entries``."""
         cq = snapshot.cluster_queue(info.cluster_queue)
         assert cq is not None
         assigner = FlavorAssigner(
             info, cq, snapshot.resource_flavors, oracle=self.preemptor,
             enable_fair_sharing=self.enable_fair_sharing)
+        t0 = spans.start() if nominating else 0
         full = assigner.assign(defer_tas=defer_tas)
+        spans.add_since("nominate.assign", t0)
         mode = full.representative_mode()
         if mode == fa.FIT:
             return full, []
         if mode == fa.PREEMPT:
+            t0 = spans.start() if nominating else 0
             targets = self.preemptor.get_targets(info, full, snapshot, now)
+            spans.add_since("nominate.targets", t0)
             if targets:
                 if defer_tas:
                     full.tas_after_targets = True
@@ -1003,12 +1013,16 @@ class Scheduler:
                 and features.enabled("PartialAdmission")
                 and info.can_be_partially_admitted()):
             def probe(counts):
+                t0 = spans.start() if nominating else 0
                 assignment = assigner.assign(counts)
+                spans.add_since("nominate.assign", t0)
                 m = assignment.representative_mode()
                 if m == fa.FIT:
                     return (assignment, []), True
                 if m == fa.PREEMPT:
+                    t0 = spans.start() if nominating else 0
                     t = self.preemptor.get_targets(info, assignment, snapshot, now)
+                    spans.add_since("nominate.targets", t0)
                     if t:
                         return (assignment, t), True
                 return None, False
@@ -1228,7 +1242,9 @@ class Scheduler:
         if not self._delays_topology(e):
             self._assume_tas_usage(e, snapshot)
         e.status = NOMINATED
+        t0 = spans.start()
         self._admit(e, now)
+        spans.add_since("entries.admit", t0)
         stats.admitted += 1
 
     def _place_deferred(self, e: Entry, snapshot: Snapshot,
@@ -1262,7 +1278,7 @@ class Scheduler:
             fa.update_for_tas_result(e.assignment, result)
             return
         e.assignment, e.preemption_targets = self._assign(
-            e.info, snapshot, now)
+            e.info, snapshot, now, nominating=False)
         e.inadmissible_msg = e.assignment.message()
         e.info.last_assignment = e.assignment.last_state
 

@@ -41,7 +41,8 @@ def emitted() -> dict:
     """kind -> the names the package's sources emit:
 
     ``timed``    ``spans.span`` / ``spans.add`` / ``spans.add_since``
-                 (a total by name: seconds and count)
+                 (a total by name: seconds and count), and the totals
+                 ``obs/spans.py`` books itself (``_bump``)
     ``counted``  ``spans.count``, and what ``obs/spans.py`` counts
                  itself from JAX's events (``counters()``)
     ``phases``   a ledger row's ``phases`` keys: the spans under a
@@ -75,8 +76,15 @@ def emitted() -> dict:
                 out["phases"].update(
                     k for k in map(_literal, node.value.keys) if k)
     # obs/spans.py counters(): ``for key in ("retrace_s", ...):
-    # out[key] = ...`` beside the boundary counters
+    # out[key] = ...`` beside the boundary counters; and the totals
+    # obs/spans.py books itself (``_bump("gc", ...)``: the collector)
     tree = ast.parse((PACKAGE / "obs" / "spans.py").read_text())
+    for node in ast.walk(tree):
+        if (isinstance(node, ast.Call) and isinstance(node.func, ast.Name)
+                and node.func.id == "_bump" and node.args):
+            name = _literal(node.args[0])
+            if name is not None:
+                out["timed"].add(name)
     fn = next(n for n in ast.walk(tree)
               if isinstance(n, ast.FunctionDef) and n.name == "counters")
     for node in ast.walk(fn):
@@ -150,6 +158,8 @@ def test_guard_fails_on_a_renamed_span_phase_or_scope():
                                 "scan_victim_entry_share"),
                                ("counted", "scan_entries",
                                 "scan_victim_entry_share"),
+                               ("timed", "gc.full", "gc_full_ms"),
+                               ("timed", "recorder", "recorder_share"),
                                ("phases", "device_put",
                                 "encode_put_s_per_pass"),
                                ("scopes", "classical_search",
