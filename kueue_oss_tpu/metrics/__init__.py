@@ -710,6 +710,11 @@ solver_compile_seconds = registry.register(Histogram(
     "the wall includes the traced execution)", (),
     buckets=(0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
              30.0, 60.0)))
+solver_program_store_total = registry.register(Counter(
+    "kueue_tpu_solver_program_store_total",
+    "Preemption programs loaded from the program store, written to it, "
+    "and entries that failed to load and were traced instead "
+    "(solver/program_store.py)", ("event",)))
 solver_transfer_bytes_total = registry.register(Counter(
     "kueue_tpu_solver_transfer_bytes_total",
     "Host<->device and wire transfer bytes by direction (h2d = "

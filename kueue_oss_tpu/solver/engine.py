@@ -66,13 +66,17 @@ class DrainResult:
     scan_entries: int = 0
     scan_victim_entries: int = 0
     #: the static caps of the program a full drain ran (_size_caps; a
-    #: local drain may run one candidate width up, _full_program) and
-    #: the preemption programs it had to build (full_kernels.full_solver
-    #: on a miss of its cache): 0 in a drain that reused one, and in a
+    #: local drain may run one candidate width up, _full_program), the
+    #: preemption programs it had to build (full_kernels.full_solver on
+    #: a miss of its cache), and of their executables those it traced
+    #: and those it loaded from the program store (solver/
+    #: program_store.py): 0 in a drain that reused one, and in a
     #: sidecar's, which builds in its own process; 0 caps in a lean drain
     h_max: int = 0
     p_max: int = 0
     program_builds: int = 0
+    program_traces: int = 0
+    program_loads: int = 0
 
 
 class SolverEngine:
@@ -671,6 +675,8 @@ class SolverEngine:
                      "scanEntries": result.scan_entries,
                      "scanVictimEntries": result.scan_victim_entries,
                      "programBuilds": result.program_builds,
+                     "programTraces": result.program_traces,
+                     "programLoads": result.program_loads,
                      "hMax": result.h_max, "pMax": result.p_max}
                     if result.p_max else None))
 
@@ -1608,9 +1614,10 @@ class SolverEngine:
                     g_max=g_max, h_max=h_max, p_max=p_max,
                     fs_enabled=self.enable_fair_sharing)
             else:
-                from kueue_oss_tpu.solver.full_kernels import solver_builds
+                from kueue_oss_tpu.solver.full_kernels import (
+                    solver_programs)
 
-                builds0 = solver_builds()
+                programs0 = solver_programs()
                 # the program's own return (full_kernels.full_solver):
                 # the plan, then the liveness gate's two counts and the
                 # entry scan's two
@@ -1625,7 +1632,12 @@ class SolverEngine:
                 result.search_live_lanes = int(search_live_lanes)
                 result.scan_entries = int(scan_entries)
                 result.scan_victim_entries = int(scan_victim_entries)
-                result.program_builds = solver_builds() - builds0
+                programs = solver_programs()
+                result.program_builds = (programs["builds"]
+                                         - programs0["builds"])
+                result.program_traces = (programs["traces"]
+                                         - programs0["traces"])
+                result.program_loads = programs["loads"] - programs0["loads"]
                 result.p_max = self.last_p_max
             admitted = np.asarray(admitted)
             opt = np.asarray(opt)

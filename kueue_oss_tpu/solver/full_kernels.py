@@ -1789,15 +1789,66 @@ def debug_drain(problem: SolverProblem, g_max: int, h_max: int = 8,
 
 
 _solver_cache: dict = {}
-#: programs :func:`full_solver` has built since the process started: a
-#: drain that adds to it met static caps this process had not traced
-#: (seconds of Python tracing, and a compile where the persistent cache
-#: has none). SolverEngine._drain_full reports each drain's share.
-_solver_builds = 0
+#: since the process started: ``builds``, programs :func:`full_solver`
+#: made for static caps this process had not met (a drain that adds to
+#: it pays seconds of Python tracing, or a load from the program store,
+#: and a compile where the persistent cache has none); of their
+#: executables (one per input signature, :class:`_Program`), ``traces``
+#: traced here and ``loads`` loaded from the store.
+#: SolverEngine._drain_full reports each drain's share.
+_solver_programs = {"builds": 0, "traces": 0, "loads": 0}
 
 
-def solver_builds() -> int:
-    return _solver_builds
+def solver_programs() -> dict:
+    return dict(_solver_programs)
+
+
+def _signature(x) -> tuple:
+    """What an executable is specialized to in one input: its aval (a
+    ``jax.Array`` carries its own; anything else is typed here) and its
+    sharding."""
+    aval = getattr(x, "aval", None)
+    return (jax.typeof(x) if aval is None else aval,
+            getattr(x, "sharding", None))
+
+
+class _Program:
+    """:func:`full_solver`'s program: the jitted drain, called through
+    one executable per input signature (avals and shardings). The first
+    call with a signature loads the executable from the program store
+    where it has one, and else traces, lowers and compiles the jitted
+    drain and stores what it built (solver/program_store.py)."""
+
+    def __init__(self, jitted, static: tuple):
+        self.jitted = jitted
+        self.static = static
+        self._executables: dict = {}
+
+    def __call__(self, t):
+        leaves, tree = jax.tree_util.tree_flatten(t)
+        sig = (tree, *map(_signature, leaves))
+        exe = self._executables.get(sig)
+        if exe is not None:
+            return exe(t)
+        exe, out = self._first_call(t)
+        self._executables[sig] = exe
+        return out
+
+    def _first_call(self, t):
+        """The executable for ``t``'s signature and its output on ``t``:
+        the stored one where it loads and runs, else one traced here."""
+        from kueue_oss_tpu.obs import spans
+        from kueue_oss_tpu.solver import program_store
+
+        name = (program_store.key(self.static, t)
+                if program_store.directory() else None)
+        stored = name and program_store.load(name, t)
+        if stored:
+            _solver_programs["loads"] += 1
+            return stored
+        _solver_programs["traces"] += 1
+        spans.count("solver_program_traces")
+        return program_store.compile_and_store(self.jitted.lower(t), name, t)
 
 
 def _solver_key(g_max: int, h_max: int, p_max: int, fs_enabled: bool,
@@ -1817,8 +1868,10 @@ def _solver_key(g_max: int, h_max: int, p_max: int, fs_enabled: bool,
 
 def full_solver(g_max: int, h_max: int = 32, p_max: int = 128,
                 fs_enabled: bool = False, mesh=None, axis: str = "wl"):
-    """The cached jitted drain for static caps; (g_max, h_max, p_max,
-    fs) are compile-time. Called on the tensors it returns the plan's
+    """The cached drain for static caps; (g_max, h_max, p_max, fs) are
+    compile-time, and a process that has none of its executables loads
+    them from the program store where it can (:class:`_Program`).
+    Called on the tensors it returns the plan's
     eight arrays (:func:`solve_backlog_full`) and, after them, four
     int32 sums over the drain's rounds: of the victim search's liveness
     gate, lanes offered and lanes that ran the heavy search
@@ -1830,11 +1883,9 @@ def full_solver(g_max: int, h_max: int = 32, p_max: int = 128,
     key = _solver_key(g_max, h_max, p_max, fs_enabled, mesh, axis)
     fn = _solver_cache.get(key)
     if fn is None:
-        global _solver_builds
-        fn = make_full_solver(g_max, h_max, p_max, fs_enabled,
-                              mesh=mesh, axis=axis)
-        _solver_cache[key] = fn
-        _solver_builds += 1
+        fn = _solver_cache[key] = _Program(make_full_solver(
+            g_max, h_max, p_max, fs_enabled, mesh=mesh, axis=axis), key)
+        _solver_programs["builds"] += 1
     return fn
 
 

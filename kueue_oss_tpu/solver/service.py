@@ -1142,6 +1142,7 @@ def main(argv: Optional[list[str]] = None) -> int:
     until SIGTERM/SIGINT; with KUEUE_SOLVER_COORDINATOR set, the
     non-coordinator ranks join the pod's collective solves instead."""
     import argparse
+    import logging
     import signal
 
     ap = argparse.ArgumentParser(
@@ -1150,6 +1151,11 @@ def main(argv: Optional[list[str]] = None) -> int:
     ap.add_argument("socket_path",
                     help="unix-domain socket to serve on")
     args = ap.parse_args(argv)
+    # the package's own lines (the programs it loaded from the program
+    # store, solver/program_store.py) on stderr, beside JAX's
+    log = logging.getLogger("kueue_oss_tpu")
+    log.setLevel(logging.INFO)
+    log.addHandler(logging.StreamHandler())
     server = serve_multihost(args.socket_path)
     if not isinstance(server, SolverServer):
         return 0  # follower rank: the coordinator shut the pod down

@@ -10,7 +10,10 @@ Where ``JAX_COMPILATION_CACHE_DIR`` is set (the deployment manifests
 set it), JAX reads the variable itself and this module sets nothing.
 Where it is not, the cache goes to ONE fixed directory inside the
 checkout: the directory is part of the cache key, so a temporary,
-per-process or per-run name would never hit.
+per-process or per-run name would never hit. The compiled preemption
+programs themselves are kept in ``programs/`` beneath it
+(``solver/program_store.py``): the cache saves their compile, the store
+their trace as well.
 
 Reference analog: the reference amortizes scheduling-logic cost by
 being a long-lived controller process (cmd/kueue main.go); our

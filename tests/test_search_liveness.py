@@ -319,6 +319,8 @@ def test_engine_counts_the_drains_lanes(monkeypatch):
                           "scanEntries": result.scan_entries,
                           "scanVictimEntries": result.scan_victim_entries,
                           "programBuilds": result.program_builds,
+                          "programTraces": result.program_traces,
+                          "programLoads": result.program_loads,
                           "hMax": result.h_max, "pMax": result.p_max}
 
 
